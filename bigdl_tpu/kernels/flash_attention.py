@@ -118,10 +118,7 @@ def _sds(shape, dtype, vma):
     """ShapeDtypeStruct, carrying varying-mesh-axes when the caller runs
     inside a strict-VMA shard_map (parallel/ring_flash.py)."""
     if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
-        except TypeError:  # older jax: no vma kwarg (and no strict check)
-            pass
+        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
     return jax.ShapeDtypeStruct(shape, dtype)
 
 

@@ -28,8 +28,8 @@ through ``jax.vjp`` of the reference conv, with the stats-gradient
 injection ``dz_eff = dz + ds1 + 2*z*ds2`` applied first — the forward's
 HBM savings (no xh1 write, no z2 stats pass) are kept; the backward
 matches today's cost. Used by ``models/resnet.py`` FusedBottleneck when
-``BIGDL_TPU_FUSED_CONV2=1`` (off by default until the on-chip A/B —
-tools/ab_queue.sh — records a verdict).
+``BIGDL_TPU_FUSED_CONV2=1`` (off by default until an on-chip A/B records
+a verdict — ROADMAP Speed item 4).
 
 Reference analog: mkldnn's conv post-ops fuse the PRECEDING conv's
 epilogue; fusing the consumer conv's PROLOGUE is the TPU-shaped dual
@@ -179,7 +179,10 @@ _cv.defvjp(_cv_fwd, _cv_bwd)
 
 def _conv_vmem_need(rows, H, W, K, N, eb):
     """x tile + padded xh + 9K im2col + z out (+ double buffering on the
-    grid-varying x/z blocks)."""
+    grid-varying x/z blocks). Channels count at the 128-lane tile width
+    they occupy in VMEM: at K=N=64 (ResNet-50 stage 0) the unpadded model
+    read 12.9 MB where Mosaic allocated 19.3 MB and refused the kernel."""
+    K, N = -(-K // 128) * 128, -(-N // 128) * 128
     xpad = rows // (H * W) * (H + 2) * (W + 2) * K * eb
     return (2 * rows * (K * eb + N * eb) + xpad + rows * 9 * K * eb
             + 9 * K * N * eb + rows * N * 4)

@@ -9,6 +9,7 @@ python pipeline when a toolchain is unavailable.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,18 +20,48 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libbigdl_tpu_native.so")
 _SRC = os.path.join(_HERE, "prefetcher.cpp")
+#: sha256 of the prefetcher.cpp the .so beside it was built from. The
+#: rebuild decision compares contents, not mtimes: a checkout or a copy of
+#: the tree does not preserve mtimes, and a binary that matches no
+#: committed source must never be loaded.
+_STAMP = _SO + ".sha256"
 _lib = None
 _lock = threading.Lock()
 
 
+def _src_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _needs_build() -> bool:
+    if not os.path.exists(_SO):
+        return True
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip() != _src_hash()
+    except OSError:
+        return True
+
+
 def _build():
+    # build beside the target and rename into place: a concurrent loader
+    # never maps a half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-            _SRC, "-o", _SO]
-    try:  # with libjpeg(-turbo) when present
-        subprocess.run(base[:-2] + ["-DBIGDL_TPU_JPEG"] + base[-2:] +
-                       ["-ljpeg"], check=True, capture_output=True)
-    except subprocess.CalledProcessError:
-        subprocess.run(base, check=True, capture_output=True)
+            _SRC, "-o", tmp]
+    try:
+        try:  # with libjpeg(-turbo) when present
+            subprocess.run(base[:-2] + ["-DBIGDL_TPU_JPEG"] + base[-2:] +
+                           ["-ljpeg"], check=True, capture_output=True)
+        except subprocess.CalledProcessError:
+            subprocess.run(base, check=True, capture_output=True)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    with open(_STAMP, "w") as f:
+        f.write(_src_hash())
 
 
 def load_library():
@@ -40,8 +71,7 @@ def load_library():
         if _lib is not None:
             return _lib
         try:
-            if not os.path.exists(_SO) or \
-                    os.path.getmtime(_SO) < os.path.getmtime(_SRC):
+            if _needs_build():
                 _build()
             lib = ctypes.CDLL(_SO)
         except Exception:

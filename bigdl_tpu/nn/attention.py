@@ -86,8 +86,8 @@ def dot_product_attention(q, k, v, mask=None, dropout_p=0.0, rng=None,
 
 def flash_attention(q, k, v, causal=False):
     """Fused attention. Delegates to the ``bigdl_tpu.parallel.flash``
-    dispatcher: the custom Pallas kernel on TPU-class backends, the einsum
-    path elsewhere (with a logged, never silent, fallback)."""
+    dispatcher: the custom Pallas kernel on the ``tpu`` platform (a
+    kernel failure raises), the einsum path on any other platform."""
     from ..parallel.flash import flash_attention as dispatch
     return dispatch(q, k, v, causal=causal)
 
@@ -299,8 +299,8 @@ class Attention(Module):
         dispatch policy (``parallel.flash.paged_attention``, gated by
         ``BIGDL_TPU_PAGED_ATTN``):
 
-        * the DENSE path (:meth:`_paged_gather_attend` — the fallback
-          and the oracle) gathers the logical (B, kvH, T, D) view
+        * the DENSE path (:meth:`_paged_gather_attend` — the non-TPU
+          path and the oracle) gathers the logical (B, kvH, T, D) view
           through the tables and einsums over it. The gathered view
           presents logical positions 0..max_blocks*block_size-1 in
           order and masked positions contribute exactly 0 after softmax
@@ -343,7 +343,7 @@ class Attention(Module):
                              pos_s):
         """The dense paged-attention path: gather the logical
         (B, kvH, T, D) view through the block tables, einsum over it.
-        Fallback and ORACLE for the Pallas paged kernel — every kernel
+        The non-TPU path and the ORACLE for the Pallas paged kernel — every kernel
         change must keep this path bitwise-stable."""
         B, S = pos_s.shape
         bs = k_pages.shape[2]
@@ -408,7 +408,7 @@ class Attention(Module):
               and not (training and self.attention_dropout > 0.0
                        and rng is not None)):
             # the fused O(T)-memory path: Pallas kernel on TPU backends,
-            # einsum+mask fallback elsewhere (parallel/flash dispatcher)
+            # einsum+mask elsewhere (parallel/flash dispatcher)
             o = flash_attention(q, k, v, causal=True)
         else:
             if self.causal and mask is None:

@@ -18,8 +18,8 @@ from ``BIGDL_TPU_METRIC_SNAP_S`` (seconds; unset or ``0`` disables —
 single-host runs opt in, multi-host launchers export it) or an
 explicit ``every_s``.
 
-Import discipline: stdlib-only at import time (the package loads
-standalone in the jax-free bench parent); jax is only touched lazily
+Import discipline: stdlib-only at import time (report tools and fleet
+router processes load it without a backend); jax is only touched lazily
 for process indices, with a safe fallback.
 """
 from __future__ import annotations

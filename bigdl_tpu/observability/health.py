@@ -3,7 +3,7 @@
 PR 1's tracer/metrics tell an operator where the time goes; nothing
 tells them whether anything is still happening. A hung stager thread, a
 NaN streak, an HBM leak, or a serving batcher wedged mid-dispatch all
-present today as "no output" — on a remote TPU tunnel that is
+present today as "no output" — on a remote TPU host that is
 indistinguishable from a slow step until someone attaches a debugger.
 This module turns those silences into structured, typed events:
 

@@ -30,9 +30,9 @@ far from the hardware ceiling* it is. Three pieces:
   crash bundle consume.
 
 Import discipline: like the rest of ``bigdl_tpu.observability`` this
-module is stdlib-only at import time (the bench parent loads the
-package standalone without jax); jax is imported lazily inside the
-functions that need it.
+module is stdlib-only at import time (report tools load the package
+without a backend); jax is imported lazily inside the functions that
+need it.
 """
 from __future__ import annotations
 
@@ -59,16 +59,18 @@ PEAK_FLOPS_TABLE = (
     ("v4", 275.0e12), ("v3", 123.0e12), ("v2", 46.0e12),
 )
 
-#: assumed ceiling when the device kind matches nothing (v5e, the
-#: BASELINE target platform). CPU smoke runs land here too — MFU on CPU
-#: is only meaningful relative to an explicit BIGDL_TPU_PEAK_FLOPS.
-DEFAULT_PEAK_FLOPS = 197.0e12
+
+class UnknownDeviceError(LookupError):
+    """``device_kind`` is not in :data:`PEAK_FLOPS_TABLE` and no
+    ``BIGDL_TPU_PEAK_FLOPS`` override names a ceiling: utilization
+    against an assumed peak would be a made-up number."""
 
 
 def peak_flops(device_kind: str = "") -> float:
     """Peak FLOP/s for ``device_kind``. ``BIGDL_TPU_PEAK_FLOPS`` (a
-    float, e.g. ``1e12``) overrides the table — the knob the CPU smoke
-    tests and non-TPU backends use to make MFU well-defined."""
+    float, e.g. ``1e12``) overrides the table — the knob the CPU tests
+    and non-TPU backends use to make MFU well-defined. A device the
+    table does not know raises :class:`UnknownDeviceError`."""
     env = os.environ.get("BIGDL_TPU_PEAK_FLOPS")
     if env:
         try:
@@ -79,7 +81,9 @@ def peak_flops(device_kind: str = "") -> float:
     for sub, f in PEAK_FLOPS_TABLE:
         if sub in dk:
             return f
-    return DEFAULT_PEAK_FLOPS
+    raise UnknownDeviceError(
+        f"no peak FLOP/s known for device_kind {device_kind!r}; add it to "
+        "PEAK_FLOPS_TABLE or set BIGDL_TPU_PEAK_FLOPS")
 
 
 def analyze_compiled(compiled) -> Dict[str, float]:
@@ -250,8 +254,8 @@ def reset():
 
 
 def _backend_info():
-    """(backend, device_kind) — lazy jax, never raises (the bench parent
-    and pure-host tests must be able to record artifacts jax-free)."""
+    """(backend, device_kind) — lazy jax, never raises (pure-host tests
+    and report tools record artifacts without a backend)."""
     try:
         import jax
         dev = jax.devices()[0]
@@ -315,10 +319,11 @@ class InstrumentedJit:
       whose parameter trees are shape-stable for the life of the
       function (the optimizer step: params/opt-state never change
       shape, only the batch does) key on the data arguments alone.
-    * **Graceful degradation is total**: any failure to lower, compile
-      or run the AOT executable permanently falls back to the plain jit
-      path for this wrapper (recording a degraded artifact) — the
-      introspection plane must never be able to break training.
+    * A failure to lower or compile PROPAGATES: it is the program's own
+      (a Mosaic refusal, an OOM at compile), the jit path would only pay
+      for it a second time, and the caller must see it. Only an AOT
+      executable that rejects its arguments (stricter than jit about
+      placement) falls back to the plain jit path for this wrapper.
     * When observability is disabled the wrapper IS the plain jit call
       — one flag read of overhead, no artifacts (PR-1 contract: the
       disabled path stays bulletproof and free).
@@ -394,20 +399,9 @@ class InstrumentedJit:
         self.last_artifact = self._artifacts.get(key)
         self.last_call_compiled = False
         if compiled is None:
-            try:
-                self.last_call_compiled = True
-                compiled = self._compile(key, args)
-                self.last_artifact = self._artifacts.get(key)
-            except Exception as e:  # noqa: BLE001 — degrade, never break
-                self._broken = True
-                record_compiled(
-                    self.name, self.kind, None, input_shapes=_shape_strs(args),
-                    steps_per_program=self._steps(args),
-                    degraded=f"AOT lower/compile failed: "
-                             f"{type(e).__name__}: {e}")
-                _LOG.warning("%s: AOT introspection disabled (%s: %s)",
-                             self.name, type(e).__name__, e)
-                return self._jit(*args)
+            self.last_call_compiled = True
+            compiled = self._compile(key, args)
+            self.last_artifact = self._artifacts.get(key)
         try:
             return compiled(*args)
         except (TypeError, ValueError) as e:
@@ -422,7 +416,7 @@ class InstrumentedJit:
                 type(e).__name__, e)
             return self._jit(*args)
         # anything else (XlaRuntimeError: device OOM, dead collective,
-        # tunnel loss) propagates UNTOUCHED: the buffers may already be
+        # lost device) propagates UNTOUCHED: the buffers may already be
         # donated — a silent jit re-run would trip 'Array has been
         # deleted' and bury the real error the Tier-2 FaultPolicy's
         # classify_failure needs to see — and the failure is the
@@ -443,6 +437,9 @@ def instrument_jit(jit_fn, *, name: str, kind: str,
 
 # ------------------------------------------------------------------ MFU
 
+_UNRESOLVED = object()   # _StepPerf: no peak lookup has happened yet
+
+
 class _StepPerf:
     """Run-cumulative live-MFU bookkeeping (host floats only)."""
 
@@ -450,25 +447,31 @@ class _StepPerf:
         self._lock = threading.Lock()
         self._total_flops = 0.0
         self._total_wall = 0.0
-        self._peak = None       # resolved lazily, re-resolved on env change
-        self._peak_env = None   # the override value the cache was built for
+        self._peak = None       # None = no ceiling known: MFU unpublished
+        self._peak_env = _UNRESOLVED  # override value the cache was built for
 
     def reset(self):
         with self._lock:
             self._total_flops = 0.0
             self._total_wall = 0.0
             self._peak = None
-            self._peak_env = None
+            self._peak_env = _UNRESOLVED
 
-    def peak(self) -> float:
+    def peak(self) -> Optional[float]:
+        """The device's peak FLOP/s, or None when the device is not in
+        the table and no override is set (a CPU run: the MFU gauges then
+        stay unpublished rather than divide by an assumed ceiling)."""
         # one lazy device_kind lookup per process; re-resolved whenever
         # the BIGDL_TPU_PEAK_FLOPS override CHANGES — including being
         # unset (a smoke-phase override must not leak into the real
         # measurement later in the same process)
         env = os.environ.get("BIGDL_TPU_PEAK_FLOPS")
-        if self._peak is None or env != self._peak_env:
+        if env != self._peak_env:
             _, dk = _backend_info()
-            self._peak = peak_flops(dk)
+            try:
+                self._peak = peak_flops(dk)
+            except UnknownDeviceError:
+                self._peak = None
             self._peak_env = env
         return self._peak
 
@@ -495,8 +498,9 @@ class _StepPerf:
         reg = _metrics.registry()
         reg.gauge("perf/model_flops_per_s", unit="flops/s").set(
             flops / wall_s)
-        reg.gauge("perf/mfu").set(flops / wall_s / peak)
-        reg.gauge("perf/mfu_mean").set(tf / tw / peak)
+        if peak is not None:
+            reg.gauge("perf/mfu").set(flops / wall_s / peak)
+            reg.gauge("perf/mfu_mean").set(tf / tw / peak)
         reg.counter("perf/model_flops", unit="flops").inc(flops)
         if host_s is not None and dispatch_s is not None:
             # host = producing/fetching the batch, dispatch = enqueueing

@@ -26,6 +26,7 @@ Execution model (TPU-first):
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import pickle
@@ -617,8 +618,8 @@ class BaseOptimizer:
         (params, opt_state, model state) through K updates on device, so
         the host pays one dispatch, one batched ``[K]`` loss readback and
         one round of bookkeeping per K steps instead of per step — the
-        win when host dispatch dominates (small/medium models, remote-
-        device tunnels). Semantics stay identical to K=1: LR schedules
+        win when host dispatch dominates (small/medium models).
+        Semantics stay identical to K=1: LR schedules
         are precomputed as a ``[K]`` vector, the per-step RNG stream is
         unchanged, and dispatches auto-clamp so a superstep never
         straddles an epoch end or a checkpoint/validation/end-trigger
@@ -694,8 +695,8 @@ class BaseOptimizer:
         dispatch snapshots the resolved host-side training state first,
         and a TRANSIENT device/collective failure replays the in-flight
         step — under superstep fusion, the whole K-step group — from
-        that snapshot after an exponential backoff, so a dropped tunnel
-        packet costs one step's latency instead of the run. The replay
+        that snapshot after an exponential backoff, so a dropped
+        connection costs one step's latency instead of the run. The replay
         reuses the step's exact batches, lr vector and rng keys, so a
         retried run is bitwise-identical to a fault-free one. Permanent
         failures raise immediately (Tier 3 owns those). The per-
@@ -783,8 +784,9 @@ class BaseOptimizer:
             return loss, new_state
 
         def step(params, opt_state, mstate, x, y, lr, rng):
-            (loss, new_mstate), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, mstate, x, y, rng)
+            with self._step_trace_context():
+                (loss, new_mstate), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, mstate, x, y, rng)
             # trace-time span: this body runs under jit, so the span
             # appears once per compile (under the first step/dispatch)
             # and measures clip *trace* cost — the per-step clip itself
@@ -812,6 +814,12 @@ class BaseOptimizer:
             if self.superstep > 1 else \
             jax.jit(step, donate_argnums=(0, 1, 2))
         return self._instrument_step(fn)
+
+    def _step_trace_context(self):
+        """Context the forward+backward of the default step traces
+        under (overridden by DistriOptimizer, whose batch dim jit
+        partitions automatically)."""
+        return contextlib.nullcontext()
 
     def _instrument_step(self, jit_fn):
         """Route the compiled step through the perf-introspection
@@ -2298,6 +2306,13 @@ class DistriOptimizer(BaseOptimizer):
                 out_specs=(P(), P(), P(), P()), check_vma=False)
         return self._instrument_step(
             jax.jit(sharded, donate_argnums=(0, 1, 2)))
+
+    def _step_trace_context(self):
+        # replicated mode's default step is a plain jit whose batch dim
+        # XLA partitions over 'data'; Pallas kernels inside it must be
+        # shard_mapped by their dispatcher (parallel/flash.py)
+        from ..parallel.flash import data_parallel_context
+        return data_parallel_context(self.mesh, "data")
 
     def _build_step(self):
         if self.parameter_mode != "zero1":

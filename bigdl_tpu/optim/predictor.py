@@ -157,11 +157,8 @@ class CompiledForward:
         cache (observability off)."""
         if self._jit is None:
             return 0
-        n = self._jit.compiled_shape_count()
-        try:
-            return n + int(self._jit._jit._cache_size())
-        except AttributeError:  # older jax: no introspection
-            return n if n else -1
+        return (self._jit.compiled_shape_count()
+                + int(self._jit._jit._cache_size()))
 
 
 _shared_forwards = weakref.WeakKeyDictionary()

@@ -33,7 +33,7 @@ site                          where it fires
 
 — with **seeded, deterministic schedules** (nth-call, every-k,
 seeded-probability, wedge-for-duration) and **typed fault kinds**
-reusing :func:`~.failure.classify_failure`'s taxonomy: a ``transient``
+reusing :func:`~.failure.classify_failure`'s classes: a ``transient``
 rule raises :class:`~.failure.TransientDeviceError` (the replay tiers
 must absorb it), a ``permanent`` rule raises :class:`ChaosError` (whose
 message deliberately matches no transient marker, so classification

@@ -35,8 +35,8 @@ from ..observability import health as _health
 _LOG = logging.getLogger("bigdl_tpu.parallel.failure")
 
 # ------------------------------------------------------ failure classes
-#: the failure taxonomy the remediation tiers branch on: TRANSIENT
-#: failures (a flaky collective, a dropped tunnel connection, a
+#: the failure classes the remediation tiers branch on: TRANSIENT
+#: failures (a flaky collective, a dropped connection, a
 #: preempted RPC) are worth replaying in place (FaultPolicy, Tier 2);
 #: PERMANENT failures (a dead host, a wedged mesh) need checkpoint-and-
 #: exit followed by an elastic restart on a reshaped mesh (Tier 3,
@@ -47,7 +47,7 @@ PERMANENT = "permanent"
 
 class TransientDeviceError(RuntimeError):
     """A device/collective failure worth retrying in place: the chip is
-    believed alive, the dispatch just failed (dropped tunnel packet,
+    believed alive, the dispatch just failed (dropped connection,
     preempted RPC, flaky barrier). Raised by fault-injection harnesses
     and recognized by :class:`FaultPolicy` (the trainer replays the
     in-flight step group) and by the serving engine's one-shot batch
@@ -69,7 +69,7 @@ _TRANSIENT_MARKERS = (
 
 def classify_failure(exc: BaseException) -> str:
     """Map an exception from the dispatch path onto the failure
-    taxonomy. Typed signals win: :class:`TransientDeviceError` is
+    classes. Typed signals win: :class:`TransientDeviceError` is
     transient by construction, :class:`HeartbeatLost` / a failed mesh
     probe mean a peer is gone — permanent. Everything else falls back
     to matching the runtime's status-code vocabulary in the message;
@@ -557,7 +557,7 @@ class StragglerMonitor:
         return rep
 
 
-# imported LAST: chaos.py imports this module's taxonomy, so a top-of-
+# imported LAST: chaos.py imports this module's classes, so a top-of-
 # file import would be circular — by this point every name chaos needs
 # exists, and beat()'s disarmed cost stays the documented single
 # module-global read instead of a per-call sys.modules lookup

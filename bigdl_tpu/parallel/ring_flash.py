@@ -34,9 +34,9 @@ from jax import lax
 
 from ..utils.compat import axis_size, pvary
 
-# ONE shared dispatch policy + warn-once registry (parallel/flash.py) and
-# the kernels' own masking constant — no second copy to drift
-from .flash import _warn_once, flash_mode as _block_mode
+# ONE shared dispatch policy (parallel/flash.py) and the kernels' own
+# masking constant — no second copy to drift
+from .flash import flash_mode as _block_mode
 from ..kernels.flash_attention import NEG_INF
 
 
@@ -68,14 +68,10 @@ def _block_attn(q, kb, vb, scale, diag: bool, causal: bool, axes=None):
     use_causal = causal and diag
     mode = _block_mode()
     if mode in ("pallas", "interpret"):
-        try:
-            from ..kernels.flash_attention import _flash_fwd
-            return _flash_fwd(q, kb, vb, use_causal, scale, 512, 512,
-                              mode == "interpret",
-                              vma=set(axes) if axes else None)
-        except Exception as e:  # pragma: no cover - depends on backend
-            _warn_once("ring_fwd", "ring-flash forward kernel failed (%s); "
-                       "falling back to einsum blocks", e)
+        from ..kernels.flash_attention import _flash_fwd
+        return _flash_fwd(q, kb, vb, use_causal, scale, 512, 512,
+                          mode == "interpret",
+                          vma=set(axes) if axes else None)
     return _block_attn_einsum(q, kb, vb, scale, use_causal)
 
 
@@ -104,17 +100,13 @@ def _block_bwd(q, kb, vb, o, lse, delta, do, scale, diag: bool,
     use_causal = causal and diag
     mode = _block_mode()
     if mode in ("pallas", "interpret"):
-        try:
-            from ..kernels.flash_attention import _flash_bwd
-            # out_dtype=f32: per-hop contributions must not round at the
-            # input dtype before the ring accumulators sum them
-            return _flash_bwd(use_causal, scale, 512, 512,
-                              mode == "interpret", (q, kb, vb, o, lse), do,
-                              delta=delta, out_dtype=jnp.float32,
-                              vma=set(axes) if axes else None)
-        except Exception as e:  # pragma: no cover - depends on backend
-            _warn_once("ring_bwd", "ring-flash backward kernel failed "
-                       "(%s); falling back to einsum blocks", e)
+        from ..kernels.flash_attention import _flash_bwd
+        # out_dtype=f32: per-hop contributions must not round at the
+        # input dtype before the ring accumulators sum them
+        return _flash_bwd(use_causal, scale, 512, 512,
+                          mode == "interpret", (q, kb, vb, o, lse), do,
+                          delta=delta, out_dtype=jnp.float32,
+                          vma=set(axes) if axes else None)
     return _block_bwd_einsum(q, kb, vb, lse, delta, do, scale, use_causal)
 
 
@@ -139,12 +131,9 @@ def _vma_axes(x, ring_axis):
     Under a composed mesh (e.g. dp x sp) the blocks vary over more than
     the ring axis, and every fresh constant / kernel output must carry
     the same set or strict-VMA cond/scan typing rejects the program."""
-    try:
-        vma = jax.typeof(x).vma
-        if vma:
-            return tuple(sorted(vma))
-    except (AttributeError, TypeError):  # older jax: no typeof/.vma
-        pass
+    vma = jax.typeof(x).vma
+    if vma:
+        return tuple(sorted(vma))
     return (ring_axis,) if ring_axis else ()
 
 

@@ -85,6 +85,7 @@ from ..optim.predictor import bucket_for
 from ..parallel import chaos as _chaos
 from ..parallel.failure import (FaultPolicy, TransientDeviceError,
                                 classify_failure, TRANSIENT)
+from ..utils import engine as _engine
 from .batching import (DeadlineExceeded, EngineStopped, QueueFull,
                        ServeFuture)
 from .kv_cache import (SPILL_PENDING, KVCacheOOM, KVSwapManager,
@@ -418,6 +419,10 @@ class DecodeScheduler:
             self.registry.publish(model.params, model.state, version="v0",
                                   activate=True)
         self._greedy_args = {}  # bucket -> device-resident greedy triple
+        # every program this scheduler compiles (warm-up's bucket x chunk
+        # shapes above all) goes through the persistent cache, so a
+        # restarted server warms from disk
+        _engine.maybe_enable_compilation_cache()
         self._step_jit = self._build_step(model, "serve/decode_step")
         self._draft_jit = (self._build_step(draft_model, "serve/draft_step")
                            if draft_model is not None else None)

@@ -1,62 +1,14 @@
-"""JAX version compatibility shims.
-
-The codebase targets the modern ``jax.shard_map`` API (``check_vma=``);
-older installs (< 0.5) only ship ``jax.experimental.shard_map`` with the
-``check_rep=`` spelling. Import ``shard_map`` from here instead of from
-``jax`` so both work.
+"""The JAX names the parallel code shares, for the one installation there
+is (JAX 0.9): ``shard_map`` and ``axis_size`` are JAX's own, and
+``pvary`` spells "mark as varying over these mesh axes" with
+``lax.pcast`` (``lax.pvary`` is deprecated in its favour).
 """
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map
-    _NEW_API = True
-except ImportError:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NEW_API = False
+from jax import lax, shard_map  # noqa: F401 — re-exported
+from jax.lax import axis_size  # noqa: F401 — re-exported
 
 
 def pvary(x, axes):
-    """Mark a value as varying over named axes (strict-VMA shard_map).
-    Pre-VMA jax (< 0.6) has neither ``pcast`` nor ``pvary`` — and no
-    varying-manual-axes checking either, so identity is correct there."""
-    from jax import lax
-    if hasattr(lax, "pcast"):
-        try:
-            return lax.pcast(x, axes, to="varying")
-        except TypeError:  # pcast exists but predates the to= keyword
-            pass
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
-
-
-def axis_size(axis_name) -> int:
-    """``lax.axis_size`` (new API) with a pre-0.5 fallback that reads the
-    size from the innermost binding frame of the named axis."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax._src import core as _core
-    return _core.get_axis_env().axis_size(axis_name)
-
-
-def shard_map(f=None, *, mesh=None, in_specs=None, out_specs=None,
-              check_vma=True, **kw):
-    """``jax.shard_map`` with ``check_vma`` mapped to the installed
-    API's keyword (``check_rep`` pre-0.5). Supports the same optional
-    decorator usage (``f=None`` returns a partial).
-
-    On the pre-VMA API the replication checker is disabled outright:
-    it is a static check only, and it has no rules for pallas_call and
-    other primitives these code paths rely on."""
-    if _NEW_API:
-        kw["check_vma"] = check_vma
-    else:
-        kw["check_rep"] = False
-    if f is None:
-        def wrap(g):
-            return _shard_map(g, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-        return wrap
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """Mark a value as varying over named axes (strict-VMA shard_map)."""
+    return lax.pcast(x, axes, to="varying")

@@ -103,6 +103,32 @@ def test_flash_dispatcher_interpret_env(monkeypatch):
     assert np.allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def test_flash_dispatcher_raises_when_the_kernel_throws(monkeypatch):
+    """With the mode resolved to ``pallas`` (what the tpu platform
+    resolves to) a kernel failure propagates: the dispatcher never logs
+    and returns the einsum path under the kernel's name."""
+    from bigdl_tpu.kernels import flash_attention as fk
+    from bigdl_tpu.parallel import flash
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected kernel failure")
+
+    monkeypatch.setattr(flash, "flash_mode", lambda: "pallas")
+    monkeypatch.setattr(fk, "flash_attention_fused", boom)
+    monkeypatch.setattr(fk, "flash_chunk_attention", boom)
+    monkeypatch.setattr(flash, "_einsum_attention",
+                        lambda *a: pytest.fail("einsum ran behind a "
+                                               "kernel failure"))
+    monkeypatch.setattr(flash, "_einsum_chunk_attention",
+                        lambda *a: pytest.fail("einsum ran behind a "
+                                               "kernel failure"))
+    q = k = v = jnp.ones((1, 1, 128, 16), jnp.float32)
+    with pytest.raises(RuntimeError, match="injected kernel failure"):
+        flash.flash_attention(q, k, v, causal=True)
+    with pytest.raises(RuntimeError, match="injected kernel failure"):
+        flash.flash_chunk_attention(q, k, v, q_offset=0)
+
+
 def test_fused_matmul_forward_and_grads():
     from bigdl_tpu.kernels.fused_matmul import fused_bn_relu_matmul
     rng = np.random.RandomState(0)
@@ -447,7 +473,7 @@ def test_fused_bottleneck_chain_matches_sequential_blocks(monkeypatch):
 
 def test_resnet50_fused_chain_builds_and_runs(monkeypatch):
     """ResNet(fused='pallas') assembles FusedBottleneckChain stages by
-    default; BIGDL_TPU_FUSED_CHAIN=0 (the ab_queue control arm) keeps
+    default; BIGDL_TPU_FUSED_CHAIN=0 (the A/B control arm) keeps
     per-block modules; BOTH run (jnp fallback) and agree with the same
     weights."""
     from bigdl_tpu.models.resnet import ResNet, FusedBottleneckChain

@@ -129,3 +129,45 @@ def test_lm_loss_chunked_matches_full_logits():
 # ---------------------------------------------------------------------------
 # fused BN+ReLU+matmul (+stats) kernel and the FusedBottleneck built on it
 # ---------------------------------------------------------------------------
+
+
+def test_flash_kernel_is_shard_mapped_under_a_data_parallel_trace(
+        monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically (the four-chip
+    TPU refused DistriOptimizer's replicated step with "Please wrap the
+    call in a shard_map"), so inside ``data_parallel_context`` the
+    dispatcher shard_maps the kernel over the batch axis — values and
+    gradients unchanged, and nothing is wrapped outside the context."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from bigdl_tpu.parallel import flash
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    rng = np.random.RandomState(3)
+    q, k, v = [jax.device_put(
+        jnp.asarray(rng.randn(4, 2, 128, 16).astype(np.float32)),
+        NamedSharding(mesh, P("data"))) for _ in range(3)]
+
+    def loss(q, k, v):
+        return (flash.flash_attention(q, k, v, causal=True) ** 2).sum()
+
+    def in_context(q, k, v):
+        with flash.data_parallel_context(mesh, "data"):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    assert "shard_map" in str(jax.make_jaxpr(in_context)(q, k, v))
+    assert "shard_map" not in str(jax.make_jaxpr(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v))
+    got, got_g = jax.jit(in_context)(q, k, v)
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "off")
+    want, want_g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, k, v)
+    assert np.allclose(float(got), float(want), rtol=1e-4)
+    for a, b in zip(got_g, want_g):
+        assert np.allclose(np.asarray(a), np.asarray(b), atol=2e-3)
+    # the output keeps the batch sharding the step's other ops expect
+    with flash.data_parallel_context(mesh, "data"):
+        monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+        out = jax.jit(lambda q, k, v: flash.flash_attention(
+            q, k, v, causal=True))(q, k, v)
+    assert len({s.device.id for s in out.addressable_shards}) == 4
+    assert out.addressable_shards[0].data.shape == (1, 2, 128, 16)

@@ -1,4 +1,6 @@
 """Native C++ prefetcher tests."""
+import os
+
 import numpy as np
 import pytest
 
@@ -350,3 +352,24 @@ def test_native_jpeg_encode_roundtrip():
     back = native.decode_jpeg(native.encode_jpeg(img, quality=95))
     assert back.shape == img.shape
     assert np.abs(back.astype(int) - img.astype(int)).mean() < 3.0
+
+
+def test_rebuild_decision_follows_source_contents_not_mtimes(tmp_path,
+                                                             monkeypatch):
+    """A checkout or a copy of the tree does not preserve mtimes, so the
+    loader rebuilds when the stamp beside the .so does not hold the hash
+    of the committed source — however new the binary looks."""
+    from bigdl_tpu import native
+    so, src = tmp_path / "lib.so", tmp_path / "prefetcher.cpp"
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_STAMP", str(so) + ".sha256")
+    src.write_text("int v1;")
+    assert native._needs_build()                      # no binary at all
+    so.write_bytes(b"stale binary, newer mtime than the source")
+    assert native._needs_build()                      # binary, no stamp
+    (tmp_path / "lib.so.sha256").write_text(native._src_hash())
+    assert not native._needs_build()                  # stamp matches
+    src.write_text("int v2;")
+    os.utime(src, (1, 1))                             # source looks OLD
+    assert native._needs_build()                      # contents changed

@@ -111,7 +111,8 @@ def test_kernel_greedy_argmax_bitwise_through_projection():
 def test_dispatch_gating_and_trace_spy(monkeypatch):
     """BIGDL_TPU_PAGED_ATTN routes the seam: off/auto-on-CPU -> dense
     (no kernel trace), interpret -> kernel (trace count bumps); a
-    kernel failure falls back to the dense value, never raises."""
+    kernel failure RAISES — it never returns the dense value under the
+    kernel's name."""
     rng = np.random.RandomState(1)
     q, kp, vp, tables, pos = _case(rng, 2, 4, 2, 1, 8, 4, 4)
     dense = lambda: _dense_ref(q, kp, vp, tables, pos)
@@ -132,12 +133,18 @@ def test_dispatch_gating_and_trace_spy(monkeypatch):
     assert pk.trace_count() == t0 + 1, "spy: the Pallas path must trace"
     assert float(jnp.max(jnp.abs(out - want))) < 1e-5
 
-    # fallback: a kernel that raises degrades to the dense value, loudly
+    # no fallback: with the mode resolved to the kernel ('on' is what the
+    # tpu platform resolves to), a kernel that throws propagates
     def boom(*a, **kw):
         raise RuntimeError("injected kernel failure")
     monkeypatch.setattr(pk, "paged_decode_attention", boom)
-    out = pf.paged_attention(q, kp, vp, tables, pos, dense)
-    assert np.array_equal(np.asarray(out), np.asarray(want))
+    dense_calls = []
+    for mode in ("on", "interpret"):
+        monkeypatch.setenv("BIGDL_TPU_PAGED_ATTN", mode)
+        with pytest.raises(RuntimeError, match="injected kernel failure"):
+            pf.paged_attention(q, kp, vp, tables, pos,
+                               lambda: dense_calls.append(1) or want)
+    assert not dense_calls, "the dense path must not run behind a failure"
 
 
 def test_dispatch_counters_exported(monkeypatch):

@@ -145,28 +145,34 @@ def test_analyze_compiled_degrades_without_apis():
     assert art.degraded and art.flops is None
 
 
-def test_instrumented_jit_falls_back_when_lowering_breaks():
+def test_instrumented_jit_compile_failure_propagates_once():
+    """A lower/compile failure is the program's own (a Mosaic refusal on
+    the chip): it propagates, and the wrapper does NOT mark itself broken
+    and pay for a second compile through the plain jit path."""
     obs.enable()
+    calls = {"lower": 0, "jit": 0}
 
     class BrokenLower:
-        def __init__(self, fn):
-            self._fn = jax.jit(fn)
-
         def __call__(self, *args):
-            return self._fn(*args)
+            calls["jit"] += 1
+            return args[0]
 
         def lower(self, *args):
-            raise RuntimeError("no AOT on this backend")
+            calls["lower"] += 1
+            raise RuntimeError("Mosaic refused the kernel")
 
-    wrapped = perf.instrument_jit(BrokenLower(lambda x: x * 2),
-                                  name="t/broken", kind="forward")
-    out = wrapped(jnp.ones((3,)))
-    assert np.allclose(np.asarray(out), 2.0)
-    art = perf.registry().latest("t/broken")
-    assert art is not None and art.degraded  # recorded the degradation
-    # permanently broken: later calls go straight through the jit path
-    assert np.allclose(np.asarray(wrapped(jnp.ones((3,)))), 2.0)
-    assert len(perf.registry().artifacts()) == 1
+    wrapped = perf.instrument_jit(BrokenLower(), name="t/broken",
+                                  kind="forward")
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        wrapped(jnp.ones((3,)))
+    assert calls == {"lower": 1, "jit": 0}
+    assert perf.registry().latest("t/broken") is None
+    assert obs.registry().counter("compile/degraded").value == 0
+    # still armed: the next call reports the same failure, not a silent
+    # jit-path success
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        wrapped(jnp.ones((3,)))
+    assert calls["jit"] == 0
 
 
 def test_instrumented_jit_one_compile_per_shape():
@@ -189,11 +195,13 @@ def test_instrumented_jit_one_compile_per_shape():
 
 # ------------------------------------------------------- live MFU
 
-def test_live_mfu_agrees_with_offline_bench_math():
+def test_live_mfu_agrees_with_offline_bench_math(monkeypatch):
     """The acceptance bar: perf/mfu_mean within 10% of the MFU computed
     offline the way bench.py computes it — XLA cost-analysis FLOPs of
     the SAME compiled program over the measured step wall time, against
     the same peak table."""
+    # the CPU is not in the peak table: MFU needs the explicit ceiling
+    monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "1e12")
     obs.enable()
     opt = _train(steps=8)
     reg = obs.registry()
@@ -246,9 +254,24 @@ def test_peak_flops_table_and_env_override(monkeypatch):
     monkeypatch.delenv("BIGDL_TPU_PEAK_FLOPS", raising=False)
     assert perf.peak_flops("TPU v5 lite") == 197.0e12
     assert perf.peak_flops("TPU v5p chip") == 459.0e12
-    assert perf.peak_flops("unknown cpu") == perf.DEFAULT_PEAK_FLOPS
+    # a device the table does not know is an error, not an assumed v5e
+    for kind in ("cpu", "", "unknown accelerator"):
+        with pytest.raises(perf.UnknownDeviceError, match="device_kind"):
+            perf.peak_flops(kind)
     monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "2.5e12")
     assert perf.peak_flops("TPU v5 lite") == 2.5e12
+    assert perf.peak_flops("cpu") == 2.5e12
+
+
+def test_no_mfu_gauge_without_a_known_peak(monkeypatch):
+    """On a device with no known ceiling the step loop still publishes
+    FLOP/s and the phase split, and leaves the MFU gauges unpublished."""
+    monkeypatch.delenv("BIGDL_TPU_PEAK_FLOPS", raising=False)
+    obs.enable()
+    _train(steps=4)
+    reg = obs.registry()
+    assert reg.gauge("perf/model_flops_per_s").value > 0
+    assert reg.get("perf/mfu") is None and reg.get("perf/mfu_mean") is None
 
 
 def test_step_perf_peak_unsticks_when_env_unset(monkeypatch):
@@ -259,7 +282,7 @@ def test_step_perf_peak_unsticks_when_env_unset(monkeypatch):
     monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "1e9")
     assert sp.peak() == 1e9
     monkeypatch.delenv("BIGDL_TPU_PEAK_FLOPS")
-    assert sp.peak() == perf.peak_flops("")  # re-resolved from the table
+    assert sp.peak() is None  # re-resolved: the CPU has no table entry
     monkeypatch.setenv("BIGDL_TPU_PEAK_FLOPS", "3e9")
     assert sp.peak() == 3e9  # and a CHANGED override re-resolves too
 

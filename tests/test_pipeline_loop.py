@@ -284,22 +284,19 @@ def test_stager_collapses_data_fetch_5x():
 # persistent compile cache wiring
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_env_gate_and_entries(tmp_path, monkeypatch):
+def test_compile_cache_env_gate_and_entries(tmp_path, monkeypatch,
+                                            restore_jax_cache_config):
     monkeypatch.setenv("BIGDL_TPU_COMPILE_CACHE", "0")
-    prev = engine._state["compile_cache_dir"]
     engine._state["compile_cache_dir"] = None
-    try:
-        assert engine.maybe_enable_compilation_cache() is None
-        assert engine.compilation_cache_entries() == 0
-        monkeypatch.setenv("BIGDL_TPU_COMPILE_CACHE", "1")
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-        d = engine.maybe_enable_compilation_cache()
-        assert d == str(tmp_path)
-        assert engine.compilation_cache_dir() == str(tmp_path)
-        # idempotent: the second call returns the same dir without re-init
-        assert engine.maybe_enable_compilation_cache() == str(tmp_path)
-        assert engine.compilation_cache_entries() == 0
-        (tmp_path / "a_compiled_executable").write_bytes(b"x")
-        assert engine.compilation_cache_entries() == 1
-    finally:
-        engine._state["compile_cache_dir"] = prev
+    assert engine.maybe_enable_compilation_cache() is None
+    assert engine.compilation_cache_entries() == 0
+    monkeypatch.setenv("BIGDL_TPU_COMPILE_CACHE", "1")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    d = engine.maybe_enable_compilation_cache()
+    assert d == str(tmp_path)
+    assert engine.compilation_cache_dir() == str(tmp_path)
+    # idempotent: the second call returns the same dir without re-init
+    assert engine.maybe_enable_compilation_cache() == str(tmp_path)
+    assert engine.compilation_cache_entries() == 0
+    (tmp_path / "a_compiled_executable").write_bytes(b"x")
+    assert engine.compilation_cache_entries() == 1

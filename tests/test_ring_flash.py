@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from bigdl_tpu.parallel.flash import _einsum_fallback as _dense_ref_impl
+from bigdl_tpu.parallel.flash import _einsum_attention as _dense_ref_impl
 from bigdl_tpu.parallel.ring_flash import make_ring_flash_attention
 
 
@@ -126,11 +126,9 @@ def test_ring_flash_trains_end_to_end():
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_interpret_kernel_path(causal, monkeypatch):
     """BIGDL_TPU_FLASH=interpret drives the ring through the actual Pallas
-    kernels (forward AND backward) on CPU — and fails loudly if the
-    kernels silently fell back to einsum."""
-    import bigdl_tpu.parallel.flash as _flash_mod
+    kernels (forward AND backward) on CPU. There is no einsum fallback
+    behind them: a kernel failure raises out of the ring."""
     monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
-    _flash_mod._warned.clear()
     B, H, T, D = 1, 1, 32, 8
     rng = np.random.RandomState(5 if causal else 6)
     q, k, v = [jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
@@ -152,9 +150,6 @@ def test_ring_flash_interpret_kernel_path(causal, monkeypatch):
     for name, a, b in zip("qkv", g_ring, g_dense):
         assert np.allclose(np.asarray(a), np.asarray(b), atol=2e-2), \
             (name, np.abs(np.asarray(a) - np.asarray(b)).max())
-    # a silent kernel->einsum fallback would leave warn-once entries
-    assert not {k for k in _flash_mod._warned
-                if k in ("ring_fwd", "ring_bwd")}, _flash_mod._warned
 
 
 def test_attention_module_seq_parallel_matches_dense():

@@ -101,23 +101,47 @@ def test_device_memory_stats():
     assert len(stats) == 8
 
 
-def test_enable_compilation_cache(tmp_path, monkeypatch):
-    import jax
+def test_compilation_cache_follows_the_environment(
+        tmp_path, monkeypatch, restore_jax_cache_config):
+    """JAX_COMPILATION_CACHE_DIR set -> that directory and no other."""
+    env_dir = str(tmp_path / "env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    assert engine.enable_compilation_cache() == env_dir
+    assert os.path.isdir(env_dir)
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert engine.compilation_cache_dir() == env_dir
+    # every program is worth keeping: a serving warm-up's small bucket
+    # programs must not fall under a minimum-compile-time threshold
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
+
+def test_compilation_cache_defaults_to_the_checkout(
+        monkeypatch, restore_jax_cache_config):
+    """Unset -> <checkout>/.jax_cache, resolved from the package's own
+    location (never ~, a temp name, a pid or a time)."""
+    import bigdl_tpu
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    prior_dir = jax.config.jax_compilation_cache_dir
-    prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(bigdl_tpu.__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    existed = os.path.isdir(want)
     try:
-        d = str(tmp_path / "xla_cache")
-        got = engine.enable_compilation_cache(d, min_compile_time_secs=0.5)
-        assert got == d and os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.5
-        # env override wins when no explicit dir is passed
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                           str(tmp_path / "env"))
-        assert engine.enable_compilation_cache() == str(tmp_path / "env")
-    finally:  # global jax config: restore so later tests don't cache here
-        jax.config.update("jax_compilation_cache_dir", prior_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prior_min)
+        assert engine.enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        if not existed and os.path.isdir(want) and not os.listdir(want):
+            os.rmdir(want)
+
+
+def test_no_argument_can_override_the_cache_variable(
+        tmp_path, monkeypatch, restore_jax_cache_config):
+    """There is deliberately no directory argument: the variable (or the
+    checkout default) is the only way to place the cache."""
+    import inspect
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert list(inspect.signature(
+        engine.enable_compilation_cache).parameters) == [
+            "min_compile_time_secs"]
+    with pytest.raises(TypeError):
+        engine.enable_compilation_cache(cache_dir=str(tmp_path / "arg"))
+    assert not (tmp_path / "arg").exists()

@@ -1,7 +1,7 @@
 """Host-only input-pipeline microbench (no device needed).
 
-The realdata config's open question (docs/R4_ONCHIP_STATUS.md) is
-`input_wait_frac 0.92` — the chip starved. That fraction conflates two
+The realdata config's open question is `input_wait_frac 0.92` (an earlier
+builder's on-chip run, 2026-07-31, older code) — the chip starved. That fraction conflates two
 distinct failures: (a) the host pipeline cannot sustain the chip's
 images/sec at all, or (b) it can, but the overlap/backpressure plumbing
 stalls. This tool measures (a) in isolation: the C++ libjpeg prefetcher
@@ -13,9 +13,9 @@ images/sec, the realdata gap is (b) — fix the overlap; if it is far
 below, the pipeline needs more workers / faster decode, and
 `images_per_sec / workers` says whether scaling is linear.
 
-Runs anywhere (CPU-only box included; the TPU-host run in
-tools/ab_queue.sh is the number that matters — its core count feeds the
-decode workers). One JSON line on stdout like bench.py children.
+Runs anywhere (CPU-only box included; the run on the TPU host is the
+number that matters — its core count feeds the decode workers). One JSON
+line on stdout, in bench.py's line schema.
 
 Usage: python tools/bench_input_pipeline.py [--batch 256] [--size 224]
            [--workers N] [--batches 30]
