@@ -167,6 +167,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp)
     return o[:, :, :t_q], lse[:, :, :t_q, 0]
 
@@ -304,6 +305,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
 
     q_spec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, x, y: (b_, h_, x, 0))
@@ -321,6 +323,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g,
         out_shape=_sds((b, h, tq_pad, d), out_dtype or q.dtype, vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
     return dq[:, :, :t_q], dk[:, :, :t_kv], dv[:, :, :t_kv]

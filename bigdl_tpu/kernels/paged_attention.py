@@ -170,6 +170,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, positions,
         grid_spec=grid_spec,
         out_shape=_sds((B, kvH, rows, D), q.dtype, vma),
         interpret=interpret,
+        name="paged_attention",
     )(tables, pos, qr, k_pages, v_pages)
     # bump only after the pallas trace SUCCEEDED: the spy must not count
     # a program that was never built (bench_serving's kernel arm and

@@ -524,9 +524,10 @@ class TransformerBlock(Module):
     def _attn_sublayer(self, params, h, mask, training, rng):
         """ln1 → self-attention → residual (shared with MoE blocks)."""
         r1 = jax.random.fold_in(rng, 1) if rng is not None else None
-        n, _ = self.ln1.apply(params["ln1"], {}, h, training, None)
+        n, _ = self.ln1.apply(params["ln1"], {}, h, training, None,
+                              scope="ln1")
         a, _ = self.attn.apply(params["attn"], {}, Table(n, n, mask),
-                               training, r1)
+                               training, r1, scope="attn")
         return h + a
 
     def _apply(self, params, state, x, training, rng):
@@ -540,12 +541,16 @@ class TransformerBlock(Module):
         r2 = jax.random.fold_in(rng, 2) if rng is not None else None
         h = self._attn_sublayer(params, h, mask, training, rng)
         if self.with_cross and enc is not None:
-            n, _ = self.ln3.apply(params["ln3"], {}, h, training, None)
+            n, _ = self.ln3.apply(params["ln3"], {}, h, training, None,
+                                  scope="ln3")
             c, _ = self.cross.apply(params["cross"], {},
-                                    Table(n, enc, enc_mask), training, r1)
+                                    Table(n, enc, enc_mask), training, r1,
+                                    scope="cross")
             h = h + c
-        n, _ = self.ln2.apply(params["ln2"], {}, h, training, None)
-        f, _ = self.ffn.apply(params["ffn"], {}, n, training, r2)
+        n, _ = self.ln2.apply(params["ln2"], {}, h, training, None,
+                              scope="ln2")
+        f, _ = self.ffn.apply(params["ffn"], {}, n, training, r2,
+                              scope="ffn")
         return h + f
 
     def _ffn_sublayer(self, params, h):
@@ -681,6 +686,7 @@ class Transformer(Module):
                     k[2 + len(self.blocks) + i])
         return p
 
+    @jax.named_scope("embed")
     def _embed(self, params, ids):
         return embed_ids(params["embed"], ids, self.hidden_size,
                          with_pe=getattr(self, "pos_encoding",
@@ -690,6 +696,10 @@ class Transformer(Module):
                enc=None, enc_mask=None):
         for i, blk in enumerate(blocks):
             r = jax.random.fold_in(rng, i) if rng is not None else None
+            # the block goes through `_apply`, so its scope (the parameter
+            # key, as `Module.apply(scope=)` takes it) is put here: inside
+            # the checkpoint, so the recomputed forward carries it too
+            @jax.named_scope(f"{prefix}{i}")
             def run(p, h, enc=enc, blk=blk, r=r):
                 arg = Table(h, mask) if enc is None else Table(h, mask, enc,
                                                                enc_mask)
@@ -707,7 +717,8 @@ class Transformer(Module):
         assert self.mode == "lm", "hidden_states is the LM-mode trunk"
         h = self._embed(params, x)
         h = self._stack(self.blocks, "block", params, h, None, training, rng)
-        h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None)
+        h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None,
+                               scope="ln_f")
         return h
 
     def _apply(self, params, state, x, training, rng):
@@ -721,10 +732,15 @@ class Transformer(Module):
             mask = causal_mask(tgt.shape[1])
             h = self._stack(self.blocks, "block", params, h, mask, training,
                             rng, enc, src_mask)
-            h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None)
-            return h @ params["embed"].T  # tied output projection
+            h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None,
+                                   scope="ln_f")
+            return self._head(params, h)
         # LM mode: causal masking lives inside the blocks (flash path)
-        h = self.hidden_states(params, x, training, rng)
+        return self._head(params, self.hidden_states(params, x, training,
+                                                     rng))
+
+    @jax.named_scope("head")
+    def _head(self, params, h):
         return h @ params["embed"].T  # tied output projection
 
     # ---- autoregressive inference (KV cache; TPU-first, the reference's

@@ -24,6 +24,7 @@ exact ``updateGradInput``/``accGradParameters`` pair for every layer.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import pickle
 from typing import Any, Dict, Optional, Tuple
@@ -98,10 +99,17 @@ class Module:
         return self._init_params(rng), self._init_state()
 
     def apply(self, params: Params, state: State, x, training: bool = False,
-              rng=None):
-        """Pure forward. Returns ``(output, new_state)``."""
+              rng=None, scope: Optional[str] = None):
+        """Pure forward. Returns ``(output, new_state)``. ``scope`` is the
+        key of ``params`` in the caller's parameter tree: inside a
+        compiled program the layer's operations then carry it in their
+        names (``jax.named_scope``), so a profile reads in the paths an
+        operator knows from the parameter tree. (``self.name`` would not
+        do: its default counts instances, so it changes with the order of
+        construction.)"""
         try:
-            out = self._apply(params, state, x, training, rng)
+            with jax.named_scope(scope) if scope else contextlib.nullcontext():
+                out = self._apply(params, state, x, training, rng)
         except Exception as e:
             # LayerException parity (utils/LayerException.scala): errors
             # deep inside a model carry the failing layer's identity.
@@ -489,7 +497,7 @@ class Container(Module):
     def child_apply(self, i, params, state, x, training, rng):
         sub_rng = None if rng is None else jax.random.fold_in(rng, i)
         out, new_sub = self.modules[i].apply(params[str(i)], state[str(i)], x,
-                                             training, sub_rng)
+                                             training, sub_rng, scope=str(i))
         return out, new_sub
 
     def training(self):

@@ -195,8 +195,8 @@ class Beacon:
 
 
 class _NullBeacon:
-    """Shared no-op beacon for the disabled path (mirrors trace's
-    ``_NULL_SPAN`` pattern: hot loops keep the calls inline)."""
+    """Shared no-op beacon for the disabled path (hot loops keep the
+    calls inline)."""
 
     __slots__ = ()
     name = "<null>"

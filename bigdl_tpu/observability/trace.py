@@ -2,11 +2,13 @@
 
 Design constraints, in order:
 
-1. **Disabled cost is one flag read.** The hot-path spelling is
-   ``with span("step/dispatch"):`` — when tracing is off that call
-   returns a shared immutable no-op context manager; no allocation, no
-   clock read, no lock. The training loop keeps the instrumentation
-   inline at all times (no conditional code paths to bit-rot).
+1. **Disabled cost is the profiler's own no-op.** The hot-path
+   spelling is ``with span("step/dispatch"):`` — when tracing is off
+   that call builds one ``jax.profiler.TraceAnnotation``, which outside
+   a profiler session reads one flag and records nothing (well under a
+   microsecond; no clock read, no lock). The training loop keeps the
+   instrumentation inline at all times (no conditional code paths to
+   bit-rot).
 2. **Monotonic clocks.** Spans stamp ``time.perf_counter_ns()``; wall
    clocks (NTP steps, suspend) must never produce negative durations in
    a trace.
@@ -15,12 +17,21 @@ Design constraints, in order:
    thread nests its own spans without corrupting the main loop's stack.
    Finished spans land in one shared list (CPython list.append is
    atomic; the exporters snapshot under the tracer lock).
+4. **One call site, one name, both sinks.** Every span is also a
+   ``jax.profiler.TraceAnnotation`` of the same name with its args, so
+   whenever a profiler session runs (the benchmark's traced window, a
+   ``BIGDL_TPU_PROFILE`` window) the program's spans sit in the
+   profile's host plane, on the device trace's clock, enabled or not.
+   A span given ``step_num=`` is the profiler's step boundary
+   (``StepTraceAnnotation``).
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -51,18 +62,20 @@ class _SpanHandle:
     not; an exception tags the span (``error: ExcType``) instead of
     leaking an open span on the stack."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
     def __init__(self, tracer: "Tracer", sp: Span):
         self._tracer = tracer
         self._span = sp
+        self._annotation = _annotation(sp.name, sp.args or {})
 
     def annotate(self, **kw):
         """Attach key/values to the live span (shows up in the Chrome
-        trace ``args`` pane)."""
+        trace ``args`` pane and in the profiler's event)."""
         if self._span.args is None:
             self._span.args = {}
         self._span.args.update(kw)
+        self._annotation.set_metadata(**kw)
         return self
 
     @property
@@ -78,28 +91,29 @@ class _SpanHandle:
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.annotate(error=exc_type.__name__)
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._finish(self._span)
         return False
 
 
-class _NullSpan:
-    """Shared no-op handle for the disabled path (and a safe annotate)."""
-
-    __slots__ = ()
+class _ProfilerSpan(TraceAnnotation):
+    """The handle of the disabled path: the profiler's annotation alone
+    (its own no-op outside a session; it opens where it is built, as a
+    ``Span`` does), with the handle's ``annotate`` and ``duration_s``."""
 
     duration_s = 0.0
 
     def annotate(self, **kw):
+        self.set_metadata(**kw)
         return self
 
-    def __enter__(self):
-        return self
 
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+def _annotation(name: str, args: Dict) -> _ProfilerSpan:
+    if "step_num" in args:
+        # what jax.profiler.StepTraceAnnotation passes: the profiler's
+        # step view takes its boundaries from these
+        return _ProfilerSpan(name, _r=1, **args)
+    return _ProfilerSpan(name, **args)
 
 
 class Tracer:
@@ -221,10 +235,11 @@ def reset():
 
 
 def span(name: str, **args):
-    """Module-level hot-path entry: a real span when enabled, the shared
-    no-op handle when not."""
+    """Module-level hot-path entry: always the profiler's annotation
+    (nothing outside a profiler session), and a recorded span besides
+    when enabled. ``step_num=`` marks a training step."""
     if not _enabled:
-        return _NULL_SPAN
+        return _annotation(name, args)
     return _tracer.span(name, **args)
 
 

@@ -303,6 +303,7 @@ def _scan_superstep(step):
     return superstep
 
 
+@jax.named_scope("grad_clip")
 def _clip_grads(grads, clip_const=None, clip_norm=None):
     if clip_const is not None:
         lo, hi = clip_const
@@ -313,6 +314,32 @@ def _clip_grads(grads, clip_const=None, clip_norm=None):
         scale = jnp.minimum(1.0, clip_norm / (total + 1e-12))
         grads = _tmap(lambda g: g * scale, grads)
     return grads
+
+
+def _loss_fn(model, criterion):
+    """``(params, mstate, x, y, rng) -> (loss, new model state)``, the
+    function every step builder differentiates. The model's own scopes
+    name the forward; criterion and regulariser go under ``loss``."""
+    reg_tree = regularizer_tree(model)
+
+    def loss_fn(params, mstate, x, y, rng):
+        out, new_state = model.apply(params, mstate, x, training=True,
+                                     rng=rng)
+        with jax.named_scope("loss"):
+            loss = criterion._forward(out, y)
+            if reg_tree:
+                loss = loss + regularization_loss(reg_tree, params)
+        return loss, new_state
+
+    return loss_fn
+
+
+def _guarded(loss, new, old):
+    """NaN/Inf guard inside the compiled step (buffers are donated, so
+    the host can't roll back): a non-finite loss keeps the previous
+    state and only the loss reports the failure."""
+    ok = jnp.isfinite(loss)
+    return _tmap(lambda a, b: jnp.where(ok, a, b), new, old)
 
 
 class RemediationPolicy:
@@ -769,46 +796,29 @@ class BaseOptimizer:
                               num_shards=self._num_shards())
 
     def _build_step(self):
-        model, criterion = self.model, self.criterion
-        reg_tree = regularizer_tree(model)
         clip_const, clip_norm = self.clip_const, self.clip_norm
         optim = self.optim_method
-        frozen_mask = _frozen_mask(model)
-
-        def loss_fn(params, mstate, x, y, rng):
-            out, new_state = model.apply(params, mstate, x, training=True,
-                                         rng=rng)
-            loss = criterion._forward(out, y)
-            if reg_tree:
-                loss = loss + regularization_loss(reg_tree, params)
-            return loss, new_state
+        frozen_mask = _frozen_mask(self.model)
+        loss_fn = _loss_fn(self.model, self.criterion)
 
         def step(params, opt_state, mstate, x, y, lr, rng):
             with self._step_trace_context():
                 (loss, new_mstate), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, mstate, x, y, rng)
-            # trace-time span: this body runs under jit, so the span
-            # appears once per compile (under the first step/dispatch)
-            # and measures clip *trace* cost — the per-step clip itself
-            # is fused into the compiled program
-            with obs.span("step/grad_clip", traced=True):
-                grads = _clip_grads(grads, clip_const, clip_norm)
-            if frozen_mask is not None:
-                grads = _tmap(lambda g, m: g * m, grads, frozen_mask)
-            new_params, new_opt = optim.update(grads, params, opt_state, lr)
-            if frozen_mask is not None:
-                # weight decay must not move frozen params either — restore
-                new_params = _tmap(
-                    lambda n, o, m: jnp.where(m > 0, n, o),
-                    new_params, params, frozen_mask)
-            # NaN/Inf guard inside the compiled step (buffers are donated, so
-            # the host can't roll back): a non-finite loss keeps the previous
-            # params/opt-state and only the loss reports the failure.
-            ok = jnp.isfinite(loss)
-            pick = lambda new, old: _tmap(
-                lambda a, b: jnp.where(ok, a, b), new, old)
-            return (loss, pick(new_params, params), pick(new_opt, opt_state),
-                    pick(new_mstate, mstate))
+            grads = _clip_grads(grads, clip_const, clip_norm)
+            with jax.named_scope("optim_update"):
+                if frozen_mask is not None:
+                    grads = _tmap(lambda g, m: g * m, grads, frozen_mask)
+                new_params, new_opt = optim.update(grads, params, opt_state,
+                                                   lr)
+                if frozen_mask is not None:
+                    # weight decay must not move frozen params either
+                    new_params = _tmap(
+                        lambda n, o, m: jnp.where(m > 0, n, o),
+                        new_params, params, frozen_mask)
+                return (loss,) + _guarded(
+                    loss, (new_params, new_opt, new_mstate),
+                    (params, opt_state, mstate))
 
         fn = jax.jit(_scan_superstep(step), donate_argnums=(0, 1, 2)) \
             if self.superstep > 1 else \
@@ -1578,18 +1588,19 @@ class BaseOptimizer:
             while True:
                 self._step_beacon.pulse()
                 self._check_halt()
-                with obs.span("step", neval=state["neval"]):
-                    t0 = time.time()
+                with obs.span("step", step_num=state["neval"]):
+                    t0 = time.perf_counter()
                     with obs.span("step/data_fetch"):
                         try:
                             x, y = next(batches)
                         except StopIteration:
                             return
-                    t1 = time.time()
-                    # *1.0 is bitwise-exact: the remediation scale only
-                    # changes lr after a plateau actually reduced it
-                    lr = optim.current_lr() * self._remediation_lr_scale
-                    rng = engine.next_rng_key()
+                    t1 = time.perf_counter()
+                    with obs.span("step/prepare"):
+                        # *1.0 is bitwise-exact: the remediation scale only
+                        # changes lr after a plateau actually reduced it
+                        lr = optim.current_lr() * self._remediation_lr_scale
+                        rng = engine.next_rng_key()
                     dsp = obs.span("step/dispatch")
                     with dsp:
                         loss, params, opt_state, mstate = \
@@ -1608,7 +1619,7 @@ class BaseOptimizer:
                         # OLDER one — _resolved_step names it
                         loss_val = self._observe_loss(
                             loss, state["neval"] + 1)
-                    t2 = time.time()
+                    t2 = time.perf_counter()
                     if loss_val is not None and not np.isfinite(loss_val):
                         nan_streak += 1
                         if obs.enabled():
@@ -1656,74 +1667,79 @@ class BaseOptimizer:
                         obs.instant("step/nan_skip", neval=state["neval"])
                         state["neval"] += 1
                         continue
-                    if loss_val is not None:
-                        # windowed policies have no resolved loss until K
-                        # are in flight — the streak/loss state only moves
-                        # on an actually-observed value
-                        nan_streak = 0
-                        state["loss"] = loss_val
-                    state["neval"] += 1
-                    state["epoch_finished"] = False
-                    health_events = []
-                    if loss_val is not None:
-                        # provenance rides the already-resolved host
-                        # float — no extra readback; under async/
-                        # window:K the loss belongs to _resolved_step,
-                        # up to K-1 before the current iteration
+                    # what follows the resolved loss: bookkeeping,
+                    # summaries, remediation, and the triggers (validation,
+                    # checkpoint, the caller's end trigger)
+                    with obs.span("step/triggers"):
+                        if loss_val is not None:
+                            # windowed policies have no resolved loss until
+                            # K are in flight — the streak/loss state only
+                            # moves on an actually-observed value
+                            nan_streak = 0
+                            state["loss"] = loss_val
+                        state["neval"] += 1
+                        state["epoch_finished"] = False
+                        health_events = []
+                        if loss_val is not None:
+                            # provenance rides the already-resolved host
+                            # float — no extra readback; under async/
+                            # window:K the loss belongs to _resolved_step,
+                            # up to K-1 before the current iteration
+                            if obs.enabled():
+                                _flight.record("step",
+                                               neval=self._resolved_step,
+                                               epoch=state["epoch"],
+                                               loss=loss_val)
+                            if self._loss_monitor is not None:
+                                health_events = self._loss_monitor.observe(
+                                    loss_val, self._resolved_step)
+                        if self._profiler is not None:
+                            self._profiler.maybe_tick(state["neval"])
+                        self.metrics.add("data_time", t1 - t0)
+                        self.metrics.add("step_time", t2 - t1)
                         if obs.enabled():
-                            _flight.record("step",
-                                           neval=self._resolved_step,
-                                           epoch=state["epoch"],
-                                           loss=loss_val)
-                        if self._loss_monitor is not None:
-                            health_events = self._loss_monitor.observe(
-                                loss_val, self._resolved_step)
-                    if self._profiler is not None:
-                        self._profiler.maybe_tick(state["neval"])
-                    self.metrics.add("data_time", t1 - t0)
-                    self.metrics.add("step_time", t2 - t1)
-                    if obs.enabled():
-                        obs.counter("optim/steps").inc()
-                        obs.gauge("optim/throughput", unit="samples/s").set(
-                            self.batch_size / max(t2 - t0, 1e-9))
-                        # live MFU + step-phase gauges: host floats the
-                        # loop already measured, zero new readbacks. A
-                        # dispatch that paid a compile measures XLA, not
-                        # the model — excluded, like bench warmup. The
-                        # wall is the FULL iteration (t0→t2): under
-                        # async/window:K the dispatch+resolve sliver
-                        # alone excludes the device time entirely.
-                        if not getattr(self._step_fn, "last_call_compiled",
-                                       True):
-                            obs.perf.note_step(
-                                getattr(self._step_fn, "last_artifact",
-                                        None),
-                                wall_s=t2 - t0, host_s=t1 - t0,
-                                dispatch_s=dsp.duration_s)
-                        self._snap_writer.maybe_write(step=state["neval"])
-                    if self.train_summary is not None:
-                        rec = self.train_summary.should_record
-                        if loss_val is not None and rec("Loss", state):
-                            self.train_summary.add_scalar("Loss", loss_val,
-                                                          state["neval"])
-                        if rec("LearningRate", state):
-                            self.train_summary.add_scalar("LearningRate", lr,
-                                                          state["neval"])
-                        if rec("Throughput", state):
-                            self.train_summary.add_scalar(
-                                "Throughput",
-                                self.batch_size / max(t2 - t0, 1e-9),
-                                state["neval"])
-                    if self._remediation_tick(state, params, opt_state,
-                                              mstate, health_events,
-                                              step_time_s=t2 - t1):
-                        box["done"] = True
-                        return
-                    if self._fire_mid_epoch(state, params, opt_state, mstate):
-                        pass
-                    if self.end_trigger(state):
-                        box["done"] = True
-                        return
+                            obs.counter("optim/steps").inc()
+                            obs.gauge("optim/throughput",
+                                      unit="samples/s").set(
+                                self.batch_size / max(t2 - t0, 1e-9))
+                            # live MFU + step-phase gauges: host floats the
+                            # loop already measured, zero new readbacks. A
+                            # dispatch that paid a compile measures XLA, not
+                            # the model — excluded, like bench warmup. The
+                            # wall is the FULL iteration (t0→t2): under
+                            # async/window:K the dispatch+resolve sliver
+                            # alone excludes the device time entirely.
+                            if not getattr(self._step_fn,
+                                           "last_call_compiled", True):
+                                obs.perf.note_step(
+                                    getattr(self._step_fn, "last_artifact",
+                                            None),
+                                    wall_s=t2 - t0, host_s=t1 - t0,
+                                    dispatch_s=dsp.duration_s)
+                            self._snap_writer.maybe_write(
+                                step=state["neval"])
+                        if self.train_summary is not None:
+                            rec = self.train_summary.should_record
+                            if loss_val is not None and rec("Loss", state):
+                                self.train_summary.add_scalar(
+                                    "Loss", loss_val, state["neval"])
+                            if rec("LearningRate", state):
+                                self.train_summary.add_scalar(
+                                    "LearningRate", lr, state["neval"])
+                            if rec("Throughput", state):
+                                self.train_summary.add_scalar(
+                                    "Throughput",
+                                    self.batch_size / max(t2 - t0, 1e-9),
+                                    state["neval"])
+                        if self._remediation_tick(state, params, opt_state,
+                                                  mstate, health_events,
+                                                  step_time_s=t2 - t1):
+                            box["done"] = True
+                            return
+                        self._fire_mid_epoch(state, params, opt_state, mstate)
+                        if self.end_trigger(state):
+                            box["done"] = True
+                            return
         finally:
             box.update(params=params, opt_state=opt_state, mstate=mstate,
                        nan_streak=nan_streak)
@@ -1771,7 +1787,7 @@ class BaseOptimizer:
             while True:
                 self._step_beacon.pulse()
                 self._check_halt()
-                t0 = time.time()
+                t0 = time.perf_counter()
                 if pending is not None:
                     (k, xs, ys), pending = pending, None
                 else:
@@ -1789,11 +1805,12 @@ class BaseOptimizer:
                     xs = _tmap(lambda a: a[:j], xs)
                     ys = _tmap(lambda a: a[:j], ys)
                     k = j
-                scale = self._remediation_lr_scale  # *1.0 is bitwise-exact
-                lrs = [l * scale for l in optim.current_lr_vector(k)]
-                rngs = engine.next_rng_keys(k)  # one dispatch, same stream
-                t1 = time.time()
-                with obs.span("step/superstep", neval=state["neval"], k=k):
+                with obs.span("step/prepare"):
+                    scale = self._remediation_lr_scale  # *1.0: bitwise-exact
+                    lrs = [l * scale for l in optim.current_lr_vector(k)]
+                    rngs = engine.next_rng_keys(k)  # one dispatch, same stream
+                t1 = time.perf_counter()
+                with obs.span("step/superstep", step_num=state["neval"], k=k):
                     dsp = obs.span("step/dispatch")
                     with dsp:
                         losses_dev, params, opt_state, mstate = \
@@ -1809,135 +1826,140 @@ class BaseOptimizer:
                         losses = np.asarray(losses_dev)
                     if obs.enabled():
                         obs.counter("optim/loss_syncs").inc()
-                t2 = time.time()
-                self.metrics.add("data_time", t1 - t0)
-                self.metrics.add("step_time", t2 - t1)
-                if obs.enabled():
-                    obs.counter("optim/steps").inc(k)
-                    obs.gauge("optim/throughput", unit="samples/s").set(
-                        k * self.batch_size / max(t2 - t0, 1e-9))
-                    # one artifact covers the whole K-step program (a
-                    # clamped j<K dispatch reads ITS program's artifact,
-                    # not the full-K one), so flops over the FULL
-                    # iteration wall IS the fused-dispatch MFU; compile
-                    # dispatches are excluded like bench warmup
-                    if not getattr(self._step_fn, "last_call_compiled",
-                                   True):
-                        obs.perf.note_step(
-                            getattr(self._step_fn, "last_artifact", None),
-                            wall_s=t2 - t0, host_s=t1 - t0,
-                            dispatch_s=dsp.duration_s)
-                    self._snap_writer.maybe_write(step=state["neval"])
-                restored = False
-                health_events = []
-                for i, loss_val in enumerate(losses.tolist()):
-                    if not np.isfinite(loss_val):
-                        nan_streak += 1
+                t2 = time.perf_counter()
+                # the host replay of the resolved [k] losses, then the
+                # triggers at the superstep boundary
+                with obs.span("step/triggers"):
+                    self.metrics.add("data_time", t1 - t0)
+                    self.metrics.add("step_time", t2 - t1)
+                    if obs.enabled():
+                        obs.counter("optim/steps").inc(k)
+                        obs.gauge("optim/throughput", unit="samples/s").set(
+                            k * self.batch_size / max(t2 - t0, 1e-9))
+                        # one artifact covers the whole K-step program (a
+                        # clamped j<K dispatch reads ITS program's artifact,
+                        # not the full-K one), so flops over the FULL
+                        # iteration wall IS the fused-dispatch MFU; compile
+                        # dispatches are excluded like bench warmup
+                        if not getattr(self._step_fn, "last_call_compiled",
+                                       True):
+                            obs.perf.note_step(
+                                getattr(self._step_fn, "last_artifact", None),
+                                wall_s=t2 - t0, host_s=t1 - t0,
+                                dispatch_s=dsp.duration_s)
+                        self._snap_writer.maybe_write(step=state["neval"])
+                    restored = False
+                    health_events = []
+                    for i, loss_val in enumerate(losses.tolist()):
+                        if not np.isfinite(loss_val):
+                            nan_streak += 1
+                            if obs.enabled():
+                                # superstep-vector aware: the host replay of
+                                # the batched [k] readback feeds the recorder
+                                # and detector per microstep
+                                _flight.record("nan", neval=state["neval"],
+                                               epoch=state["epoch"],
+                                               loss=loss_val,
+                                               policy=self.nan_policy,
+                                               superstep_k=k, microstep=i)
+                            if self._loss_monitor is not None:
+                                self._loss_monitor.observe(loss_val,
+                                                           state["neval"])
+                            if self.nan_policy == "error":
+                                raise FloatingPointError(
+                                    f"non-finite loss {loss_val} at iteration "
+                                    f"{state['neval']} — enable "
+                                    "set_nan_policy('skip') to drop such "
+                                    "steps")
+                            if nan_streak > self.max_nan_retries:
+                                raise FloatingPointError(
+                                    f"{nan_streak} consecutive non-finite "
+                                    f"steps (nan_policy='{self.nan_policy}')"
+                                    " — data or hyperparameters are "
+                                    "unrecoverably bad")
+                            if self.nan_policy == "resume":
+                                self.wait_for_checkpoints()  # in-flight writes
+                                snap = self._latest_checkpoint()
+                                if snap is None:
+                                    raise FloatingPointError(
+                                        "non-finite loss with nan_policy="
+                                        "'resume' but no checkpoint saved yet "
+                                        "— call set_checkpoint(...) first")
+                                with open(snap, "rb") as f:
+                                    payload = pickle.load(f)
+                                self.optim_method.state.update(
+                                    payload["optim_host_state"])
+                                params, opt_state, mstate = \
+                                    self._restore_step_state(payload)
+                                # the rest of this group's losses describe
+                                # updates the restore just discarded
+                                self.metrics.add("nan_resumes", 1.0)
+                                obs.instant("step/nan_resume",
+                                            neval=state["neval"])
+                                restored = True
+                                break
+                            # 'skip': the in-scan guard already kept the
+                            # previous state; count the iteration so end
+                            # triggers advance
+                            self.metrics.add("nan_skips", 1.0)
+                            obs.instant("step/nan_skip", neval=state["neval"])
+                            state["neval"] += 1
+                            continue
+                        nan_streak = 0
+                        state["loss"] = loss_val
+                        state["neval"] += 1
+                        state["epoch_finished"] = False
                         if obs.enabled():
-                            # superstep-vector aware: the host replay of
-                            # the batched [k] readback feeds the recorder
-                            # and detector per microstep
-                            _flight.record("nan", neval=state["neval"],
-                                           epoch=state["epoch"],
-                                           loss=loss_val,
-                                           policy=self.nan_policy,
+                            _flight.record("step", neval=state["neval"],
+                                           epoch=state["epoch"], loss=loss_val,
                                            superstep_k=k, microstep=i)
                         if self._loss_monitor is not None:
-                            self._loss_monitor.observe(loss_val,
-                                                       state["neval"])
-                        if self.nan_policy == "error":
-                            raise FloatingPointError(
-                                f"non-finite loss {loss_val} at iteration "
-                                f"{state['neval']} — enable "
-                                f"set_nan_policy('skip') to drop such steps")
-                        if nan_streak > self.max_nan_retries:
-                            raise FloatingPointError(
-                                f"{nan_streak} consecutive non-finite steps "
-                                f"(nan_policy='{self.nan_policy}') — data or "
-                                "hyperparameters are unrecoverably bad")
-                        if self.nan_policy == "resume":
-                            self.wait_for_checkpoints()  # in-flight writes
-                            snap = self._latest_checkpoint()
-                            if snap is None:
-                                raise FloatingPointError(
-                                    "non-finite loss with nan_policy="
-                                    "'resume' but no checkpoint saved yet "
-                                    "— call set_checkpoint(...) first")
-                            with open(snap, "rb") as f:
-                                payload = pickle.load(f)
-                            self.optim_method.state.update(
-                                payload["optim_host_state"])
-                            params, opt_state, mstate = \
-                                self._restore_step_state(payload)
-                            # the rest of this group's losses describe
-                            # updates the restore just discarded
-                            self.metrics.add("nan_resumes", 1.0)
-                            obs.instant("step/nan_resume",
-                                        neval=state["neval"])
-                            restored = True
-                            break
-                        # 'skip': the in-scan guard already kept the
-                        # previous state; count the iteration so end
-                        # triggers advance
-                        self.metrics.add("nan_skips", 1.0)
-                        obs.instant("step/nan_skip", neval=state["neval"])
-                        state["neval"] += 1
+                            health_events.extend(self._loss_monitor.observe(
+                                loss_val, state["neval"]))
+                        if self.train_summary is not None:
+                            rec = self.train_summary.should_record
+                            if rec("Loss", state):
+                                self.train_summary.add_scalar(
+                                    "Loss", loss_val, state["neval"])
+                            if rec("LearningRate", state):
+                                self.train_summary.add_scalar(
+                                    "LearningRate", lrs[i], state["neval"])
+                            if rec("Throughput", state):
+                                self.train_summary.add_scalar(
+                                    "Throughput",
+                                    k * self.batch_size / max(t2 - t0, 1e-9),
+                                    state["neval"])
+                    if restored:
+                        # the group's pre-NaN spike/plateau events describe
+                        # losses that really happened — the policy must see
+                        # them, or a diverging run that interleaves spikes
+                        # with NaN restores starves max_spikes forever and
+                        # loops checkpoint-restore indefinitely
+                        if self._remediation_tick(state, params, opt_state,
+                                                  mstate, health_events,
+                                                  step_time_s=t2 - t1):
+                            box["done"] = True
+                            return
                         continue
-                    nan_streak = 0
-                    state["loss"] = loss_val
-                    state["neval"] += 1
-                    state["epoch_finished"] = False
-                    if obs.enabled():
-                        _flight.record("step", neval=state["neval"],
-                                       epoch=state["epoch"], loss=loss_val,
-                                       superstep_k=k, microstep=i)
-                    if self._loss_monitor is not None:
-                        health_events.extend(self._loss_monitor.observe(
-                            loss_val, state["neval"]))
-                    if self.train_summary is not None:
-                        rec = self.train_summary.should_record
-                        if rec("Loss", state):
-                            self.train_summary.add_scalar(
-                                "Loss", loss_val, state["neval"])
-                        if rec("LearningRate", state):
-                            self.train_summary.add_scalar(
-                                "LearningRate", lrs[i], state["neval"])
-                        if rec("Throughput", state):
-                            self.train_summary.add_scalar(
-                                "Throughput",
-                                k * self.batch_size / max(t2 - t0, 1e-9),
-                                state["neval"])
-                if restored:
-                    # the group's pre-NaN spike/plateau events describe
-                    # losses that really happened — the policy must see
-                    # them, or a diverging run that interleaves spikes
-                    # with NaN restores starves max_spikes forever and
-                    # loops checkpoint-restore indefinitely
-                    if self._remediation_tick(state, params, opt_state,
-                                              mstate, health_events,
+                    if self._profiler is not None:
+                        self._profiler.maybe_tick(state["neval"])
+                    if self._remediation_tick(state, params, opt_state, mstate,
+                                              health_events,
                                               step_time_s=t2 - t1):
                         box["done"] = True
                         return
-                    continue
-                if self._profiler is not None:
-                    self._profiler.maybe_tick(state["neval"])
-                if self._remediation_tick(state, params, opt_state, mstate,
-                                          health_events,
-                                          step_time_s=t2 - t1):
-                    box["done"] = True
-                    return
-                # checkpoint/validation/end triggers evaluate ONCE at the
-                # superstep boundary, where params and the iteration
-                # counter are consistent: clamping already aligned every
-                # counter-driven firing point to a boundary, and a
-                # loss-driven trigger (which the probe cannot foresee)
-                # defers at most K-1 steps — it must never pair interior
-                # counters with post-superstep params in a checkpoint
-                if self._fire_mid_epoch(state, params, opt_state, mstate):
-                    pass
-                if self.end_trigger(state):
-                    box["done"] = True
-                    return
+                    # checkpoint/validation/end triggers evaluate ONCE at the
+                    # superstep boundary, where params and the iteration
+                    # counter are consistent: clamping already aligned every
+                    # counter-driven firing point to a boundary, and a
+                    # loss-driven trigger (which the probe cannot foresee)
+                    # defers at most K-1 steps — it must never pair interior
+                    # counters with post-superstep params in a checkpoint
+                    if self._fire_mid_epoch(state, params, opt_state, mstate):
+                        pass
+                    if self.end_trigger(state):
+                        box["done"] = True
+                        return
         finally:
             box.update(params=params, opt_state=opt_state, mstate=mstate,
                        nan_streak=nan_streak)
@@ -2221,23 +2243,15 @@ class DistriOptimizer(BaseOptimizer):
         from ..utils.compat import shard_map
         from ..nn.sparse import embedding_grad_rows
         from ..parallel.allreduce import sparse_embedding_grad_allreduce
-        model, criterion = self.model, self.criterion
-        reg_tree = regularizer_tree(model)
         clip_const, clip_norm = self.clip_const, self.clip_norm
         optim = self.optim_method
-        frozen_mask = _frozen_mask(model)
+        frozen_mask = _frozen_mask(self.model)
+        loss_fn = _loss_fn(self.model, self.criterion)
         mesh = self.mesh
         path, vocab = self._sparse_embedding_path()
         superstep_k = self.superstep
 
-        def loss_fn(params, mstate, x, y, rng):
-            out, new_state = model.apply(params, mstate, x, training=True,
-                                         rng=rng)
-            loss = criterion._forward(out, y)
-            if reg_tree:
-                loss = loss + regularization_loss(reg_tree, params)
-            return loss, new_state
-
+        @jax.named_scope("grad_exchange")
         def exchange(grads, x):
             ids = jnp.clip(x.reshape(-1).astype(jnp.int32) - 1, 0,
                            vocab - 1)
@@ -2275,23 +2289,23 @@ class DistriOptimizer(BaseOptimizer):
                 loss_fn, has_aux=True)(params, mstate, x, y, rng)
             grads = exchange(grads, x)
             grads = _clip_grads(grads, clip_const, clip_norm)
-            if frozen_mask is not None:
-                grads = _tmap(lambda g, m: g * m, grads, frozen_mask)
-            new_params, new_opt = optim.update(grads, params, opt_state,
-                                               lr)
-            if frozen_mask is not None:
-                new_params = _tmap(
-                    lambda n, o, m: jnp.where(m > 0, n, o),
-                    new_params, params, frozen_mask)
-            loss = jax.lax.pmean(loss, "data")
-            new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"),
-                               new_mstate)
-            # same post-pmean NaN guard as the other distributed paths
-            ok = jnp.isfinite(loss)
-            pick = lambda new, old: _tmap(
-                lambda a, b: jnp.where(ok, a, b), new, old)
-            return (loss, pick(new_params, params),
-                    pick(new_opt, opt_state), pick(new_mstate, mstate))
+            with jax.named_scope("optim_update"):
+                if frozen_mask is not None:
+                    grads = _tmap(lambda g, m: g * m, grads, frozen_mask)
+                new_params, new_opt = optim.update(grads, params, opt_state,
+                                                   lr)
+                if frozen_mask is not None:
+                    new_params = _tmap(
+                        lambda n, o, m: jnp.where(m > 0, n, o),
+                        new_params, params, frozen_mask)
+                loss = jax.lax.pmean(loss, "data")
+                new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"),
+                                   new_mstate)
+                # guarded after the pmean, so every shard takes the same
+                # branch — no divergence across the mesh
+                return (loss,) + _guarded(
+                    loss, (new_params, new_opt, new_mstate),
+                    (params, opt_state, mstate))
 
         if superstep_k > 1:
             sharded = shard_map(
@@ -2322,8 +2336,7 @@ class DistriOptimizer(BaseOptimizer):
 
         from ..utils.compat import shard_map
         from jax.flatten_util import ravel_pytree
-        model, criterion = self.model, self.criterion
-        reg_tree = regularizer_tree(model)
+        model = self.model
         clip_const, clip_norm = self.clip_const, self.clip_norm
         arp, flat = self._arp, self._flat
         mesh = self.mesh
@@ -2335,14 +2348,10 @@ class DistriOptimizer(BaseOptimizer):
                          model.params, fm)
             flat_mask = flat.flatten(full)
 
+        tree_loss_fn = _loss_fn(model, self.criterion)
+
         def loss_fn(flat_w, mstate, x, y, rng):
-            params = flat.unflatten(flat_w)
-            out, new_state = model.apply(params, mstate, x, training=True,
-                                         rng=rng)
-            loss = criterion._forward(out, y)
-            if reg_tree:
-                loss = loss + regularization_loss(reg_tree, params)
-            return loss, new_state
+            return tree_loss_fn(flat.unflatten(flat_w), mstate, x, y, rng)
 
         superstep_k = self.superstep
 
@@ -2351,21 +2360,21 @@ class DistriOptimizer(BaseOptimizer):
             (loss, new_mstate), gflat = jax.value_and_grad(
                 loss_fn, has_aux=True)(flat_w, mstate, x, y, rng)
             gflat = _clip_grads(gflat, clip_const, clip_norm)
-            if flat_mask is not None:
-                gflat = gflat * flat_mask
-            new_flat, new_opt = arp.update(gflat, flat_w, opt_slice, lr,
-                                           traced_steps=superstep_k)
-            if flat_mask is not None:
-                new_flat = jnp.where(flat_mask > 0, new_flat, flat_w)
-            loss = jax.lax.pmean(loss, "data")
-            new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"), new_mstate)
-            # same in-step NaN guard as the local path (post-pmean, so every
-            # shard takes the same branch — no divergence across the mesh)
-            ok = jnp.isfinite(loss)
-            pick = lambda new, old: _tmap(
-                lambda a, b: jnp.where(ok, a, b), new, old)
-            return (loss, pick(new_flat, flat_w), pick(new_opt, opt_slice),
-                    pick(new_mstate, mstate))
+            with jax.named_scope("optim_update"):
+                if flat_mask is not None:
+                    gflat = gflat * flat_mask
+                new_flat, new_opt = arp.update(gflat, flat_w, opt_slice, lr,
+                                               traced_steps=superstep_k)
+                if flat_mask is not None:
+                    new_flat = jnp.where(flat_mask > 0, new_flat, flat_w)
+                loss = jax.lax.pmean(loss, "data")
+                new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"),
+                                   new_mstate)
+                # guarded after the pmean, so every shard takes the same
+                # branch — no divergence across the mesh
+                return (loss,) + _guarded(
+                    loss, (new_flat, new_opt, new_mstate),
+                    (flat_w, opt_slice, mstate))
 
         opt_specs = arp.state_specs()
         mstate_specs = _tmap(lambda _: P(), self.model.state)

@@ -259,9 +259,10 @@ class AllReduceParameter:
                 obs.counter("collective/grad_wire_traced_bytes",
                             unit="B").inc(
                     float(g.size * g.dtype.itemsize) * traced_steps)
-            pieces = lax.all_to_all(
-                g.reshape(self.n, self.flat.shard_size), self.axis,
-                split_axis=0, concat_axis=0)
+            with jax.named_scope("grad_exchange"):
+                pieces = lax.all_to_all(
+                    g.reshape(self.n, self.flat.shard_size), self.axis,
+                    split_axis=0, concat_axis=0)
             gslice = jnp.sum(
                 FP16CompressPolicy.decompress(pieces, dtype), axis=0
             ) / self.n
@@ -274,13 +275,15 @@ class AllReduceParameter:
                             unit="B").inc(
                     float(g.size * g.dtype.itemsize) * traced_steps)
             # aggregated gradient for my slice (mean over data shards)
-            gslice = lax.psum_scatter(g, self.axis, scatter_dimension=0,
-                                      tiled=True)
+            with jax.named_scope("grad_exchange"):
+                gslice = lax.psum_scatter(g, self.axis, scatter_dimension=0,
+                                          tiled=True)
             gslice = FP16CompressPolicy.decompress(gslice, dtype) / self.n
         wslice = lax.dynamic_slice_in_dim(
             params_flat, i * self.flat.shard_size, self.flat.shard_size)
         new_slice, new_state = self.optim.update(gslice, wslice, opt_state, lr)
-        new_full = lax.all_gather(new_slice, self.axis, tiled=True)
+        with jax.named_scope("grad_exchange"):
+            new_full = lax.all_gather(new_slice, self.axis, tiled=True)
         return new_full, new_state
 
 
