@@ -27,10 +27,18 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# ``checkpoint_name``s of the two residuals the forward kernel itself
+# computes. A ``jax.checkpoint`` whose policy saves these names
+# (nn.attention.remat_block) keeps them, so its backward pass does not run
+# the forward kernel a second time only to get them back.
+FLASH_OUT_NAME = "flash_attention_out"
+FLASH_LSE_NAME = "flash_attention_lse"
 
 
 def _pick_block(t: int, target: int) -> int:
@@ -341,6 +349,23 @@ def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+    # `lse` is lane 0 of the kernel's 128-lane output. Tied to `o`, the
+    # slice runs before anything reads `o`; left free, XLA may put it off
+    # until the backward pass, and what is held meanwhile (by a remat
+    # policy that saves `lse`, or as a plain residual) is the 128-lane
+    # array. The barrier moves no data.
+    o, lse = jax.lax.optimization_barrier((o, lse))
+    # The two residuals the kernel itself computed are named for remat
+    # policies (q, k, v are not: a checkpointed caller recomputes them from
+    # its own input). `o` is named in the merged (B, T, H*D) form, the one
+    # the caller's output projection reads: dense in HBM, where a
+    # (..., T, D) array with D < 128 is padded to 128 lanes for as long as
+    # it is kept. XLA cancels the way back against the caller's own merge.
+    b, h, t, d = o.shape
+    o = checkpoint_name(o.transpose(0, 2, 1, 3).reshape(b, t, h * d),
+                        FLASH_OUT_NAME)
+    o = o.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return o, (q, k, v, o, lse)
 
 

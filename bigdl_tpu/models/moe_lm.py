@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..nn.attention import (LayerNormalization, Transformer,
-                            TransformerBlock, embed_ids)
+                            TransformerBlock, embed_ids, remat_block)
 from ..nn.moe import MixtureOfExperts
 from ..nn.module import Module
 from ..utils.table import Table
@@ -41,9 +41,11 @@ class MoETransformerLM(Module):
             raise ValueError(f"pos_encoding must be 'sinusoidal' or "
                              f"'rope', got {pos_encoding!r}")
         self.pos_encoding = pos_encoding
-        # jax.checkpoint per block: the router's dispatch/combine one-hots
-        # are (T, E, capacity)-sized residuals — at bench scale ~GBs the
-        # backward would otherwise keep live (mirrors Transformer's remat)
+        # nn.attention.remat_block per block, as Transformer's remat: the
+        # router's dispatch/combine one-hots are (T, E, capacity)-sized
+        # residuals — at bench scale ~GBs the backward would otherwise keep
+        # live. Kept per layer: the block input and the flash kernel's
+        # output and logsumexp; everything else is recomputed
         self.remat = remat
         self.mode = "lm"  # the Transformer inference machinery's guard
         self.blocks = []
@@ -98,14 +100,14 @@ class MoETransformerLM(Module):
                 def run(p, hh, blk=blk, r=r):
                     return blk.apply_with_aux(p, hh, mask, training, r)
                 if self.remat:
-                    run = jax.checkpoint(run)
+                    run = remat_block(run)
                 h, a = run(params[f"block{i}"], h)
                 aux = aux + a
             else:
                 def run(p, hh, blk=blk, r=r):
                     return blk._apply(p, {}, Table(hh, mask), training, r)
                 if self.remat:
-                    run = jax.checkpoint(run)
+                    run = remat_block(run)
                 h = run(params[f"block{i}"], h)
         h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None)
         return h, aux

@@ -7,11 +7,14 @@ model: the driver compile-checks its forward single-chip and its full
 sharded train step on an N-device mesh.
 
 TPU memory story (round 3): LM-mode self-attention runs the fused Pallas
-flash path (O(T) memory — no (B,H,T,T) score matrix), ``remat=True`` wraps
-each block in ``jax.checkpoint``, and :func:`lm_loss_chunked` fuses the tied
-vocab projection with the softmax-CE loss in rematerialised sequence chunks
-so the (B,T,vocab) logits tensor never exists. Together these take the
-B16/T1024 12-layer config from HBM-OOM on a 16 GB v5e to fitting with room.
+flash path (O(T) memory — no (B,H,T,T) score matrix), ``remat=True`` runs
+each block under ``jax.checkpoint`` (the backward pass recomputes the block
+from its input; only that input and the flash kernel's output and logsumexp
+are kept, two (B,T,H) tensors a layer), and :func:`lm_loss_chunked` fuses
+the tied vocab projection with the softmax-CE loss in rematerialised
+sequence chunks so the (B,T,vocab) logits tensor never exists. Together
+these take the B16/T1024 12-layer config from HBM-OOM on a 16 GB v5e to
+fitting with room.
 """
 from __future__ import annotations
 
@@ -34,7 +37,12 @@ def TransformerLM(vocab_size: int = 32000, hidden_size: int = 512,
     cache; see the grouped branch of Attention.decode_chunk).
     ``pos_encoding='rope'`` swaps the
     additive sinusoidal PE for rotary embeddings on q/k (relative
-    positions; the KV cache stores rotated keys)."""
+    positions; the KV cache stores rotated keys).
+    ``remat=True`` (``nn.attention.remat_block``): the backward pass
+    recomputes each block from its input. Kept per layer are that input
+    and, where the flash kernel ran, its output and logsumexp, so the
+    forward kernel is not run a second time: two (B,T,H) tensors and
+    (B,heads,T) f32 a layer, one (B,T,H) on the einsum path."""
     return Transformer(vocab_size=vocab_size, hidden_size=hidden_size,
                        num_heads=num_heads, filter_size=filter_size,
                        num_hidden_layers=num_layers,

@@ -31,6 +31,7 @@ def _fused_qkv_enabled():
 
 from .module import Module
 from .norm import LayerNormalization
+from ..kernels.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
 from ..utils.table import Table
 
 
@@ -623,6 +624,21 @@ class TransformerBlock(Module):
         return self._ffn_sublayer(params, h_t + a), k_pages, v_pages
 
 
+def remat_block(run):
+    """What ``remat=True`` means in this package: ``run`` (one block) under
+    ``jax.checkpoint``. The backward pass recomputes the block from its
+    input, except what a Pallas flash forward produced: the kernel's output
+    ``o`` and its logsumexp are kept, so the forward kernel runs once a
+    layer and not twice. Per layer that keeps the block input and ``o``
+    (two ``[B, T, H]`` tensors, was one; ``o`` in the merged form the
+    output projection reads) plus ``[B, heads, T]`` of f32. On the einsum
+    path no residual carries these names, nothing is kept, and the whole
+    block is recomputed."""
+    return jax.checkpoint(
+        run, policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME))
+
+
 class Transformer(Module):
     """Transformer (nn/Transformer.scala). ``mode='lm'`` (decoder-only causal
     LM over token ids) or ``mode='translation'`` (encoder-decoder; input
@@ -638,10 +654,13 @@ class Transformer(Module):
                  ffn_activation: str = "relu", name=None):
         """``use_flash``: LM-mode self-attention goes through the fused
         O(T)-memory flash path (Pallas on TPU) instead of materialising the
-        (B,H,T,T) score matrix. ``remat``: each block is wrapped in
-        ``jax.checkpoint`` so the backward pass recomputes block internals
-        instead of storing them — activation memory drops from
-        O(layers * intermediates) to O(layers * block_inputs)."""
+        (B,H,T,T) score matrix. ``remat``: each block runs under
+        :func:`remat_block`, so the backward pass recomputes block internals
+        (layer norms, projections, the head split, the FFN) instead of
+        storing them. Kept per layer: the block input and, where the flash
+        kernel ran, its output and logsumexp (the kernel is not run again)
+        — activation memory drops from O(layers * intermediates) to two
+        (B,T,H) tensors a layer, one on the einsum path."""
         super().__init__(name=name)
         self.vocab_size, self.hidden_size = vocab_size, hidden_size
         self.mode, self.max_len = mode, max_len
@@ -705,7 +724,7 @@ class Transformer(Module):
                                                                enc_mask)
                 return blk._apply(p, {}, arg, training, r)
             if self.remat:
-                run = jax.checkpoint(run)
+                run = remat_block(run)
             h = run(params[f"{prefix}{i}"], h)
         return h
 

@@ -1,6 +1,9 @@
 """LM-level kernel-integration tests (flash path in the model, remat
 equivalence, chunked CE loss) — split from test_kernels.py so xdist
 loadfile sharding overlaps these compile-heavy checks with the rest."""
+import contextlib
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -82,6 +85,69 @@ def test_moe_lm_remat_matches_plain():
     for a, b in zip(jax.tree_util.tree_leaves(g0),
                     jax.tree_util.tree_leaves(g1)):
         assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+def _kernel_calls(jaxpr_text):
+    """How often each flash kernel appears in a jaxpr's text."""
+    return {k: len(re.findall(rf"name={k}\b", jaxpr_text))
+            for k in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")}
+
+
+def _flash_lm(family, remat, layers):
+    from bigdl_tpu.models import MoETransformerLM, TransformerLM
+    kw = dict(vocab_size=67, hidden_size=32, num_heads=2, filter_size=64,
+              num_layers=layers, max_len=128, use_flash=True, remat=remat)
+    if family == "moe":
+        return MoETransformerLM(n_experts=4, moe_every=2,
+                                capacity_factor=4.0, **kw)
+    return TransformerLM(**kw)
+
+
+@pytest.mark.parametrize("family,replicated", [
+    ("dense", False), ("moe", False), ("dense", True)])
+def test_remat_keeps_what_the_flash_kernel_made(monkeypatch, family,
+                                                replicated):
+    """With the flash kernel, ``remat=True`` gives the loss and every
+    gradient leaf of ``remat=False``, and the backward pass does not run
+    the forward kernel again: ``o`` and ``lse`` are kept by name
+    (``nn.attention.remat_block``), the rest of the block is recomputed.
+    ``replicated``: traced as ``DistriOptimizer``'s replicated mode does,
+    under ``data_parallel_context`` — the kernel and its named residuals
+    sit in a ``shard_map`` of their own, and JAX takes the policy into
+    it."""
+    from bigdl_tpu.parallel.flash import data_parallel_context
+    from bigdl_tpu.parallel.mesh import data_parallel_mesh
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    layers = 2
+    ids = jnp.asarray(np.random.RandomState(1).randint(
+        1, 67, size=(2, 128)).astype(np.int32))
+    plain, remat = (_flash_lm(family, r, layers) for r in (False, True))
+    params, _ = plain.init(jax.random.PRNGKey(0))
+
+    def loss(m):
+        def f(p):
+            if family == "moe":
+                h, aux = m.hidden_states(p, ids, training=False)
+                return jnp.sum(jnp.tanh(h * 0.01)) + 0.1 * aux
+            out, _ = m.apply(p, {}, ids, training=False)
+            return jnp.sum(jnp.tanh(out * 0.01))
+        return jax.jit(jax.value_and_grad(f))
+
+    ctx = data_parallel_context(data_parallel_mesh(2), "data") \
+        if replicated else contextlib.nullcontext()
+    with ctx:
+        (l0, g0), (l1, g1) = loss(plain)(params), loss(remat)(params)
+        texts = [str(jax.make_jaxpr(loss(m))(params))
+                 for m in (plain, remat)]
+    assert np.allclose(float(l0), float(l1), atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(g0),
+                    jax.tree_util.tree_leaves(g1)):
+        assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+    want = {"flash_fwd": layers, "flash_bwd_dkv": layers,
+            "flash_bwd_dq": layers}
+    assert [_kernel_calls(t) for t in texts] == [want, want]
+    # each block is still recomputed: remat did not become a no-op
+    assert len(re.findall(r"= remat\w*\[", texts[1])) == layers
 
 
 def test_lm_loss_chunked_matches_full_logits():

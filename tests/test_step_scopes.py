@@ -114,6 +114,23 @@ def test_the_recomputation_frame_appears_only_with_remat():
     assert not has(names, "checkpoint")
 
 
+def test_remat_recomputes_the_block_but_not_the_flash_forward(monkeypatch):
+    """With ``remat`` and the flash kernel the recomputation frame still
+    holds the block's projections and FFN and holds no ``flash_fwd``: the
+    kernel's ``o`` and ``lse`` were kept (``nn.attention.remat_block``).
+    The forward and the backward kernels keep their names."""
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    names = op_names(LocalOptimizer, toy_lm(), lm_samples(),
+                     nn.LMCriterion(padding_value=0))
+    assert has(names, "rematted_computation", "block0", "attn")
+    assert has(names, "rematted_computation", "block1", "attn")
+    assert has(names, "rematted_computation", "block1", "ffn")
+    assert not has(names, "rematted_computation", "flash_fwd")
+    assert has(names, "jvp(", "block0", "attn", "flash_fwd")
+    assert has(names, "transpose(", "block0", "attn", "flash_bwd_dkv")
+    assert has(names, "transpose(", "block1", "attn", "flash_bwd_dq")
+
+
 def test_the_sparse_step_carries_the_same_four_scope_names():
     """``_build_sparse_step`` wants the ids to be the model's input, so
     its toy is a ``Sequential`` whose children carry their container keys
