@@ -3,13 +3,29 @@
 Replaces the reference's O(T^2)-memory attention (the reference materialises
 the full score matrix — ``nn/Attention.scala`` builds it with two MM layers)
 with the online-softmax tiling of FlashAttention: Q/K/V stream through VMEM
-in (block x d) tiles, scores never leave VMEM, and the output is rescaled
+in (block x lanes) tiles, scores never leave VMEM, and the output is rescaled
 incrementally — O(T) HBM traffic per head.
 
 Forward and backward are both Pallas kernels wired through ``jax.custom_vjp``
 (flash-attention-2 split: the backward recomputes probabilities per tile from
 the saved logsumexp; one kernel accumulates dK/dV over query tiles, one
 accumulates dQ over key tiles).
+
+Layout. The kernels index their operands as ROWS, ``[N, T, heads * D]``: the
+form a projection writes and the output projection reads, so nothing is
+copied on either side of the call. A block is ``(1, block, lanes)`` with
+``lanes = g * D`` a multiple of 128 holding ``g`` whole heads
+(:func:`heads_per_block`: two 64-wide heads, or one head of 128 or 256), and
+one grid step does those ``g`` heads' work, one head after the other in a
+loop on the device (:func:`_each_head`; unrolled, the two-head bodies of a
+24-layer step cost ten seconds of set-up more, measured on the chip's host).
+Inside the block a head is told apart by a lane mask and not by a lane slice: its q (and dO) are zeroed on
+the other heads' lanes and contracted over all of them, and an operand that
+gives a ``(., D)`` product (v, k, q, dO) is zeroed likewise, so the product
+lands on the head's own lanes of the 128-lane accumulator and adds nothing
+elsewhere. The ``[B, H, T, D]`` entries (decode caches, ring blocks) are the
+same kernels over ``[B * H, T, D]``: one head a row, its D the whole minor
+dimension.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
   * the streaming axis is the innermost grid dimension, so the VMEM scratch
@@ -41,17 +57,30 @@ FLASH_OUT_NAME = "flash_attention_out"
 FLASH_LSE_NAME = "flash_attention_lse"
 
 
+def heads_per_block(heads: int, d: int):
+    """How many heads one lane block of a ``[.., T, heads * d]`` array
+    holds, or None where no block of whole heads is a multiple of 128
+    lanes (d = 80 or 96, three heads of 64): such shapes go through the
+    ``[B, H, T, D]`` entry."""
+    if d % 128 == 0:
+        return 1
+    g = 128 // d
+    return g if 128 % d == 0 and heads % g == 0 else None
+
+
 def _pick_block(t: int, target: int) -> int:
     """Block size: multiple of 128, capped at the (padded) sequence length."""
     t_pad = (t + 127) // 128 * 128
     return min(target, t_pad)
 
 
-def _pad_t(x, t_pad):
-    t = x.shape[2]
+def _pad_t(x, t_pad, axis=1):
+    t = x.shape[axis]
     if t == t_pad:
         return x
-    return jnp.pad(x, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, t_pad - t)
+    return jnp.pad(x, pad)
 
 
 def _mm(a, b, ta=False, tb=False):
@@ -63,12 +92,47 @@ def _mm(a, b, ta=False, tb=False):
     return out
 
 
+def _head_lanes(shape, h, d, g):
+    """Which lanes of a ``(rows, g * d)`` block are head ``h``'s; None
+    where the block is one head's."""
+    if g == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jnp.logical_and(lane >= h * d, lane < (h + 1) * d)
+
+
+def _own(x, lanes):
+    """``x`` with the other heads' lanes zeroed."""
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _each_head(g, head):
+    """``head(h)`` for each of the ``g`` heads of a block. Where g > 1 the
+    heads are a loop on the device and ``h`` is traced: the body is traced
+    and lowered once, which a step of many layers pays for in set-up."""
+    if g == 1:
+        head(0)
+    else:
+        jax.lax.fori_loop(0, g, lambda h, carry: (head(h), carry)[1], 0)
+
+
+def _score_mask(q_off, k_off, block_q, block_k, kv_len, causal):
+    """Which (query row, key column) pairs of one tile take part."""
+    col = k_off + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    mask = col < kv_len
+    if causal:
+        row = q_off + jax.lax.broadcasted_iota(jnp.int32,
+                                               (block_q, block_k), 0)
+        mask = jnp.logical_and(mask, col <= row)
+    return mask
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, block_q, block_k, causal, kv_len, nk,
+                *, d, g, scale, block_q, block_k, causal, kv_len, nk,
                 q_offset=0):
     i = pl.program_id(2)   # query-block index
     j = pl.program_id(3)   # key-block index (sequential, innermost)
@@ -93,33 +157,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # operands run the MXU at a fraction of bf16 throughput (the
         # round-3 fused-matmul A/B measured the all-f32 form 2.2x slower).
         # f32 is reserved for the softmax statistics math.
-        s = _mm(q_ref[0, 0], k_ref[0, 0], tb=True) * scale   # (bq, bk) f32
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        mask = _score_mask(q_off, k_off, block_q, block_k, kv_len, causal)
 
-        col = k_off + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = q_off + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, col <= row)
-        s = jnp.where(mask, s, NEG_INF)
+        def head(h):
+            lanes = _head_lanes(q.shape, h, d, g)
+            s = _mm(_own(q, lanes), k, tb=True) * scale      # (bq, bk) f32
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[:, :1]                      # (bq, 1)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)                     # (bq, bk)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + _mm(p.astype(v_ref.dtype),
-                                              v_ref[0, 0])
-        m_ref[:] = jnp.broadcast_to(m_cur, m_ref.shape)
+            m_prev = m_ref[h, :, :1]                   # (bq, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)                     # (bq, bk)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[h] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
+            # (bq, g*d): nothing outside the head's own lanes, which
+            # alone are rescaled
+            pv = _mm(p.astype(v.dtype),
+                     _own(v, _head_lanes(v.shape, h, d, g)))
+            keep = alpha if lanes is None else jnp.where(lanes, alpha, 1.0)
+            acc_ref[:] = acc_ref[:] * keep + pv
+
+        _each_head(g, head)
 
     @pl.when(j == nk - 1)
     def _finish():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)       # fully-masked rows → 0
-        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        lse = m_ref[:, :1] + jnp.log(jnp.maximum(l, 1e-30))
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        def head(h):
+            # the head's lanes of the accumulator become its output
+            l = l_ref[h, :, :1]
+            safe_l = jnp.where(l == 0.0, 1.0, l)       # fully-masked rows → 0
+            o_h = acc_ref[:] / safe_l
+            lanes = _head_lanes(o_h.shape, h, d, g)
+            acc_ref[:] = o_h if lanes is None else jnp.where(lanes, o_h,
+                                                             acc_ref[:])
+            lse = m_ref[h, :, :1] + jnp.log(jnp.maximum(l, 1e-30))
+            lse_ref[0, h] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+
+        _each_head(g, head)
+        o_ref[0] = acc_ref[:].astype(o_ref.dtype)
 
 
 def _sds(shape, dtype, vma):
@@ -130,10 +205,26 @@ def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               vma=None, q_offset=0, kv_len=None):
-    b, h, t_q, d = q.shape
-    t_kv = k.shape[2]
+def _block_heads(heads, d):
+    """Heads a lane block: :func:`heads_per_block`, and one head where its
+    ``d`` is the whole minor dimension (the ``[B * H, T, D]`` callers)."""
+    g = 1 if heads == 1 else heads_per_block(heads, d)
+    if g is None:
+        raise ValueError(
+            f"{heads} heads of {d} fill no 128-lane block: split the heads "
+            "and use flash_attention_fused")
+    return g
+
+
+def _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k, interpret,
+              vma=None, q_offset=0, kv_len=None):
+    """q ``[N, Tq, heads*D]``, k/v ``[N, Tkv, heads*D]`` → o like q and
+    lse ``[N, heads, Tq]``."""
+    n, t_q, c = q.shape
+    d = c // heads
+    g = _block_heads(heads, d)
+    w = g * d
+    t_kv = k.shape[1]
     # kv_len < t_kv: attend only the first kv_len positions (the VALID
     # prefix of a decode cache — chunked prefill). The GRID is bounded
     # to ceil(kv_len / bk) key blocks, so the garbage tail of the cache
@@ -150,34 +241,32 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     nq = tq_pad // bq
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, block_q=bq, block_k=bk, causal=causal,
-        kv_len=kv_len, nk=nk, q_offset=q_offset)
+        _fwd_kernel, d=d, g=g, scale=scale, block_q=bq, block_k=bk,
+        causal=causal, kv_len=kv_len, nk=nk, q_offset=q_offset)
+    q_spec = pl.BlockSpec((1, bq, w), lambda b_, p_, i, j: (b_, i, p_))
+    k_spec = pl.BlockSpec((1, bk, w), lambda b_, p_, i, j: (b_, j, p_))
     o, lse = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, j, 0)),
-        ],
+        grid=(n, heads // g, nq, nk),
+        in_specs=[q_spec, k_spec, k_spec],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq, 128),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
+            q_spec,
+            pl.BlockSpec((1, g, bq, 128),
+                         lambda b_, p_, i, j: (b_, p_, i, 0)),
         ],
         out_shape=[
-            _sds((b, h, tq_pad, d), q.dtype, vma),
-            _sds((b, h, tq_pad, 128), jnp.float32, vma),
+            _sds((n, tq_pad, c), q.dtype, vma),
+            _sds((n, heads, tq_pad, 128), jnp.float32, vma),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, w), jnp.float32),
+            pltpu.VMEM((g, bq, 128), jnp.float32),
+            pltpu.VMEM((g, bq, 128), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
     )(qp, kp, vp)
-    return o[:, :, :t_q], lse[:, :, :t_q, 0]
+    return o[:, :t_q], lse[:, :, :t_q, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +275,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 def _bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dk_ref, dv_ref, dk_acc, dv_acc,
-                   *, scale, block_q, block_k, causal, kv_len, nq):
+                   *, d, g, scale, block_q, block_k, causal, kv_len, nq):
     j = pl.program_id(2)   # key-block (parallel)
     i = pl.program_id(3)   # query-block (sequential, innermost)
 
@@ -204,34 +293,37 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # bf16-operand MXU contractions with f32 accumulation (see the
         # forward kernel's dtype note); p/ds are computed in f32 and cast
         # back to the wire dtype only as matmul operands
-        lse = lse_ref[0, 0][:, :1]                 # (bq, 1)
-        delta = delta_ref[0, 0][:, :1]             # (bq, 1)
-        dt = q_ref.dtype
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        dt = q.dtype
+        mask = _score_mask(q_off, k_off, block_q, block_k, kv_len, causal)
 
-        s = _mm(q_ref[0, 0], k_ref[0, 0], tb=True) * scale   # (bq, bk)
-        col = k_off + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = q_off + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, col <= row)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (bq, bk) f32
+        def head(h):
+            # q and dO on the head's lanes alone: the scores contract
+            # over them, and dV, dK land on them
+            lanes = _head_lanes(q.shape, h, d, g)
+            q_h, do_h = _own(q, lanes), _own(do, lanes)
+            lse = lse_ref[0, h, :, :1]                 # (bq, 1)
+            delta = delta_ref[0, h, :, :1]             # (bq, 1)
 
-        dv_acc[:] += _mm(p.astype(dt), do_ref[0, 0], ta=True)  # (bk, d)
-        dp = _mm(do_ref[0, 0], v_ref[0, 0], tb=True)           # (bq, bk)
-        ds = p * (dp - delta) * scale
-        dk_acc[:] += _mm(ds.astype(dt), q_ref[0, 0], ta=True)  # (bk, d)
+            s = _mm(q_h, k, tb=True) * scale           # (bq, bk)
+            p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (bq, bk) f32
+
+            dv_acc[:] += _mm(p.astype(dt), do_h, ta=True)   # (bk, g*d)
+            dp = _mm(do_h, v, tb=True)                      # (bq, bk)
+            ds = p * (dp - delta) * scale
+            dk_acc[:] += _mm(ds.astype(dt), q_h, ta=True)   # (bk, g*d)
+
+        _each_head(g, head)
 
     @pl.when(i == nq - 1)
     def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                   dq_ref, dq_acc,
-                  *, scale, block_q, block_k, causal, kv_len, nk):
+                  *, d, g, scale, block_q, block_k, causal, kv_len, nk):
     i = pl.program_id(2)   # query-block (parallel)
     j = pl.program_id(3)   # key-block (sequential, innermost)
 
@@ -245,36 +337,43 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(needed)
     def _compute():
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        mask = _score_mask(q_off, k_off, block_q, block_k, kv_len, causal)
 
-        s = _mm(q_ref[0, 0], k_ref[0, 0], tb=True) * scale
-        col = k_off + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 1)
-        mask = col < kv_len
-        if causal:
-            row = q_off + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, col <= row)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = _mm(do_ref[0, 0], v_ref[0, 0], tb=True)
-        ds = p * (dp - delta) * scale
-        dq_acc[:] += _mm(ds.astype(k_ref.dtype), k_ref[0, 0])  # (bq, d)
+        def head(h):
+            # here k and v carry the head: dQ lands on its lanes
+            lanes = _head_lanes(k.shape, h, d, g)
+            k_h, v_h = _own(k, lanes), _own(v, lanes)
+            lse = lse_ref[0, h, :, :1]
+            delta = delta_ref[0, h, :, :1]
+
+            s = _mm(q, k_h, tb=True) * scale
+            p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+            dp = _mm(do, v_h, tb=True)
+            ds = p * (dp - delta) * scale
+            dq_acc[:] += _mm(ds.astype(k.dtype), k_h)       # (bq, g*d)
+
+        _each_head(g, head)
 
     @pl.when(j == nk - 1)
     def _finish():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g,
-               delta=None, out_dtype=None, vma=None):
-    """``delta``/``out_dtype`` are for block-composed callers
-    (parallel/ring_flash.py): a ring backward precomputes the global
-    rowsum(dO*O) once and needs f32 gradient outputs so per-hop
-    accumulation does not round at the input dtype."""
+def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
+              delta=None, out_dtype=None, vma=None):
+    """``res`` = (q, k, v, o, lse) as :func:`_fwd_rows` takes and gives
+    them, ``g`` = dO like ``o``. ``delta`` (``[N, heads, Tq]``) and
+    ``out_dtype`` are for block-composed callers (parallel/ring_flash.py):
+    a ring backward precomputes the global rowsum(dO*O) once and needs f32
+    gradient outputs so per-hop accumulation does not round at the input
+    dtype."""
     q, k, v, o, lse = res
-    b, h, t_q, d = q.shape
-    t_kv = k.shape[2]
+    n, t_q, c = q.shape
+    d = c // heads
+    hb = _block_heads(heads, d)
+    w = hb * d
+    t_kv = k.shape[1]
     bq = _pick_block(t_q, block_q)
     bk = _pick_block(t_kv, block_k)
     tq_pad = (t_q + bq - 1) // bq * bq
@@ -282,73 +381,105 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g,
     nq, nk = tq_pad // bq, tkv_pad // bk
 
     if delta is None:
-        # delta_i = rowsum(dO_i * O_i) — cheap elementwise+reduce; XLA
-        # fuses it
-        delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)
+        # delta_i = rowsum(dO_i * O_i) over each head's own lanes — cheap
+        # elementwise+reduce; XLA fuses it
+        prod = g.astype(jnp.float32) * o.astype(jnp.float32)
+        delta = jnp.sum(prod.reshape(n, t_q, heads, d),
+                        axis=-1).transpose(0, 2, 1)
 
     qp, kp, vp = _pad_t(q, tq_pad), _pad_t(k, tkv_pad), _pad_t(v, tkv_pad)
     dop = _pad_t(g, tq_pad)
     # lse/delta padded along T and broadcast into 128 lanes so each (bq, 128)
     # tile is layout-friendly
-    pad_q = ((0, 0), (0, 0), (0, tq_pad - t_q))
-    lsep = jnp.pad(lse, pad_q)[..., None] * jnp.ones((1, 1, 1, 128), jnp.float32)
-    deltap = jnp.pad(delta, pad_q)[..., None] * jnp.ones((1, 1, 1, 128),
-                                                         jnp.float32)
+    ones = jnp.ones((1, 1, 1, 128), jnp.float32)
+    lsep = _pad_t(lse, tq_pad, axis=2)[..., None] * ones
+    deltap = _pad_t(delta, tq_pad, axis=2)[..., None] * ones
 
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, x, y: (b_, h_, y, 0))
-    k_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, x, y: (b_, h_, x, 0))
-    r_spec = pl.BlockSpec((1, 1, bq, 128),
-                          lambda b_, h_, x, y: (b_, h_, y, 0))
-    kv_kernel = functools.partial(
-        _bwd_kv_kernel, scale=scale, block_q=bq, block_k=bk, causal=causal,
-        kv_len=t_kv, nq=nq)
+    statics = dict(d=d, g=hb, scale=scale, block_q=bq, block_k=bk,
+                   causal=causal, kv_len=t_kv)
+    q_spec = pl.BlockSpec((1, bq, w), lambda b_, p_, x, y: (b_, y, p_))
+    k_spec = pl.BlockSpec((1, bk, w), lambda b_, p_, x, y: (b_, x, p_))
+    r_spec = pl.BlockSpec((1, hb, bq, 128),
+                          lambda b_, p_, x, y: (b_, p_, y, 0))
     dk, dv = pl.pallas_call(
-        kv_kernel,
-        grid=(b, h, nk, nq),
+        functools.partial(_bwd_kv_kernel, nq=nq, **statics),
+        grid=(n, heads // hb, nk, nq),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=[k_spec, k_spec],
-        out_shape=[_sds((b, h, tkv_pad, d), out_dtype or k.dtype, vma),
-                   _sds((b, h, tkv_pad, d), out_dtype or v.dtype, vma)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+        out_shape=[_sds((n, tkv_pad, c), out_dtype or k.dtype, vma),
+                   _sds((n, tkv_pad, c), out_dtype or v.dtype, vma)],
+        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
+                        pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
 
-    q_spec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, x, y: (b_, h_, x, 0))
-    k_spec2 = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, x, y: (b_, h_, y, 0))
-    r_spec2 = pl.BlockSpec((1, 1, bq, 128),
-                           lambda b_, h_, x, y: (b_, h_, x, 0))
-    q_kernel = functools.partial(
-        _bwd_q_kernel, scale=scale, block_q=bq, block_k=bk, causal=causal,
-        kv_len=t_kv, nk=nk)
+    q_spec2 = pl.BlockSpec((1, bq, w), lambda b_, p_, x, y: (b_, x, p_))
+    k_spec2 = pl.BlockSpec((1, bk, w), lambda b_, p_, x, y: (b_, y, p_))
+    r_spec2 = pl.BlockSpec((1, hb, bq, 128),
+                           lambda b_, p_, x, y: (b_, p_, x, 0))
     dq = pl.pallas_call(
-        q_kernel,
-        grid=(b, h, nq, nk),
+        functools.partial(_bwd_q_kernel, nk=nk, **statics),
+        grid=(n, heads // hb, nq, nk),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
         out_specs=q_spec2,
-        out_shape=_sds((b, h, tq_pad, d), out_dtype or q.dtype, vma),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        out_shape=_sds((n, tq_pad, c), out_dtype or q.dtype, vma),
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
-    return dq[:, :, :t_q], dk[:, :, :t_kv], dv[:, :, :t_kv]
+    return dq[:, :t_q], dk[:, :t_kv], dv[:, :t_kv]
 
 
 # ---------------------------------------------------------------------------
-# public entry
+# the [B, H, T, D] entries: one head a row of [B * H, T, D]
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+def _fold(x):
+    """``[B, H, ...]`` → ``[B * H, ...]``: no data moves."""
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               vma=None, q_offset=0, kv_len=None):
+    """q, k, v ``[B, H, T, D]`` → o ``[B, H, Tq, D]``, lse ``[B, H, Tq]``."""
+    o, lse = _fwd_rows(_fold(q), _fold(k), _fold(v), 1, causal, scale,
+                       block_q, block_k, interpret, vma=vma,
+                       q_offset=q_offset, kv_len=kv_len)
+    return o.reshape(q.shape), lse.reshape(q.shape[:3])
+
+
+def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g,
+               delta=None, out_dtype=None, vma=None):
+    """:func:`_bwd_rows` for ``[B, H, T, D]`` operands (``lse`` and
+    ``delta`` ``[B, H, Tq]``)."""
+    q, k, v, o, lse = res
+    if delta is not None:
+        delta = delta.reshape(-1, 1, delta.shape[-1])
+    dq, dk, dv = _bwd_rows(
+        1, causal, scale, block_q, block_k, interpret,
+        (_fold(q), _fold(k), _fold(v), _fold(o),
+         lse.reshape(-1, 1, lse.shape[-1])),
+        _fold(g), delta=delta, out_dtype=out_dtype, vma=vma)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+# ---------------------------------------------------------------------------
+# public entries
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, heads, causal, scale, block_q, block_k, interpret):
+    o, _ = _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k,
+                     interpret)
     return o
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+def _flash_vjp_fwd(q, k, v, heads, causal, scale, block_q, block_k,
+                   interpret):
+    o, lse = _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k,
+                       interpret)
     # `lse` is lane 0 of the kernel's 128-lane output. Tied to `o`, the
     # slice runs before anything reads `o`; left free, XLA may put it off
     # until the backward pass, and what is held meanwhile (by a remat
@@ -357,30 +488,52 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     o, lse = jax.lax.optimization_barrier((o, lse))
     # The two residuals the kernel itself computed are named for remat
     # policies (q, k, v are not: a checkpointed caller recomputes them from
-    # its own input). `o` is named in the merged (B, T, H*D) form, the one
-    # the caller's output projection reads: dense in HBM, where a
-    # (..., T, D) array with D < 128 is padded to 128 lanes for as long as
-    # it is kept. XLA cancels the way back against the caller's own merge.
-    b, h, t, d = o.shape
-    o = checkpoint_name(o.transpose(0, 2, 1, 3).reshape(b, t, h * d),
-                        FLASH_OUT_NAME)
-    o = o.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+    # its own input). `o` is named as the kernel wrote it: `[B, T, H*D]`,
+    # dense in HBM, is what the caller's output projection reads and the
+    # backward kernels take. (Through the `[B, H, T, D]` entry it is
+    # `[B*H, T, D]`, padded to 128 lanes for as long as it is kept where
+    # D < 128.)
+    o = checkpoint_name(o, FLASH_OUT_NAME)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    return _flash_bwd(causal, scale, block_q, block_k, interpret, res, g)
+def _flash_vjp_bwd(heads, causal, scale, block_q, block_k, interpret, res,
+                   g):
+    return _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res,
+                     g)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False,
+                         scale: float | None = None,
+                         block_q: int = 512, block_k: int = 512,
+                         interpret: bool = False):
+    """Fused flash attention on the activations' own layout. q, k, v:
+    ``[B, T, num_heads * D]``, as the q/k/v projections write them;
+    returns ``[B, T, num_heads * D]``, as the output projection reads it.
+    Needs :func:`heads_per_block` ``(num_heads, D)`` to be a number.
+
+    The same attention as :func:`flash_attention_fused` gives on the split
+    heads, with no copy on either side of the kernels."""
+    d = q.shape[-1] // num_heads
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    return _flash(q, k, v, int(num_heads), bool(causal), float(scale),
+                  int(block_q), int(block_k), bool(interpret))
 
 
 def flash_attention_fused(q, k, v, causal: bool = False,
                           scale: float | None = None,
                           block_q: int = 512, block_k: int = 512,
                           interpret: bool = False):
-    """Fused flash attention. q, k, v: (B, H, T, D); returns (B, H, T, D).
+    """Fused flash attention on SPLIT heads. q, k, v: ``[B, H, T, D]``;
+    returns ``[B, H, T, D]`` — the layout of a decode cache, a ring block
+    and of any head size that fills no 128-lane block. A caller that holds
+    ``[B, T, H * D]`` and can use :func:`flash_attention_rows` saves the
+    transposes around this one.
 
     Matches ``nn.attention.dot_product_attention(q, k, v, causal_mask)``
     numerically (softmax(QK^T / sqrt(D)) V) with O(T) memory. Differentiable
@@ -389,8 +542,9 @@ def flash_attention_fused(q, k, v, causal: bool = False,
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, bool(causal), float(scale),
-                  int(block_q), int(block_k), bool(interpret))
+    o = _flash(_fold(q), _fold(k), _fold(v), 1, bool(causal), float(scale),
+               int(block_q), int(block_k), bool(interpret))
+    return o.reshape(q.shape)
 
 
 def flash_chunk_attention(q, k, v, q_offset: int, kv_len: int = None,

@@ -167,20 +167,22 @@ class Attention(Module):
                 "wv": _glorot(k[2], (H, kvd)), "wo": _glorot(k[3], (H, H))}
 
     def _split(self, x, heads=None):
+        """``[B, T, heads * D]`` → ``[B, heads, T, D]``: the layout of the
+        KV caches, the einsum paths and the sequence-parallel exchanges.
+        A transposing copy on the device; the flash branch of
+        :meth:`_apply` does without it."""
         b, t, _ = x.shape
         return x.reshape(b, t, heads or self.num_heads,
                          -1).transpose(0, 2, 1, 3)
 
-    def qkv(self, params, qx, kx=None):
-        """Projected query (B, nH, T, D) and key/value (B, kvH, T, D)
-        heads — kvH < nH is grouped-query attention (GQA: the KV cache
-        and K/V projections shrink by nH/kvH, the decode-path HBM lever).
+    def _project(self, params, qx, kx=None):
+        """The q, k, v projections as the matmuls write them: query
+        ``[B, T, nH * D]``, key and value ``[B, T, kvH * D]``.
 
         Self-attention projects through ONE (H, H+2*kvD) matmul — one
         read of the activations and a single well-packed MXU contraction
         instead of three dots. Params stay separate wq/wk/wv (checkpoint
         layout unchanged); the concat is a trace-time weight reshuffle."""
-        kvh = self._kvh()
         ws = (params["wq"], params["wk"], params["wv"])
         if (kx is None or kx is qx) and _fused_qkv_enabled() and all(
                 isinstance(w, jnp.ndarray) for w in ws):
@@ -190,14 +192,18 @@ class Attention(Module):
             H = self.hidden_size
             kvd = ws[1].shape[1]
             flat = qx @ w3
-            q, k, v = (flat[..., :H], flat[..., H:H + kvd],
-                       flat[..., H + kvd:])
-            return (self._split(q), self._split(k, kvh),
-                    self._split(v, kvh))
+            return flat[..., :H], flat[..., H:H + kvd], flat[..., H + kvd:]
         kx = qx if kx is None else kx
-        return (self._split(qx @ params["wq"]),
-                self._split(kx @ params["wk"], kvh),
-                self._split(kx @ params["wv"], kvh))
+        return qx @ params["wq"], kx @ params["wk"], kx @ params["wv"]
+
+    def qkv(self, params, qx, kx=None):
+        """:meth:`_project`, split into heads: query ``[B, nH, T, D]`` and
+        key/value ``[B, kvH, T, D]`` — kvH < nH is grouped-query attention
+        (GQA: the KV cache and K/V projections shrink by nH/kvH, the
+        decode-path HBM lever)."""
+        q, k, v = self._project(params, qx, kx)
+        kvh = self._kvh()
+        return self._split(q), self._split(k, kvh), self._split(v, kvh)
 
     def _expand_kv(self, k, v):
         """Broadcast kv heads up to the query head count for the dense/
@@ -209,6 +215,8 @@ class Attention(Module):
         return jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
 
     def _merge(self, o, params):
+        """Split heads ``[B, H, T, D]`` → ``[B, T, H * D]`` (a transposing
+        copy) and the output projection."""
         b, h, t, d = o.shape
         return o.transpose(0, 2, 1, 3).reshape(b, t, h * d) @ params["wo"]
 
@@ -378,7 +386,20 @@ class Attention(Module):
             mask = x[3] if len(x) >= 3 else None
         else:
             qx, kx, mask = x, x, None
-        q, k, v = self.qkv(params, qx, kx)
+        flash = (self.causal and mask is None and self.use_flash
+                 and self.seq_axis is None
+                 and not (training and self.attention_dropout > 0.0
+                          and rng is not None))
+        kvh = self._kvh()
+        q, k, v = self._project(params, qx, kx)
+        if flash and not self.rope and kvh == self.num_heads:
+            # the fused O(T)-memory path on the projections' own layout:
+            # Pallas kernel on TPU backends, einsum+mask elsewhere
+            # (parallel/flash dispatcher); no head is split or merged
+            from ..parallel.flash import flash_attention_rows
+            o = flash_attention_rows(q, k, v, self.num_heads, causal=True)
+            return o @ params["wo"]
+        q, k, v = self._split(q), self._split(k, kvh), self._split(v, kvh)
         if self.rope:
             if kx is not qx:
                 raise ValueError("RoPE supports self-attention only")
@@ -405,11 +426,9 @@ class Attention(Module):
                 from ..parallel.ring_flash import ring_flash_attention
                 o = ring_flash_attention(q, k, v, axis=self.seq_axis,
                                          causal=self.causal)
-        elif (self.causal and mask is None and self.use_flash
-              and not (training and self.attention_dropout > 0.0
-                       and rng is not None)):
-            # the fused O(T)-memory path: Pallas kernel on TPU backends,
-            # einsum+mask elsewhere (parallel/flash dispatcher)
+        elif flash:
+            # RoPE and grouped K/V are applied to split heads: the fused
+            # path through the (B, H, T, D) entry
             o = flash_attention(q, k, v, causal=True)
         else:
             if self.causal and mask is None:
@@ -656,8 +675,8 @@ class Transformer(Module):
         O(T)-memory flash path (Pallas on TPU) instead of materialising the
         (B,H,T,T) score matrix. ``remat``: each block runs under
         :func:`remat_block`, so the backward pass recomputes block internals
-        (layer norms, projections, the head split, the FFN) instead of
-        storing them. Kept per layer: the block input and, where the flash
+        (layer norms, projections, the FFN) instead of storing them. Kept
+        per layer: the block input and, where the flash
         kernel ran, its output and logsumexp (the kernel is not run again)
         — activation memory drops from O(layers * intermediates) to two
         (B,T,H) tensors a layer, one on the einsum path."""

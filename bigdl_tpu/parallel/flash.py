@@ -3,7 +3,11 @@
 The kernels themselves live in ``bigdl_tpu.kernels`` (hand-written
 Pallas; ``flash_attention`` for training/prefill, ``paged_attention``
 for the serving tier's paged decode). This module is only the
-dispatcher:
+dispatcher. Flash attention has two entries by the layout the caller
+holds: :func:`flash_attention_rows` for ``[B, T, H*D]`` (the training
+step: nothing is copied around the kernels) and :func:`flash_attention`
+for split heads ``[B, H, T, D]`` (KV caches, sequence-parallel blocks).
+For every entry:
 
 * on the ``tpu`` platform the compiled kernels run, and a kernel that
   fails to trace, lower or compile RAISES — there is no path from a
@@ -21,6 +25,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import logging
+import math
 import os
 
 import jax
@@ -46,15 +51,28 @@ def _scoped(var, value):
         var.reset(tok)
 
 
-def _einsum_attention(q, k, v, causal):
+def _causal_bias(t):
     import numpy as np
+    return jnp.where(np.tril(np.ones((t, t), np.bool_))[None, None],
+                     0.0, -1e9)
+
+
+def _einsum_attention(q, k, v, causal):
     from ..nn.attention import dot_product_attention
-    mask = None
-    if causal:
-        t = q.shape[-2]
-        mask = jnp.where(np.tril(np.ones((t, t), np.bool_))[None, None],
-                         0.0, -1e9)
+    mask = _causal_bias(q.shape[-2]) if causal else None
     return dot_product_attention(q, k, v, mask)
+
+
+def _einsum_attention_rows(q, k, v, heads, causal):
+    """:func:`_einsum_attention` read from the ``[B, T, H, D]`` view of
+    ``[B, T, H * D]`` operands: no head is moved."""
+    b, t, c = q.shape
+    q, k, v = (x.reshape(b, x.shape[1], heads, -1) for x in (q, k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    if causal:
+        logits = logits + _causal_bias(t)
+    w = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(b, t, c)
 
 
 def flash_mode() -> str:
@@ -115,28 +133,71 @@ def data_parallel_context(mesh, axis: str = "data"):
     return _scoped(_DATA_CTX, (mesh, axis))
 
 
+def _kernel_obs(counter: str):
+    """Trace-time dispatch accounting: one bump per kernel call (flash) or
+    program (paged) BUILT on each path (execution never re-enters Python,
+    so what was built is the honest unit — serve/decode_steps counts the
+    dispatches riding a paged program)."""
+    from .. import observability as obs
+    if obs.enabled():
+        obs.counter(f"kernels/{counter}").inc()
+
+
+def _over_batch(fused):
+    """``fused`` under the :func:`data_parallel_context`'s ``shard_map``,
+    if one is set; the batch is the leading dimension in both layouts."""
+    mesh, axis = _DATA_CTX.get()
+    if mesh is None:
+        return fused
+    from jax.sharding import PartitionSpec as P
+    from ..utils.compat import shard_map
+    return shard_map(fused, mesh=mesh, in_specs=(P(axis),) * 3,
+                     out_specs=P(axis), check_vma=False)
+
+
 def flash_attention(q, k, v, causal: bool = False):
-    """q, k, v: (B, H, T, D)."""
+    """q, k, v: (B, H, T, D) — split heads, as a decode cache and the
+    sequence-parallel exchanges hold them. A caller whose operands are
+    ``[B, T, H * D]`` uses :func:`flash_attention_rows`."""
 
     def kernel(interpret):
         # imported lazily: einsum-path callers never load pallas
         from ..kernels.flash_attention import flash_attention_fused
-
-        def fused(q, k, v):
-            return flash_attention_fused(q, k, v, causal=causal,
-                                         interpret=interpret,
-                                         **_flash_blocks())
-
-        mesh, axis = _DATA_CTX.get()
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-            from ..utils.compat import shard_map
-            fused = shard_map(fused, mesh=mesh, in_specs=(P(axis),) * 3,
-                              out_specs=P(axis), check_vma=False)
-        return fused(q, k, v)
+        _kernel_obs("flash_heads")
+        return _over_batch(lambda q, k, v: flash_attention_fused(
+            q, k, v, causal=causal, interpret=interpret,
+            **_flash_blocks()))(q, k, v)
 
     return _dispatch("flash attention", kernel,
                      lambda: _einsum_attention(q, k, v, causal))
+
+
+def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False):
+    """q, k, v: (B, T, H*D), the q/k/v projections as they are written;
+    returns (B, T, H*D), what the output projection reads. Where the
+    kernels can index that layout (whole heads fill 128-lane blocks:
+    ``kernels.flash_attention.heads_per_block``) no head is split or
+    merged; other head sizes are split here, go through
+    :func:`flash_attention` and are merged again."""
+    b, t, c = q.shape
+    if flash_mode() != "einsum":
+        from ..kernels.flash_attention import heads_per_block
+        if heads_per_block(num_heads, c // num_heads) is None:
+            split = lambda x: x.reshape(b, x.shape[1], num_heads,  # noqa: E731
+                                        -1).swapaxes(1, 2)
+            o = flash_attention(split(q), split(k), split(v), causal=causal)
+            return o.swapaxes(1, 2).reshape(b, t, c)
+
+    def kernel(interpret):
+        from ..kernels.flash_attention import flash_attention_rows as rows
+        _kernel_obs("flash_rows")
+        return _over_batch(lambda q, k, v: rows(
+            q, k, v, num_heads, causal=causal, interpret=interpret,
+            **_flash_blocks()))(q, k, v)
+
+    return _dispatch("flash attention", kernel,
+                     lambda: _einsum_attention_rows(q, k, v, num_heads,
+                                                    causal))
 
 
 def _einsum_chunk_attention(q, k, v, q_offset, kv_len):
@@ -206,15 +267,6 @@ def paged_mode() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "dense"
 
 
-def _paged_obs(counter: str):
-    """Trace-time dispatch accounting: one bump per program BUILT on
-    each path (execution never re-enters Python, so per-program is the
-    honest unit — serve/decode_steps counts the dispatches riding it)."""
-    from .. import observability as obs
-    if obs.enabled():
-        obs.counter(f"kernels/{counter}").inc()
-
-
 def paged_attention(q, k_pages, v_pages, block_tables, positions,
                     dense_fn):
     """The serving tier's paged-decode attention seam.
@@ -238,7 +290,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions,
                        "paged attention: platform %r uses the dense "
                        "gather path (set BIGDL_TPU_PAGED_ATTN=interpret to "
                        "run the Pallas kernel in interpreter mode)", backend)
-        _paged_obs("paged_attn_dense_programs")
+        _kernel_obs("paged_attn_dense_programs")
         return dense_fn()
     interpret = mode == "interpret"
     mesh, axis = _PAGED_CTX.get()
@@ -260,5 +312,5 @@ def paged_attention(q, k_pages, v_pages, block_tables, positions,
                         in_specs=(head, head, head, P(), P()),
                         out_specs=head, check_vma=False)(
             q, k_pages, v_pages, block_tables, positions)
-    _paged_obs("paged_attn_programs")
+    _kernel_obs("paged_attn_programs")
     return out

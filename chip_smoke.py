@@ -148,7 +148,9 @@ def phase_kernels(cfg, env):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from bigdl_tpu.kernels.flash_attention import flash_attention_fused
+    from bigdl_tpu.kernels.flash_attention import (flash_attention_fused,
+                                                   flash_attention_rows,
+                                                   heads_per_block)
     from bigdl_tpu.kernels.paged_attention import paged_decode_attention
     from bigdl_tpu.nn.attention import (Attention, causal_mask,
                                         dot_product_attention)
@@ -188,6 +190,20 @@ def phase_kernels(cfg, env):
                                  argnums=(0, 1, 2)), q, k, v)
     for name, a, b in zip("qkv", g_kernel, g_dense):
         within(f"flash_bwd d{name}", a, b)
+    if heads_per_block(H, D):
+        # the trainer's own entry: the same attention indexed on the
+        # projections' layout [B, T, H*D], against the same references
+        rows = lambda x: x.transpose(0, 2, 1, 3).reshape(B, T, H * D)  # noqa: E731
+        flash_r = lambda q, k, v: flash_attention_rows(  # noqa: E731
+            q, k, v, H, causal=True, interpret=env.interpret)
+        qr, kr, vr, wr = rows(q), rows(k), rows(v), rows(w)
+        within(f"flash_rows_fwd B{B} T{T} H{H}xD{D} f32",
+               jax.jit(flash_r)(qr, kr, vr), rows(reference(dense, q, k, v)))
+        g_rows = jax.jit(jax.grad(
+            lambda q, k, v: (flash_r(q, k, v) * wr).sum(),
+            argnums=(0, 1, 2)))(qr, kr, vr)
+        for name, a, b in zip("qkv", g_rows, g_dense):
+            within(f"flash_rows_bwd d{name}", a, rows(b))
 
     # paged decode attention at the server's geometry: S=1 over a full
     # slot bucket, and one prefill chunk; tables are a random permutation

@@ -80,6 +80,89 @@ def test_flash_grads_match_einsum(causal):
         assert err < 5e-4, f"d{name} err {err}"
 
 
+@pytest.mark.parametrize("heads,d,t,rows", [
+    (4, 64, 256, True),      # two heads a 128-lane block
+    (2, 128, 256, True),     # one head a block
+    (4, 64, 200, True),      # unpadded length
+    (8, 32, 128, True),      # four heads a block
+    (3, 64, 128, False),     # an odd head count: no block of whole heads
+    (2, 80, 128, False),     # 80 lanes a head: nor here
+])
+def test_flash_rows_match_einsum(monkeypatch, heads, d, t, rows):
+    """The ``[B, T, H*D]`` entry (``parallel.flash.flash_attention_rows``)
+    against the einsum path, forward and the gradients of q, k, v. Where
+    whole heads fill 128-lane blocks the kernels index the operands as
+    they are (no transpose is traced); other shapes are split, go through
+    the ``[B, H, T, D]`` entry and still match."""
+    from bigdl_tpu.kernels.flash_attention import heads_per_block
+    from bigdl_tpu.parallel import flash
+    monkeypatch.setenv("BIGDL_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("BIGDL_TPU_FLASH_BLOCK_K", "128")
+    rng = np.random.RandomState(6)
+    q, k, v = [jnp.asarray(rng.randn(2, t, heads * d).astype(np.float32))
+               for _ in range(3)]
+
+    def loss(q, k, v):
+        o = flash.flash_attention_rows(q, k, v, heads, causal=True)
+        return jnp.sum(jnp.sin(o)), o
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "off")
+    (_, o_ref), g_ref = grad(q, k, v)
+    split = lambda x: x.reshape(2, t, heads, d).transpose(0, 2, 1, 3)
+    assert np.allclose(np.asarray(split(o_ref)), np.asarray(
+        _ref(split(q), split(k), split(v), True)), atol=2e-5)
+
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    (_, o), g = grad(q, k, v)
+    assert np.allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5), \
+        np.abs(np.asarray(o) - np.asarray(o_ref)).max()
+    for a, b, name in zip(g, g_ref, "qkv"):
+        err = np.abs(np.asarray(a) - np.asarray(b)).max()
+        assert err < 5e-4, f"d{name} err {err}"
+    assert (heads_per_block(heads, d) is not None) == rows
+    forward = str(jax.make_jaxpr(lambda q, k, v: loss(q, k, v)[1])(q, k, v))
+    assert "name=flash_fwd" in forward
+    assert ("transpose[" not in forward) == rows, forward
+
+
+def test_flash_entry_counters(monkeypatch):
+    """``kernels/flash_rows`` and ``kernels/flash_heads`` count the flash
+    calls BUILT on each entry: one bump a traced call, none for a cached
+    program's next run, none on the einsum path, none while the
+    observability is off."""
+    from bigdl_tpu import observability as obs
+    from bigdl_tpu.parallel import flash
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    rows = jnp.ones((1, 128, 128), jnp.float32)          # 2 heads of 64
+    odd = jnp.ones((1, 128, 192), jnp.float32)           # 3 heads of 64
+    heads = jnp.ones((1, 2, 128, 64), jnp.float32)
+    count = lambda name: getattr(
+        obs.registry().get(f"kernels/{name}"), "value", 0)
+    flash.flash_attention_rows(rows, rows, rows, 2, causal=True)
+    assert (count("flash_rows"), count("flash_heads")) == (0, 0)
+    obs.enable()
+    try:
+        r0, h0 = count("flash_rows"), count("flash_heads")
+        fn = jax.jit(lambda x: flash.flash_attention_rows(x, x, x, 2,
+                                                          causal=True))
+        fn(rows), fn(rows)                       # built once, run twice
+        assert (count("flash_rows"), count("flash_heads")) == (r0 + 1, h0)
+        flash.flash_attention(heads, heads, heads, causal=True)
+        assert (count("flash_rows"), count("flash_heads")) == (r0 + 1,
+                                                               h0 + 1)
+        # no 128-lane block of whole heads: the (B, H, T, D) entry
+        flash.flash_attention_rows(odd, odd, odd, 3, causal=True)
+        assert (count("flash_rows"), count("flash_heads")) == (r0 + 1,
+                                                               h0 + 2)
+        monkeypatch.setenv("BIGDL_TPU_FLASH", "off")
+        flash.flash_attention_rows(rows, rows, rows, 2, causal=True)
+        assert (count("flash_rows"), count("flash_heads")) == (r0 + 1,
+                                                               h0 + 2)
+    finally:
+        obs.disable()
+
+
 def test_flash_bf16_runs():
     rng = np.random.RandomState(4)
     q, k, v = [jnp.asarray(rng.randn(1, 2, 128, 64)).astype(jnp.bfloat16)

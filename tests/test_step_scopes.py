@@ -172,3 +172,45 @@ def test_the_flash_kernels_carry_their_names():
     bwd = str(jax.make_jaxpr(jax.grad(fwd, argnums=(0, 1, 2)))(q, q, q))
     for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
         assert name in bwd, name
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (``remat``, ``custom_vjp_call``, ``pjit``, ``shard_map``)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def test_flash_attention_moves_no_head(monkeypatch):
+    """Two 64-wide heads fill a 128-lane block, so the training step's
+    attention runs on the projections' own ``[B, T, H*D]``: under the scope
+    ``attn`` the gradient of a ``remat`` LM transposes no 4-D array (no head
+    split, no merge; forward, recomputation and backward), and each kernel
+    is called once a layer under its own name and no other kernel is."""
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    layers = 2
+    model = TransformerLM(vocab_size=64, hidden_size=256, num_heads=4,
+                          filter_size=64, num_layers=layers, max_len=128,
+                          remat=True)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    ids = jnp.ones((2, 128), jnp.int32)
+
+    def loss(p):
+        return jnp.sum(jnp.tanh(model.apply(p, {}, ids,
+                                            training=False)[0] * 0.01))
+
+    eqns = list(_equations(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
+    moved = [(e.invars[0].aval.shape, str(e.source_info.name_stack))
+             for e in eqns if e.primitive.name == "transpose"
+             and e.invars[0].aval.ndim == 4
+             and "attn" in str(e.source_info.name_stack)]
+    assert not moved, moved
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert sorted(kernels) == sorted(
+        ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"] * layers)
