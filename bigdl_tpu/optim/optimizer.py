@@ -303,6 +303,15 @@ def _scan_superstep(step):
     return superstep
 
 
+def _steps_in_stack(args):
+    """The step count of ONE superstep program, read off the
+    ``[k, batch, ...]`` stack's leading dim at compile time — a clamped
+    j<K group compiles its OWN program and its artifact must say j, not
+    the configured K."""
+    leaves = jax.tree_util.tree_leaves(args[3])
+    return leaves[0].shape[0] if leaves else 1
+
+
 @jax.named_scope("grad_clip")
 def _clip_grads(grads, clip_const=None, clip_norm=None):
     if clip_const is not None:
@@ -332,6 +341,16 @@ def _loss_fn(model, criterion):
         return loss, new_state
 
     return loss_fn
+
+
+# what :meth:`BaseOptimizer._account_loss` says became of a step
+_COUNTED, _SKIPPED, _RESTORED = "counted", "skipped", "restored"
+
+
+def _host_xy(mb):
+    """A MiniBatch's host ``(x, y)``: what the stager extracts per
+    microbatch when ``_stage_group`` places the stack."""
+    return mb.get_input(), mb.get_target()
 
 
 def _guarded(loss, new, old):
@@ -795,35 +814,78 @@ class BaseOptimizer:
         return ShardedDataSet(self.training_set, self.batch_size,
                               num_shards=self._num_shards())
 
+    def _step_mode(self):
+        """What this mode's step is made of, for :meth:`_build_step`:
+        ``(loss_fn, frozen_mask, exchange, update, specs)`` — the loss
+        over the mode's parameter form, the frozen mask in that form
+        (None when nothing is frozen), the gradient exchange
+        ``(grads, x) -> grads`` (None when the mode has none of its
+        own), ``update(grads, params, opt_state, lr)``, and the
+        shard_map specs of ``(params, opt_state, mstate)`` when the step
+        is an EXPLICIT per-shard program (None: a plain jit whose batch
+        dim XLA partitions by itself). Here: the tree, no exchange, the
+        optim method's own update."""
+        return (_loss_fn(self.model, self.criterion),
+                _frozen_mask(self.model), None, self.optim_method.update,
+                None)
+
     def _build_step(self):
+        """The ONE step body and its ONE ending; a mode contributes only
+        what :meth:`_step_mode` names."""
         clip_const, clip_norm = self.clip_const, self.clip_norm
-        optim = self.optim_method
-        frozen_mask = _frozen_mask(self.model)
-        loss_fn = _loss_fn(self.model, self.criterion)
+        loss_fn, frozen_mask, exchange, update, specs = self._step_mode()
+        explicit = specs is not None
+        trace_context = contextlib.nullcontext if explicit \
+            else self._step_trace_context
 
         def step(params, opt_state, mstate, x, y, lr, rng):
-            with self._step_trace_context():
+            with trace_context():
                 (loss, new_mstate), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, mstate, x, y, rng)
+            if exchange is not None:
+                grads = exchange(grads, x)
             grads = _clip_grads(grads, clip_const, clip_norm)
             with jax.named_scope("optim_update"):
                 if frozen_mask is not None:
                     grads = _tmap(lambda g, m: g * m, grads, frozen_mask)
-                new_params, new_opt = optim.update(grads, params, opt_state,
-                                                   lr)
+                new_params, new_opt = update(grads, params, opt_state, lr)
                 if frozen_mask is not None:
                     # weight decay must not move frozen params either
                     new_params = _tmap(
                         lambda n, o, m: jnp.where(m > 0, n, o),
                         new_params, params, frozen_mask)
+                if explicit:
+                    loss = jax.lax.pmean(loss, "data")
+                    new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"),
+                                       new_mstate)
+                # guarded after the pmean, so every shard takes the same
+                # branch — no divergence across the mesh
                 return (loss,) + _guarded(
                     loss, (new_params, new_opt, new_mstate),
                     (params, opt_state, mstate))
 
-        fn = jax.jit(_scan_superstep(step), donate_argnums=(0, 1, 2)) \
-            if self.superstep > 1 else \
-            jax.jit(step, donate_argnums=(0, 1, 2))
-        return self._instrument_step(fn)
+        def local_step(params, opt_state, mstate, x, y, lr, rng):
+            # one shard's step of an explicit mode: its own rng stream
+            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
+            return step(params, opt_state, mstate, x, y, lr, rng)
+
+        fn, batch, steps = local_step if explicit else step, P("data"), 1
+        if self.superstep > 1:
+            # the scan lives INSIDE the shard_map body: the ZeRO-1
+            # psum_scatter/update/all_gather cycle stays in the compiled
+            # loop (the cross-replica sharded update must ride the scan
+            # for superstep fusion to pay off — one program, K collective
+            # rounds, zero host round-trips in between). Batch stacks
+            # carry the scan dim first, per-step batch dim sharded.
+            fn, batch, steps = \
+                _scan_superstep(fn), P(None, "data"), _steps_in_stack
+        if explicit:
+            from ..utils.compat import shard_map
+            fn = shard_map(fn, mesh=self.mesh,
+                           in_specs=specs + (batch, batch, P(), P()),
+                           out_specs=(P(),) + specs, check_vma=False)
+        return self._instrument_step(
+            jax.jit(fn, donate_argnums=(0, 1, 2)), steps)
 
     def _step_trace_context(self):
         """Context the forward+backward of the default step traces
@@ -831,53 +893,42 @@ class BaseOptimizer:
         partitions automatically)."""
         return contextlib.nullcontext()
 
-    def _instrument_step(self, jit_fn):
+    def _instrument_step(self, jit_fn, steps_per_program):
         """Route the compiled step through the perf-introspection
         wrapper: each distinct batch signature records a
         CompiledArtifact (XLA FLOPs/bytes, memory footprint, compile
         wall time, cache provenance) that the live ``perf/mfu`` gauge
         and ``tools/xla_report.py`` read. Params/opt-state/model-state
         shapes are fixed for the life of the step fn, so the signature
-        keys on the batch arguments alone (argnums 3, 4). Under
-        superstep fusion the per-program step count is read off the
-        ``[k, batch, ...]`` stack's leading dim at compile time — a
-        clamped j<K group compiles its OWN program and its artifact
-        must say j, not the configured K."""
-        if self.superstep > 1:
-            def steps_from_stack(args):
-                leaves = jax.tree_util.tree_leaves(args[3])
-                return leaves[0].shape[0] if leaves else 1
-            steps = steps_from_stack
-        else:
-            steps = 1
+        keys on the batch arguments alone (argnums 3, 4).
+        ``steps_per_program`` is 1, or under superstep fusion the
+        builder's reader of the per-program step count."""
         return obs.perf.instrument_jit(
             jit_fn, name="optim/step", kind="train_step",
-            key_argnums=(3, 4), steps_per_program=steps)
+            key_argnums=(3, 4), steps_per_program=steps_per_program)
 
     def _place_batch(self, x, y):
         from .staging import place_host_value
         return place_host_value(x), place_host_value(y)
 
     def _stage_minibatch(self, mb):
-        """Produce-side staging: host MiniBatch -> device-resident (x, y).
-        Runs on the stager thread when prefetch is enabled (the native
-        bf16_nhwc prefetcher's batches pass through as a cast-free
-        device_put), inline otherwise."""
-        return self._place_batch(mb.get_input(), mb.get_target())
-
-    def _stage_minibatch_host(self, mb):
-        """Superstep produce-side stage 1: extract the host (x, y) only —
-        placement happens once per GROUP in ``_stage_group`` so the whole
-        ``[K, batch, ...]`` stack ships in one (sharded) device_put."""
-        return mb.get_input(), mb.get_target()
+        """Produce-side staging at K = 1: host MiniBatch -> the loop's
+        element ``(1, x, y)`` with device-resident x, y. Runs on the
+        stager thread when prefetch is enabled (the native bf16_nhwc
+        prefetcher's batches pass through as a cast-free device_put),
+        inline otherwise."""
+        return (1,) + self._place_batch(*_host_xy(mb))
 
     def _stage_group(self, items):
         """Superstep stacking stage (runs on the stager thread): K host
-        microbatches -> one ``(k, xs, ys)`` element with device-resident
-        ``[k, batch, ...]`` stacks, so the hot loop dequeues one element
-        per dispatch. ``np.asarray`` first: the native prefetchers may
-        hand device-resident batches (direct-to-device staging); the
-        stack itself must run on host memory."""
+        microbatches ``(x, y)`` (``_host_xy``: placement happens once
+        per GROUP here, so the whole ``[K, batch, ...]`` stack ships in
+        one (sharded) device_put) -> one ``(k, xs, ys)`` element with
+        device-resident ``[k, batch, ...]`` stacks, so the hot loop
+        dequeues one element per dispatch. ``np.asarray`` first: the
+        native prefetchers may hand device-resident batches
+        (direct-to-device staging); the stack itself must run on host
+        memory."""
         def stack(vals):
             return _tmap(lambda *ls: np.stack([np.asarray(l) for l in ls]),
                          *vals)
@@ -1181,6 +1232,10 @@ class BaseOptimizer:
         optim = self.optim_method
         state = optim.state  # {'neval', 'epoch', ...}
         batched = self._batched()
+        # K is read ONCE a run, beside the build of the step that scans
+        # it: the stager's grouping and the loop's element follow from
+        # the same reading, so the three cannot disagree
+        fused = self.superstep > 1
         done = False
         nan_streak = 0
         while not done:
@@ -1192,26 +1247,17 @@ class BaseOptimizer:
             # With superstep K > 1 it also owns the stacking stage:
             # groups of K microbatches assemble into [K, batch, ...]
             # device stacks and the hot loop dequeues one per dispatch.
-            if self.superstep > 1:
-                batches = staged(batched.data(train=True),
-                                 self._stage_minibatch_host,
-                                 depth=self.prefetch_depth, name="stager",
-                                 group=self.superstep,
-                                 group_fn=self._stage_group,
-                                 group_key=self._stage_group_key,
-                                 stall_deadline_s=self.stall_deadline_s)
-            else:
-                batches = staged(batched.data(train=True),
-                                 self._stage_minibatch,
-                                 depth=self.prefetch_depth, name="stager",
-                                 stall_deadline_s=self.stall_deadline_s)
+            batches = staged(batched.data(train=True),
+                             _host_xy if fused else self._stage_minibatch,
+                             depth=self.prefetch_depth, name="stager",
+                             group=self.superstep,
+                             group_fn=self._stage_group if fused else None,
+                             group_key=self._stage_group_key,
+                             stall_deadline_s=self.stall_deadline_s)
             box = {"params": params, "opt_state": opt_state,
                    "mstate": mstate, "nan_streak": nan_streak, "done": done}
             try:
-                if self.superstep > 1:
-                    self._run_epoch_supersteps(batches, state, box)
-                else:
-                    self._run_epoch_steps(batches, state, box)
+                self._run_epoch(batches, state, box, fused)
             finally:
                 batches.close()  # join the stager thread — no leaks, ever
             params, opt_state, mstate = \
@@ -1573,177 +1619,6 @@ class BaseOptimizer:
                 sm.report()  # emits health/straggler on persistence
         return False
 
-    def _run_epoch_steps(self, batches, state, box):
-        """One epoch of the pipelined step loop. ``batches`` yields
-        device-resident (x, y) (already staged by the caller's stager);
-        mutable step state travels in ``box``
-        (params/opt_state/mstate/nan_streak/done) so every exit path —
-        exhaustion, end trigger, an exception mid-step — leaves the
-        caller with the latest device handles."""
-        optim = self.optim_method
-        params, opt_state, mstate = \
-            box["params"], box["opt_state"], box["mstate"]
-        nan_streak = box["nan_streak"]
-        try:
-            while True:
-                self._step_beacon.pulse()
-                self._check_halt()
-                with obs.span("step", step_num=state["neval"]):
-                    t0 = time.perf_counter()
-                    with obs.span("step/data_fetch"):
-                        try:
-                            x, y = next(batches)
-                        except StopIteration:
-                            return
-                    t1 = time.perf_counter()
-                    with obs.span("step/prepare"):
-                        # *1.0 is bitwise-exact: the remediation scale only
-                        # changes lr after a plateau actually reduced it
-                        lr = optim.current_lr() * self._remediation_lr_scale
-                        rng = engine.next_rng_key()
-                    dsp = obs.span("step/dispatch")
-                    with dsp:
-                        loss, params, opt_state, mstate = \
-                            self._dispatch_guarded(
-                                params, opt_state, mstate, x, y,
-                                jnp.asarray(lr, jnp.float32), rng)
-                    # the last COMPLETED dispatch's handles: what the
-                    # watchdog-thread stall remediation checkpoints
-                    self._live_state = (params, opt_state, mstate)
-                    self._tighten_stall_deadline()
-                    if obs.enabled():
-                        obs.counter("engine/dispatches").inc()
-                    with obs.span("step/loss_sync"):
-                        # step provenance: the dispatch just issued is
-                        # iteration neval+1; async/window:K resolve an
-                        # OLDER one — _resolved_step names it
-                        loss_val = self._observe_loss(
-                            loss, state["neval"] + 1)
-                    t2 = time.perf_counter()
-                    if loss_val is not None and not np.isfinite(loss_val):
-                        nan_streak += 1
-                        if obs.enabled():
-                            _flight.record("nan",
-                                           neval=self._resolved_step,
-                                           epoch=state["epoch"],
-                                           loss=loss_val,
-                                           policy=self.nan_policy)
-                        if self._loss_monitor is not None:
-                            self._loss_monitor.observe(
-                                loss_val, self._resolved_step)
-                        if self.nan_policy == "error":
-                            raise FloatingPointError(
-                                f"non-finite loss {loss_val} at iteration "
-                                f"{state['neval']} — enable "
-                                f"set_nan_policy('skip') to drop such steps")
-                        if nan_streak > self.max_nan_retries:
-                            raise FloatingPointError(
-                                f"{nan_streak} consecutive non-finite steps "
-                                f"(nan_policy='{self.nan_policy}') — data or "
-                                "hyperparameters are unrecoverably bad")
-                        if self.nan_policy == "resume":
-                            self.wait_for_checkpoints()  # in-flight writes
-                            snap = self._latest_checkpoint()
-                            if snap is None:
-                                raise FloatingPointError(
-                                    "non-finite loss with nan_policy='resume' "
-                                    "but no checkpoint saved yet — call "
-                                    "set_checkpoint(...) first")
-                            with open(snap, "rb") as f:
-                                payload = pickle.load(f)
-                            self.optim_method.state.update(
-                                payload["optim_host_state"])
-                            params, opt_state, mstate = \
-                                self._restore_step_state(payload)
-                            # in-flight losses refer to pre-restore steps
-                            self._pending_loss = None
-                            self._loss_window.clear()
-                            self.metrics.add("nan_resumes", 1.0)
-                            obs.instant("step/nan_resume", neval=state["neval"])
-                            continue
-                        # 'skip': the in-step guard already kept the previous
-                        # params; count the iteration so end triggers advance
-                        self.metrics.add("nan_skips", 1.0)
-                        obs.instant("step/nan_skip", neval=state["neval"])
-                        state["neval"] += 1
-                        continue
-                    # what follows the resolved loss: bookkeeping,
-                    # summaries, remediation, and the triggers (validation,
-                    # checkpoint, the caller's end trigger)
-                    with obs.span("step/triggers"):
-                        if loss_val is not None:
-                            # windowed policies have no resolved loss until
-                            # K are in flight — the streak/loss state only
-                            # moves on an actually-observed value
-                            nan_streak = 0
-                            state["loss"] = loss_val
-                        state["neval"] += 1
-                        state["epoch_finished"] = False
-                        health_events = []
-                        if loss_val is not None:
-                            # provenance rides the already-resolved host
-                            # float — no extra readback; under async/
-                            # window:K the loss belongs to _resolved_step,
-                            # up to K-1 before the current iteration
-                            if obs.enabled():
-                                _flight.record("step",
-                                               neval=self._resolved_step,
-                                               epoch=state["epoch"],
-                                               loss=loss_val)
-                            if self._loss_monitor is not None:
-                                health_events = self._loss_monitor.observe(
-                                    loss_val, self._resolved_step)
-                        if self._profiler is not None:
-                            self._profiler.maybe_tick(state["neval"])
-                        self.metrics.add("data_time", t1 - t0)
-                        self.metrics.add("step_time", t2 - t1)
-                        if obs.enabled():
-                            obs.counter("optim/steps").inc()
-                            obs.gauge("optim/throughput",
-                                      unit="samples/s").set(
-                                self.batch_size / max(t2 - t0, 1e-9))
-                            # live MFU + step-phase gauges: host floats the
-                            # loop already measured, zero new readbacks. A
-                            # dispatch that paid a compile measures XLA, not
-                            # the model — excluded, like bench warmup. The
-                            # wall is the FULL iteration (t0→t2): under
-                            # async/window:K the dispatch+resolve sliver
-                            # alone excludes the device time entirely.
-                            if not getattr(self._step_fn,
-                                           "last_call_compiled", True):
-                                obs.perf.note_step(
-                                    getattr(self._step_fn, "last_artifact",
-                                            None),
-                                    wall_s=t2 - t0, host_s=t1 - t0,
-                                    dispatch_s=dsp.duration_s)
-                            self._snap_writer.maybe_write(
-                                step=state["neval"])
-                        if self.train_summary is not None:
-                            rec = self.train_summary.should_record
-                            if loss_val is not None and rec("Loss", state):
-                                self.train_summary.add_scalar(
-                                    "Loss", loss_val, state["neval"])
-                            if rec("LearningRate", state):
-                                self.train_summary.add_scalar(
-                                    "LearningRate", lr, state["neval"])
-                            if rec("Throughput", state):
-                                self.train_summary.add_scalar(
-                                    "Throughput",
-                                    self.batch_size / max(t2 - t0, 1e-9),
-                                    state["neval"])
-                        if self._remediation_tick(state, params, opt_state,
-                                                  mstate, health_events,
-                                                  step_time_s=t2 - t1):
-                            box["done"] = True
-                            return
-                        self._fire_mid_epoch(state, params, opt_state, mstate)
-                        if self.end_trigger(state):
-                            box["done"] = True
-                            return
-        finally:
-            box.update(params=params, opt_state=opt_state, mstate=mstate,
-                       nan_streak=nan_streak)
-
     def _clamp_superstep(self, state, k):
         """Largest j <= k such that no end/validation/checkpoint trigger
         would fire at an iteration INTERIOR to a j-step dispatch: the
@@ -1770,199 +1645,256 @@ class BaseOptimizer:
                     return i
         return k
 
-    def _run_epoch_supersteps(self, batches, state, box):
-        """Superstep (K > 1) epoch loop: ``batches`` yields stacked
-        ``(k, xs, ys)`` groups; each dispatch runs k fused steps inside
-        one XLA program and the host resolves the whole ``[k]`` loss
-        vector with ONE batched readback — per-step bookkeeping (loss
-        observation, NaN policy, summaries, triggers) then replays
-        host-side over the resolved vector, preserving K=1 semantics at
-        1/K the sync count. Same ``box`` contract as _run_epoch_steps."""
+    def _account_loss(self, state, box, step_no, loss_val, lr, rate,
+                      events):
+        """Account ONE dispatched step by its resolved loss — the one
+        place that holds the NaN policy, the flight record, the loss
+        monitor and the summaries, for any K. ``step_no`` is the 1-based
+        iteration that PRODUCED ``loss_val`` (under async/window:K up to
+        K-1 before the step being counted); ``loss_val`` is None when a
+        windowed policy has resolved nothing yet. The monitor's events go
+        onto ``events``. Says what became of the step: ``_COUNTED``,
+        ``_SKIPPED`` (non-finite, 'skip'), or ``_RESTORED`` (non-finite,
+        'resume': the checkpoint's state is in ``box`` and
+        ``optim_method.state``, and whatever the dispatch resolved after
+        this loss describes updates the restore just discarded)."""
+        if loss_val is None or np.isfinite(loss_val):
+            if loss_val is not None:
+                # windowed policies have no resolved loss until K are in
+                # flight — the streak/loss state only moves on an
+                # actually-observed value
+                box["nan_streak"] = 0
+                state["loss"] = loss_val
+            state["neval"] += 1
+            state["epoch_finished"] = False
+            if loss_val is not None:
+                # provenance rides the already-resolved host float — no
+                # extra readback; under async/window:K the loss belongs
+                # to step_no, up to K-1 before the current iteration
+                if obs.enabled():
+                    _flight.record("step", neval=step_no,
+                                   epoch=state["epoch"], loss=loss_val)
+                if self._loss_monitor is not None:
+                    events.extend(
+                        self._loss_monitor.observe(loss_val, step_no))
+            if self.train_summary is not None:
+                rec = self.train_summary.should_record
+                if loss_val is not None and rec("Loss", state):
+                    self.train_summary.add_scalar(
+                        "Loss", loss_val, state["neval"])
+                if rec("LearningRate", state):
+                    self.train_summary.add_scalar(
+                        "LearningRate", lr, state["neval"])
+                if rec("Throughput", state):
+                    self.train_summary.add_scalar(
+                        "Throughput", rate, state["neval"])
+            return _COUNTED
+        box["nan_streak"] += 1
+        if obs.enabled():
+            _flight.record("nan", neval=step_no, epoch=state["epoch"],
+                           loss=loss_val, policy=self.nan_policy)
+        if self._loss_monitor is not None:
+            self._loss_monitor.observe(loss_val, step_no)
+        if self.nan_policy == "error":
+            raise FloatingPointError(
+                f"non-finite loss {loss_val} at iteration "
+                f"{state['neval']} — enable "
+                f"set_nan_policy('skip') to drop such steps")
+        if box["nan_streak"] > self.max_nan_retries:
+            raise FloatingPointError(
+                f"{box['nan_streak']} consecutive non-finite steps "
+                f"(nan_policy='{self.nan_policy}') — data or "
+                "hyperparameters are unrecoverably bad")
+        if self.nan_policy == "resume":
+            self.wait_for_checkpoints()  # in-flight writes
+            snap = self._latest_checkpoint()
+            if snap is None:
+                raise FloatingPointError(
+                    "non-finite loss with nan_policy='resume' "
+                    "but no checkpoint saved yet — call "
+                    "set_checkpoint(...) first")
+            with open(snap, "rb") as f:
+                payload = pickle.load(f)
+            self.optim_method.state.update(payload["optim_host_state"])
+            box["params"], box["opt_state"], box["mstate"] = \
+                self._restore_step_state(payload)
+            # in-flight losses refer to pre-restore steps
+            self._pending_loss = None
+            self._loss_window.clear()
+            self.metrics.add("nan_resumes", 1.0)
+            obs.instant("step/nan_resume", neval=state["neval"])
+            return _RESTORED
+        # 'skip': the in-step guard (in-scan, under fusion) already kept
+        # the previous params; count the iteration so end triggers advance
+        self.metrics.add("nan_skips", 1.0)
+        obs.instant("step/nan_skip", neval=state["neval"])
+        state["neval"] += 1
+        return _SKIPPED
+
+    def _run_epoch(self, batches, state, box, fused):
+        """One epoch of the pipelined step loop, for any K. ``batches``
+        yields the stager's elements ``(k, xs, ys)``, device-resident:
+        one batch (``k`` = 1, ``fused`` false) or a ``[k, batch, ...]``
+        stack whose k fused steps run inside one XLA program
+        (``fused``). An iteration fetches one element, prepares its lr
+        and rng (the ``[k]`` vectors), dispatches, resolves the losses —
+        at K = 1 what the sync policy hands out (none, or one that may
+        be older than this step), under fusion the whole ``[k]`` vector
+        with ONE batched readback — accounts each through
+        :meth:`_account_loss`, preserving K=1 semantics at 1/K the sync
+        count, and then does the boundary work once. Mutable step state
+        travels in ``box`` (params/opt_state/mstate/nan_streak/done) so
+        every exit path — exhaustion, end trigger, an exception mid-step
+        — leaves the caller with the latest device handles."""
         optim = self.optim_method
         params, opt_state, mstate = \
             box["params"], box["opt_state"], box["mstate"]
-        nan_streak = box["nan_streak"]
         pending = None  # clamped remainder of a group (device slices)
         try:
             while True:
                 self._step_beacon.pulse()
                 self._check_halt()
-                t0 = time.perf_counter()
-                if pending is not None:
-                    (k, xs, ys), pending = pending, None
-                else:
+                with obs.span("step", step_num=state["neval"]) as stp:
+                    t0 = time.perf_counter()
                     with obs.span("step/data_fetch"):
-                        try:
-                            k, xs, ys = next(batches)
-                        except StopIteration:
-                            return
-                j = self._clamp_superstep(state, k)
-                if j < k:
-                    # a trigger fires mid-group: dispatch the prefix now,
-                    # park the rest (device-side slices — no host copy)
-                    pending = (k - j, _tmap(lambda a: a[j:], xs),
-                               _tmap(lambda a: a[j:], ys))
-                    xs = _tmap(lambda a: a[:j], xs)
-                    ys = _tmap(lambda a: a[:j], ys)
-                    k = j
-                with obs.span("step/prepare"):
-                    scale = self._remediation_lr_scale  # *1.0: bitwise-exact
-                    lrs = [l * scale for l in optim.current_lr_vector(k)]
-                    rngs = engine.next_rng_keys(k)  # one dispatch, same stream
-                t1 = time.perf_counter()
-                with obs.span("step/superstep", step_num=state["neval"], k=k):
+                        if pending is not None:
+                            (k, xs, ys), pending = pending, None
+                        else:
+                            try:
+                                k, xs, ys = next(batches)
+                            except StopIteration:
+                                return
+                    t1 = time.perf_counter()
+                    with obs.span("step/prepare"):
+                        j = self._clamp_superstep(state, k)
+                        if j < k:
+                            # a trigger fires mid-group: dispatch the
+                            # prefix now, park the rest (device-side
+                            # slices — no host copy)
+                            pending = (k - j, _tmap(lambda a: a[j:], xs),
+                                       _tmap(lambda a: a[j:], ys))
+                            xs = _tmap(lambda a: a[:j], xs)
+                            ys = _tmap(lambda a: a[:j], ys)
+                            k = j
+                        # *1.0 is bitwise-exact: the remediation scale only
+                        # changes lr after a plateau actually reduced it
+                        scale = self._remediation_lr_scale
+                        if fused:
+                            stp.annotate(k=k)
+                            lr = lrs = [l * scale
+                                        for l in optim.current_lr_vector(k)]
+                            # one dispatch, same stream
+                            rng = engine.next_rng_keys(k)
+                        else:
+                            lr = optim.current_lr() * scale
+                            lrs = (lr,)
+                            rng = engine.next_rng_key()
                     dsp = obs.span("step/dispatch")
                     with dsp:
-                        losses_dev, params, opt_state, mstate = \
+                        loss, params, opt_state, mstate = \
                             self._dispatch_guarded(
                                 params, opt_state, mstate, xs, ys,
-                                jnp.asarray(lrs, jnp.float32), rngs)
+                                jnp.asarray(lr, jnp.float32), rng)
+                    # the last COMPLETED dispatch's handles: what the
+                    # watchdog-thread stall remediation checkpoints
                     self._live_state = (params, opt_state, mstate)
                     self._tighten_stall_deadline()
                     if obs.enabled():
                         obs.counter("engine/dispatches").inc()
                     with obs.span("step/loss_sync"):
-                        # sync-ok: the ONE batched [k] readback per superstep
-                        losses = np.asarray(losses_dev)
-                    if obs.enabled():
-                        obs.counter("optim/loss_syncs").inc()
-                t2 = time.perf_counter()
-                # the host replay of the resolved [k] losses, then the
-                # triggers at the superstep boundary
-                with obs.span("step/triggers"):
-                    self.metrics.add("data_time", t1 - t0)
-                    self.metrics.add("step_time", t2 - t1)
-                    if obs.enabled():
-                        obs.counter("optim/steps").inc(k)
-                        obs.gauge("optim/throughput", unit="samples/s").set(
-                            k * self.batch_size / max(t2 - t0, 1e-9))
-                        # one artifact covers the whole K-step program (a
-                        # clamped j<K dispatch reads ITS program's artifact,
-                        # not the full-K one), so flops over the FULL
-                        # iteration wall IS the fused-dispatch MFU; compile
-                        # dispatches are excluded like bench warmup
-                        if not getattr(self._step_fn, "last_call_compiled",
-                                       True):
-                            obs.perf.note_step(
-                                getattr(self._step_fn, "last_artifact", None),
-                                wall_s=t2 - t0, host_s=t1 - t0,
-                                dispatch_s=dsp.duration_s)
-                        self._snap_writer.maybe_write(step=state["neval"])
-                    restored = False
-                    health_events = []
-                    for i, loss_val in enumerate(losses.tolist()):
-                        if not np.isfinite(loss_val):
-                            nan_streak += 1
+                        # step provenance: the dispatch just issued is
+                        # iterations neval+1 .. neval+k
+                        first = state["neval"] + 1
+                        if fused:
+                            # sync-ok: the ONE batched [k] readback per superstep
+                            losses = np.asarray(loss).tolist()
+                            steps = range(first, first + k)
                             if obs.enabled():
-                                # superstep-vector aware: the host replay of
-                                # the batched [k] readback feeds the recorder
-                                # and detector per microstep
-                                _flight.record("nan", neval=state["neval"],
-                                               epoch=state["epoch"],
-                                               loss=loss_val,
-                                               policy=self.nan_policy,
-                                               superstep_k=k, microstep=i)
-                            if self._loss_monitor is not None:
-                                self._loss_monitor.observe(loss_val,
-                                                           state["neval"])
-                            if self.nan_policy == "error":
-                                raise FloatingPointError(
-                                    f"non-finite loss {loss_val} at iteration "
-                                    f"{state['neval']} — enable "
-                                    "set_nan_policy('skip') to drop such "
-                                    "steps")
-                            if nan_streak > self.max_nan_retries:
-                                raise FloatingPointError(
-                                    f"{nan_streak} consecutive non-finite "
-                                    f"steps (nan_policy='{self.nan_policy}')"
-                                    " — data or hyperparameters are "
-                                    "unrecoverably bad")
-                            if self.nan_policy == "resume":
-                                self.wait_for_checkpoints()  # in-flight writes
-                                snap = self._latest_checkpoint()
-                                if snap is None:
-                                    raise FloatingPointError(
-                                        "non-finite loss with nan_policy="
-                                        "'resume' but no checkpoint saved yet "
-                                        "— call set_checkpoint(...) first")
-                                with open(snap, "rb") as f:
-                                    payload = pickle.load(f)
-                                self.optim_method.state.update(
-                                    payload["optim_host_state"])
-                                params, opt_state, mstate = \
-                                    self._restore_step_state(payload)
-                                # the rest of this group's losses describe
-                                # updates the restore just discarded
-                                self.metrics.add("nan_resumes", 1.0)
-                                obs.instant("step/nan_resume",
-                                            neval=state["neval"])
-                                restored = True
+                                obs.counter("optim/loss_syncs").inc()
+                        else:
+                            # async/window:K resolve an OLDER step —
+                            # _resolved_step names it
+                            losses = (self._observe_loss(loss, first),)
+                            steps = (self._resolved_step,)
+                    t2 = time.perf_counter()
+                    # what follows the resolved losses: their host replay
+                    # step by step, then bookkeeping, remediation and the
+                    # triggers (validation, checkpoint, the caller's end
+                    # trigger) once, at the boundary
+                    with obs.span("step/triggers"):
+                        rate = k * self.batch_size / max(t2 - t0, 1e-9)
+                        health_events = []
+                        for step_no, loss_val, step_lr in zip(steps, losses,
+                                                              lrs):
+                            fate = self._account_loss(
+                                state, box, step_no, loss_val, step_lr,
+                                rate, health_events)
+                            if fate is _RESTORED:
+                                params, opt_state, mstate = box["params"], \
+                                    box["opt_state"], box["mstate"]
                                 break
-                            # 'skip': the in-scan guard already kept the
-                            # previous state; count the iteration so end
-                            # triggers advance
-                            self.metrics.add("nan_skips", 1.0)
-                            obs.instant("step/nan_skip", neval=state["neval"])
-                            state["neval"] += 1
+                        if fate is not _COUNTED:
+                            # as at K=1, a step that did not count goes on
+                            # to the next dispatch past the boundary work.
+                            # But a group's pre-NaN spike/plateau events
+                            # describe losses that really happened — the
+                            # policy must see them, or a diverging run that
+                            # interleaves spikes with NaN restores starves
+                            # max_spikes forever and loops
+                            # checkpoint-restore indefinitely
+                            if health_events and self._remediation_tick(
+                                    state, params, opt_state, mstate,
+                                    health_events, step_time_s=t2 - t1):
+                                box["done"] = True
+                                return
                             continue
-                        nan_streak = 0
-                        state["loss"] = loss_val
-                        state["neval"] += 1
-                        state["epoch_finished"] = False
+                        if self._profiler is not None:
+                            self._profiler.maybe_tick(state["neval"])
+                        self.metrics.add("data_time", t1 - t0)
+                        self.metrics.add("step_time", t2 - t1)
                         if obs.enabled():
-                            _flight.record("step", neval=state["neval"],
-                                           epoch=state["epoch"], loss=loss_val,
-                                           superstep_k=k, microstep=i)
-                        if self._loss_monitor is not None:
-                            health_events.extend(self._loss_monitor.observe(
-                                loss_val, state["neval"]))
-                        if self.train_summary is not None:
-                            rec = self.train_summary.should_record
-                            if rec("Loss", state):
-                                self.train_summary.add_scalar(
-                                    "Loss", loss_val, state["neval"])
-                            if rec("LearningRate", state):
-                                self.train_summary.add_scalar(
-                                    "LearningRate", lrs[i], state["neval"])
-                            if rec("Throughput", state):
-                                self.train_summary.add_scalar(
-                                    "Throughput",
-                                    k * self.batch_size / max(t2 - t0, 1e-9),
-                                    state["neval"])
-                    if restored:
-                        # the group's pre-NaN spike/plateau events describe
-                        # losses that really happened — the policy must see
-                        # them, or a diverging run that interleaves spikes
-                        # with NaN restores starves max_spikes forever and
-                        # loops checkpoint-restore indefinitely
+                            obs.counter("optim/steps").inc(k)
+                            obs.gauge("optim/throughput",
+                                      unit="samples/s").set(rate)
+                            # live MFU + step-phase gauges: host floats the
+                            # loop already measured, zero new readbacks. A
+                            # dispatch that paid a compile measures XLA, not
+                            # the model — excluded, like bench warmup. The
+                            # wall is the FULL iteration (t0→t2): under
+                            # async/window:K the dispatch+resolve sliver
+                            # alone excludes the device time entirely. One
+                            # artifact covers the whole K-step program (a
+                            # clamped j<K dispatch reads ITS program's
+                            # artifact, not the full-K one), so flops over
+                            # that wall IS the fused-dispatch MFU.
+                            if not getattr(self._step_fn,
+                                           "last_call_compiled", True):
+                                obs.perf.note_step(
+                                    getattr(self._step_fn, "last_artifact",
+                                            None),
+                                    wall_s=t2 - t0, host_s=t1 - t0,
+                                    dispatch_s=dsp.duration_s)
+                            self._snap_writer.maybe_write(
+                                step=state["neval"])
                         if self._remediation_tick(state, params, opt_state,
                                                   mstate, health_events,
                                                   step_time_s=t2 - t1):
                             box["done"] = True
                             return
-                        continue
-                    if self._profiler is not None:
-                        self._profiler.maybe_tick(state["neval"])
-                    if self._remediation_tick(state, params, opt_state, mstate,
-                                              health_events,
-                                              step_time_s=t2 - t1):
-                        box["done"] = True
-                        return
-                    # checkpoint/validation/end triggers evaluate ONCE at the
-                    # superstep boundary, where params and the iteration
-                    # counter are consistent: clamping already aligned every
-                    # counter-driven firing point to a boundary, and a
-                    # loss-driven trigger (which the probe cannot foresee)
-                    # defers at most K-1 steps — it must never pair interior
-                    # counters with post-superstep params in a checkpoint
-                    if self._fire_mid_epoch(state, params, opt_state, mstate):
-                        pass
-                    if self.end_trigger(state):
-                        box["done"] = True
-                        return
+                        # checkpoint/validation/end triggers evaluate ONCE
+                        # at the superstep boundary, where params and the
+                        # iteration counter are consistent: clamping already
+                        # aligned every counter-driven firing point to a
+                        # boundary, and a loss-driven trigger (which the
+                        # probe cannot foresee) defers at most K-1 steps —
+                        # it must never pair interior counters with
+                        # post-superstep params in a checkpoint
+                        self._fire_mid_epoch(state, params, opt_state, mstate)
+                        if self.end_trigger(state):
+                            box["done"] = True
+                            return
         finally:
-            box.update(params=params, opt_state=opt_state, mstate=mstate,
-                       nan_streak=nan_streak)
+            box.update(params=params, opt_state=opt_state, mstate=mstate)
 
     def _fire_mid_epoch(self, state, params, opt_state, mstate):
         fired = False
@@ -2228,26 +2160,20 @@ class DistriOptimizer(BaseOptimizer):
             return True
         return bool(se)
 
-    def _build_sparse_step(self):
+    def _sparse_exchange(self):
         """The per-layer gradient-wire path (sparse_embedding=True):
-        an EXPLICIT shard_map data-parallel step — unlike the default
-        replicated path (where XLA's sharding propagation inserts one
-        implicit psum over all grads), each gradient leaf here picks
-        its own wire at trace time. The embedding leaf ships
-        ``(indices, value rows)`` via the Parallax exchange when that
-        is fewer elements than its dense gradient; everything else
-        rides ``pmean``. Trace-time byte counters
+        the exchange of an EXPLICIT shard_map data-parallel step —
+        unlike the default replicated path (where XLA's sharding
+        propagation inserts one implicit psum over all grads), each
+        gradient leaf here picks its own wire at trace time. The
+        embedding leaf ships ``(indices, value rows)`` via the Parallax
+        exchange when that is fewer elements than its dense gradient;
+        everything else rides ``pmean``. Trace-time byte counters
         (``collective/sparse_grad_wire_traced_bytes`` vs
         ``collective/grad_dense_traced_bytes``) make the win
         auditable per dispatch."""
-        from ..utils.compat import shard_map
         from ..nn.sparse import embedding_grad_rows
         from ..parallel.allreduce import sparse_embedding_grad_allreduce
-        clip_const, clip_norm = self.clip_const, self.clip_norm
-        optim = self.optim_method
-        frozen_mask = _frozen_mask(self.model)
-        loss_fn = _loss_fn(self.model, self.criterion)
-        mesh = self.mesh
         path, vocab = self._sparse_embedding_path()
         superstep_k = self.superstep
 
@@ -2272,9 +2198,10 @@ class DistriOptimizer(BaseOptimizer):
                             traced_steps=superstep_k)
                 if obs.enabled():
                     # trace-time: bytes this leaf ships on the dense wire
+                    # sync-ok: static shape arithmetic, no device value
+                    nbytes = float(g.size * g.dtype.itemsize) * superstep_k
                     obs.counter("collective/grad_dense_traced_bytes",
-                                unit="B").inc(
-                        float(g.size * g.dtype.itemsize) * superstep_k)
+                                unit="B").inc(nbytes)
                 return jax.lax.pmean(g, "data")
 
             out = walk(grads)
@@ -2283,43 +2210,7 @@ class DistriOptimizer(BaseOptimizer):
                     picked["sparse"])
             return out
 
-        def local_step(params, opt_state, mstate, x, y, lr, rng):
-            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
-            (loss, new_mstate), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, mstate, x, y, rng)
-            grads = exchange(grads, x)
-            grads = _clip_grads(grads, clip_const, clip_norm)
-            with jax.named_scope("optim_update"):
-                if frozen_mask is not None:
-                    grads = _tmap(lambda g, m: g * m, grads, frozen_mask)
-                new_params, new_opt = optim.update(grads, params, opt_state,
-                                                   lr)
-                if frozen_mask is not None:
-                    new_params = _tmap(
-                        lambda n, o, m: jnp.where(m > 0, n, o),
-                        new_params, params, frozen_mask)
-                loss = jax.lax.pmean(loss, "data")
-                new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"),
-                                   new_mstate)
-                # guarded after the pmean, so every shard takes the same
-                # branch — no divergence across the mesh
-                return (loss,) + _guarded(
-                    loss, (new_params, new_opt, new_mstate),
-                    (params, opt_state, mstate))
-
-        if superstep_k > 1:
-            sharded = shard_map(
-                _scan_superstep(local_step), mesh=mesh,
-                in_specs=(P(), P(), P(), P(None, "data"),
-                          P(None, "data"), P(), P()),
-                out_specs=(P(), P(), P(), P()), check_vma=False)
-        else:
-            sharded = shard_map(
-                local_step, mesh=mesh,
-                in_specs=(P(), P(), P(), P("data"), P("data"), P(), P()),
-                out_specs=(P(), P(), P(), P()), check_vma=False)
-        return self._instrument_step(
-            jax.jit(sharded, donate_argnums=(0, 1, 2)))
+        return exchange
 
     def _step_trace_context(self):
         # replicated mode's default step is a plain jit whose batch dim
@@ -2328,78 +2219,35 @@ class DistriOptimizer(BaseOptimizer):
         from ..parallel.flash import data_parallel_context
         return data_parallel_context(self.mesh, "data")
 
-    def _build_step(self):
-        if self.parameter_mode != "zero1":
-            if self._sparse_embedding_enabled():
-                return self._build_sparse_step()
-            return super()._build_step()
+    def _step_mode(self):
+        """The two explicit modes differ from the replicated default in
+        how the gradient is exchanged and who applies the update:
+        ``zero1`` differentiates over the flat vector and exchanges
+        INSIDE ``arp.update`` (sharded optimizer state); the sparse wire
+        exchanges per leaf (:meth:`_sparse_exchange`) before the optim
+        method's own update on replicated state."""
+        loss_fn, frozen_mask, exchange, update, specs = super()._step_mode()
+        if self.parameter_mode == "zero1":
+            arp, flat, superstep_k = self._arp, self._flat, self.superstep
+            if frozen_mask is not None:
+                frozen_mask = flat.flatten(_tmap(
+                    lambda p, m: jnp.full(jnp.shape(p), m, jnp.float32),
+                    self.model.params, frozen_mask))
+            tree_loss_fn = loss_fn
 
-        from ..utils.compat import shard_map
-        from jax.flatten_util import ravel_pytree
-        model = self.model
-        clip_const, clip_norm = self.clip_const, self.clip_norm
-        arp, flat = self._arp, self._flat
-        mesh = self.mesh
-        fm = _frozen_mask(model)
-        flat_mask = None
-        if fm is not None:
-            full = _tmap(lambda p, m: jnp.full(jnp.shape(p), m,
-                                               jnp.float32),
-                         model.params, fm)
-            flat_mask = flat.flatten(full)
+            def loss_fn(flat_w, mstate, x, y, rng):
+                return tree_loss_fn(flat.unflatten(flat_w), mstate, x, y,
+                                    rng)
 
-        tree_loss_fn = _loss_fn(model, self.criterion)
+            def update(gflat, flat_w, opt_slice, lr):
+                return arp.update(gflat, flat_w, opt_slice, lr,
+                                  traced_steps=superstep_k)
 
-        def loss_fn(flat_w, mstate, x, y, rng):
-            return tree_loss_fn(flat.unflatten(flat_w), mstate, x, y, rng)
-
-        superstep_k = self.superstep
-
-        def local_step(flat_w, opt_slice, mstate, x, y, lr, rng):
-            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
-            (loss, new_mstate), gflat = jax.value_and_grad(
-                loss_fn, has_aux=True)(flat_w, mstate, x, y, rng)
-            gflat = _clip_grads(gflat, clip_const, clip_norm)
-            with jax.named_scope("optim_update"):
-                if flat_mask is not None:
-                    gflat = gflat * flat_mask
-                new_flat, new_opt = arp.update(gflat, flat_w, opt_slice, lr,
-                                               traced_steps=superstep_k)
-                if flat_mask is not None:
-                    new_flat = jnp.where(flat_mask > 0, new_flat, flat_w)
-                loss = jax.lax.pmean(loss, "data")
-                new_mstate = _tmap(lambda t: jax.lax.pmean(t, "data"),
-                                   new_mstate)
-                # guarded after the pmean, so every shard takes the same
-                # branch — no divergence across the mesh
-                return (loss,) + _guarded(
-                    loss, (new_flat, new_opt, new_mstate),
-                    (flat_w, opt_slice, mstate))
-
-        opt_specs = arp.state_specs()
-        mstate_specs = _tmap(lambda _: P(), self.model.state)
-        if superstep_k > 1:
-            # the scan lives INSIDE the shard_map body: the ZeRO-1
-            # psum_scatter/update/all_gather cycle stays in the compiled
-            # loop (the cross-replica sharded update must ride the scan
-            # for superstep fusion to pay off — one program, K collective
-            # rounds, zero host round-trips in between). Batch stacks
-            # carry the scan dim first, per-step batch dim sharded.
-            sharded = shard_map(
-                _scan_superstep(local_step), mesh=mesh,
-                in_specs=(P(), opt_specs, mstate_specs, P(None, "data"),
-                          P(None, "data"), P(), P()),
-                out_specs=(P(), P(), opt_specs, mstate_specs),
-                check_vma=False)
-        else:
-            sharded = shard_map(
-                local_step, mesh=mesh,
-                in_specs=(P(), opt_specs, mstate_specs, P("data"), P("data"),
-                          P(), P()),
-                out_specs=(P(), P(), opt_specs, mstate_specs),
-                check_vma=False)
-        return self._instrument_step(
-            jax.jit(sharded, donate_argnums=(0, 1, 2)))
+            specs = (P(), arp.state_specs(),
+                     _tmap(lambda _: P(), self.model.state))
+        elif self._sparse_embedding_enabled():
+            exchange, specs = self._sparse_exchange(), (P(), P(), P())
+        return loss_fn, frozen_mask, exchange, update, specs
 
 
 class ParallelOptimizer(DistriOptimizer):

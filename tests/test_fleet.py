@@ -455,8 +455,11 @@ def test_remote_tokens_bitwise_and_fleet_swap_never_mixes(tmp_path):
 
         # two-phase fleet swap over the wire: publish ships the tree,
         # activate flips — later admissions serve the new version and
-        # answer with ITS tokens (no response mixes versions)
-        p2 = jax.tree_util.tree_map(lambda a: a * 1.01, m.params)
+        # answer with ITS tokens (no response mixes versions). The
+        # second version has to be one whose tokens differ, or the swap
+        # is invisible: * 1.01 moves no argmax of this model, * 1.5
+        # moves every prompt's tokens
+        p2 = jax.tree_util.tree_map(lambda a: a * 1.5, m.params)
         v2 = router.swap(p2)
         local.swap(p2, version=v2)
         futs2 = [router.submit(p, max_new_tokens=12) for p in prompts]

@@ -129,9 +129,13 @@ def _spy_guard(paged_path):
 
 def test_paged_decode_bitwise_vs_dense():
     """decode_paged over gathered blocks == decode_chunk over a dense
-    cache, bitwise, for the same batch (history + one step; RoPE+GQA
-    model — per-row rotary positions and the grouped einsum both
-    covered)."""
+    cache for the same batch (history + one step; RoPE+GQA model —
+    per-row rotary positions and the grouped einsum both covered): the
+    argmax tokens equal and the logits within 1e-6 absolute. That is
+    what XLA-CPU can give: the two steps contract the same sum over
+    differently shaped operands (gathered blocks against one dense
+    cache), and two contractions of one sum differ in the last ulp
+    (0.116624 against 0.11662401 here; ROADMAP Design 8)."""
     m = shared_model()
     p = m.params
     B, bs, mbs = 4, 4, 8
@@ -152,7 +156,9 @@ def test_paged_decode_bitwise_vs_dense():
         lg_p, pages = step(jnp.asarray(toks[:, t:t + 1]), ps, pages)
         lg_d, caches = dense(jnp.asarray(toks[:, t:t + 1]), jnp.int32(t),
                              caches)
-    assert np.array_equal(np.asarray(lg_p), np.asarray(lg_d))
+    lg_p, lg_d = np.asarray(lg_p), np.asarray(lg_d)
+    assert np.array_equal(lg_p.argmax(-1), lg_d.argmax(-1))
+    np.testing.assert_allclose(lg_p, lg_d, rtol=0, atol=1e-6)
 
 
 def test_prefill_schedule():

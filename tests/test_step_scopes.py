@@ -132,9 +132,9 @@ def test_remat_recomputes_the_block_but_not_the_flash_forward(monkeypatch):
 
 
 def test_the_sparse_step_carries_the_same_four_scope_names():
-    """``_build_sparse_step`` wants the ids to be the model's input, so
-    its toy is a ``Sequential`` whose children carry their container keys
-    (``Container.child_apply``)."""
+    """The sparse wire (``_sparse_exchange``) wants the ids to be the
+    model's input, so its toy is a ``Sequential`` whose children carry
+    their container keys (``Container.child_apply``)."""
     model = nn.Sequential().add(nn.LookupTable(64, 8)) \
         .add(nn.Select(2, 1)).add(nn.Linear(8, 3)).add(nn.LogSoftMax())
     rng = np.random.RandomState(0)

@@ -265,6 +265,128 @@ def test_superstep_nan_resume_replays_checkpoint(tmp_path):
     assert stager_threads_alive() == 0
 
 
+@pytest.fixture()
+def traced():
+    """Observability on for one test, everything it gathered dropped
+    before and after."""
+    from bigdl_tpu.observability import flight, health
+
+    def clean():
+        obs.reset()
+        obs.registry().reset()
+        flight.reset()
+        health.reset()
+
+    clean()
+    obs.enable()
+    try:
+        yield
+    finally:
+        obs.disable()
+        clean()
+
+
+@pytest.mark.parametrize("policy", ["skip", "resume"])
+@pytest.mark.parametrize("k", [1, 4])
+def test_nan_records_name_the_same_iteration_for_any_k(k, policy, tmp_path,
+                                                       traced):
+    """ONE accounting of a resolved loss for any K: the batch fed to
+    iteration 6 is poisoned, and the flight ring's ``nan`` record, the
+    loss monitor (through its ``health/nan_streak`` event) and the
+    counters say so in the same 1-based terms at K=1 and inside a group
+    of 4 (iterations 5..8, microstep 1)."""
+    from bigdl_tpu.observability import flight
+    ds = _FixedBatches(n_batches=8, poison_at=5)
+    m = nn.Linear(4, 1)
+    opt = LocalOptimizer(m, ds, nn.MSECriterion(), SGD(learningrate=0.05),
+                         max_epoch(1), batch_size=16)
+    opt.set_checkpoint(several_iteration(4), str(tmp_path))
+    opt.set_superstep(k).set_nan_policy(policy)
+    opt.set_anomaly_detection(nan_streak=1)
+    opt.optimize()
+    events = flight.recorder().events()
+    nans = [e for e in events if e["kind"] == "nan"]
+    assert [(e["neval"], e["policy"]) for e in nans] == [(6, policy)]
+    assert [e["step"] for e in events
+            if e["kind"] == "health/nan_streak"] == [6]
+    counted = {name: len(opt.metrics.values.get(name, []))
+               for name in ("nan_skips", "nan_resumes")}
+    assert counted == {"nan_skips": policy == "skip",
+                       "nan_resumes": policy == "resume"}
+    # every counted step's record carries its own 1-based iteration too
+    steps = [e["neval"] for e in events if e["kind"] == "step"]
+    assert steps[:5] == [1, 2, 3, 4, 5]
+    if policy == "skip":
+        # the skipped iteration counts, so the epoch's 8 batches end at 8
+        assert steps == [1, 2, 3, 4, 5, 7, 8]
+        assert opt.optim_method.state["neval"] == 8
+    assert all(np.isfinite(l).all() for l in _flat(m.params))
+    assert stager_threads_alive() == 0
+
+
+CHILDREN = ["step/data_fetch", "step/prepare", "step/dispatch",
+            "step/loss_sync", "step/triggers"]
+
+
+@pytest.mark.parametrize("k", [1, 4])
+def test_an_iteration_is_one_step_span_with_its_five_children(k, tmp_path,
+                                                              traced):
+    """ONE epoch loop for any K: every iteration is a ``step`` span that
+    holds ``step/data_fetch``, ``step/prepare``, ``step/dispatch``,
+    ``step/loss_sync`` and ``step/triggers`` in this order, and nothing of
+    the iteration lies outside it. A checkpoint every 3 iterations cuts
+    the K=4 groups (3 + the parked 1, then 2), so the parked remainder's
+    iteration is held to the same shape."""
+    engine.set_seed(3)
+    rng = np.random.RandomState(0)
+    ds = DataSet.from_arrays(rng.randn(128, 16).astype(np.float32),
+                             rng.randn(128, 4).astype(np.float32))
+    m = nn.Sequential(nn.Linear(16, 32), nn.Tanh(), nn.Linear(32, 4))
+    opt = LocalOptimizer(m, ds, nn.MSECriterion(), SGD(learningrate=0.05),
+                         max_iteration(6), batch_size=32)
+    opt.set_checkpoint(several_iteration(3), str(tmp_path))
+    opt.set_superstep(k)
+    opt.optimize()
+    spans = obs.get_tracer().events()
+    steps = sorted((s for s in spans if s.name == "step"),
+                   key=lambda s: s.start_ns)
+    loop_thread = {s.tid for s in steps}
+    assert len(loop_thread) == 1
+    # nothing of the iteration outside: on the loop's thread every span
+    # named step/... lies inside a step, and no other top-level span
+    # begins between the first step and the last
+    for s in spans:
+        if s.tid in loop_thread and s.name.startswith("step/"):
+            assert s.depth >= 1, s
+            assert any(p.start_ns <= s.start_ns and s.end_ns <= p.end_ns
+                       for p in steps), s
+    assert not [s for s in spans
+                if s.tid in loop_thread and s.depth == 0
+                and s.name != "step"
+                and steps[0].start_ns <= s.start_ns <= steps[-1].end_ns]
+    assert "step/superstep" not in {s.name for s in spans}
+    done = []
+    for p in steps:
+        inside = sorted((s for s in spans if s.tid == p.tid
+                         and s.depth == p.depth + 1
+                         and p.start_ns <= s.start_ns
+                         and s.end_ns <= p.end_ns),
+                        key=lambda s: s.start_ns)
+        names = [s.name for s in inside]
+        if names == ["step/data_fetch"]:
+            continue          # the probe that found the epoch exhausted
+        assert names == CHILDREN, (p.args, names)
+        done.append(p.args)
+    if k == 1:
+        assert [a["step_num"] for a in done] == [0, 1, 2, 3, 4, 5]
+        assert all("k" not in a for a in done)
+    else:
+        assert [(a["step_num"], a["k"]) for a in done] == \
+            [(0, 3), (3, 1), (4, 2)]
+    assert opt.optim_method.state["neval"] == 6
+    assert stager_threads_alive() == 0
+
+
 # ---------------------------------------------------------------------------
 # boundary clamping: triggers and checkpoints fire at K=1-identical points
 # ---------------------------------------------------------------------------

@@ -25,10 +25,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # file -> function names whose bodies form the training hot path
 HOT_FUNCS = {
     "bigdl_tpu/optim/optimizer.py": {
-        "optimize", "_optimize_impl", "_run_epoch_steps",
-        "_run_epoch_supersteps",
+        # the ONE epoch loop for any K, the ONE accounting of a resolved
+        # loss (NaN policy, flight record, monitor, summaries), and the
+        # ONE builder of the compiled step with what a mode puts into it
+        "optimize", "_optimize_impl", "_run_epoch", "_account_loss",
         "_clamp_superstep", "_observe_loss", "_drain_pending_losses",
-        "_stage_minibatch", "_stage_minibatch_host", "_stage_group",
+        "_build_step", "_step_mode", "_sparse_exchange",
+        "_stage_minibatch", "_host_xy", "_stage_group",
         "_place_batch", "_place_group",
         # self-healing paths that run inside the step loop: the guarded
         # dispatch (its host snapshot is the one deliberate per-dispatch
