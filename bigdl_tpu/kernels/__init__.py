@@ -5,8 +5,9 @@ ops benefit from explicit tiling/fusion beyond what XLA does automatically.
 Those live here, each with an interpret-mode path so the CPU test suite
 exercises the same kernel code the TPU runs.
 """
-from .flash_attention import flash_attention_fused, flash_attention_rows
+from .flash_attention import (flash_attention_fused, flash_attention_qkv,
+                              flash_attention_rows)
 from .paged_attention import paged_decode_attention
 
-__all__ = ["flash_attention_fused", "flash_attention_rows",
-           "paged_decode_attention"]
+__all__ = ["flash_attention_fused", "flash_attention_qkv",
+           "flash_attention_rows", "paged_decode_attention"]

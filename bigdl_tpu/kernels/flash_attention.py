@@ -19,13 +19,19 @@ copied on either side of the call. A block is ``(1, block, lanes)`` with
 one grid step does those ``g`` heads' work, one head after the other in a
 loop on the device (:func:`_each_head`; unrolled, the two-head bodies of a
 24-layer step cost ten seconds of set-up more, measured on the chip's host).
-Inside the block a head is told apart by a lane mask and not by a lane slice: its q (and dO) are zeroed on
-the other heads' lanes and contracted over all of them, and an operand that
-gives a ``(., D)`` product (v, k, q, dO) is zeroed likewise, so the product
-lands on the head's own lanes of the 128-lane accumulator and adds nothing
-elsewhere. The ``[B, H, T, D]`` entries (decode caches, ring blocks) are the
-same kernels over ``[B * H, T, D]``: one head a row, its D the whole minor
-dimension.
+An operand need not be an array of its own: a block is addressed by its
+lane-block index, so where self-attention projects q, k and v with ONE
+matmul the three are the lane blocks ``p``, ``P + p`` and ``2P + p`` of that
+matmul's output ``[N, T, 3 * heads * D]`` (:func:`flash_attention_qkv`), and
+no q, k or v array is made; only the gradient is assembled, dq, dk and dv
+concatenated.
+Inside the block a head is told apart by a lane mask and not by a lane
+slice: its q (and dO) are zeroed on the other heads' lanes and contracted
+over all of them, and an operand that gives a ``(., D)`` product (v, k, q,
+dO) is zeroed likewise, so the product lands on the head's own lanes of the
+128-lane accumulator and adds nothing elsewhere. The ``[B, H, T, D]``
+entries (decode caches, ring blocks) are the same kernels over ``[B * H, T,
+D]``: one head a row, its D the whole minor dimension.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
   * the streaming axis is the innermost grid dimension, so the VMEM scratch
@@ -81,6 +87,36 @@ def _pad_t(x, t_pad, axis=1):
     pad = [(0, 0)] * x.ndim
     pad[axis] = (0, t_pad - t)
     return jnp.pad(x, pad)
+
+
+def _pad_each(xs, t_pads):
+    """:func:`_pad_t` of each array to its length. An array given more than
+    once (the fused projection, as q, k and v) is padded once a length."""
+    done = {}
+    for x, t_pad in zip(xs, t_pads):
+        if (id(x), t_pad) not in done:
+            done[id(x), t_pad] = _pad_t(x, t_pad)
+    return [done[id(x), t_pad] for x, t_pad in zip(xs, t_pads)]
+
+
+def _rows_spec(block, w, t_index, at=0):
+    """BlockSpec of a ``(1, block, w)`` tile of a rows operand under the
+    kernels' grid ``(b, p, x, y)``: block ``t_index(x, y)`` along T and
+    lane block ``at + p``, ``at`` being where the operand starts inside a
+    wider array (k and v of the fused projection)."""
+    # an index map lowers the addition it is given, of 0 too: an operand
+    # that is an array of its own keeps the program it had
+    if at == 0:
+        return pl.BlockSpec((1, block, w),
+                            lambda b_, p_, x, y: (b_, t_index(x, y), p_))
+    return pl.BlockSpec((1, block, w),
+                        lambda b_, p_, x, y: (b_, t_index(x, y), p_ + at))
+
+
+def _qkv_specs(bq, bk, w, q_blk, k_blk, at):
+    """The :func:`_rows_spec` of q, k and v, each from its own start."""
+    return [_rows_spec(bq, w, q_blk, at[0]), _rows_spec(bk, w, k_blk, at[1]),
+            _rows_spec(bk, w, k_blk, at[2])]
 
 
 def _mm(a, b, ta=False, tb=False):
@@ -217,10 +253,14 @@ def _block_heads(heads, d):
 
 
 def _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k, interpret,
-              vma=None, q_offset=0, kv_len=None):
-    """q ``[N, Tq, heads*D]``, k/v ``[N, Tkv, heads*D]`` → o like q and
-    lse ``[N, heads, Tq]``."""
-    n, t_q, c = q.shape
+              vma=None, q_offset=0, kv_len=None, at=(0, 0, 0), width=None):
+    """q ``[N, Tq, heads*D]``, k/v ``[N, Tkv, heads*D]`` → o ``[N, Tq,
+    heads*D]`` and lse ``[N, heads, Tq]``. Where q, k and v are lanes of
+    wider arrays (ONE array, the fused projection:
+    :func:`flash_attention_qkv`), ``width`` is the ``heads*D`` lanes each
+    has and ``at`` the lane block at which each starts."""
+    n, t_q = q.shape[:2]
+    c = width or q.shape[2]
     d = c // heads
     g = _block_heads(heads, d)
     w = g * d
@@ -235,22 +275,20 @@ def _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k, interpret,
     tq_pad = (t_q + bq - 1) // bq * bq
     nk = (kv_len + bk - 1) // bk
     tkv_need = nk * bk
-    qp = _pad_t(q, tq_pad)
-    kp = _pad_t(k, tkv_need) if tkv_need > t_kv else k
-    vp = _pad_t(v, tkv_need) if tkv_need > t_kv else v
+    tkv_pad = max(tkv_need, t_kv)
+    qp, kp, vp = _pad_each((q, k, v), (tq_pad, tkv_pad, tkv_pad))
     nq = tq_pad // bq
 
     kernel = functools.partial(
         _fwd_kernel, d=d, g=g, scale=scale, block_q=bq, block_k=bk,
         causal=causal, kv_len=kv_len, nk=nk, q_offset=q_offset)
-    q_spec = pl.BlockSpec((1, bq, w), lambda b_, p_, i, j: (b_, i, p_))
-    k_spec = pl.BlockSpec((1, bk, w), lambda b_, p_, i, j: (b_, j, p_))
+    q_blk, k_blk = (lambda i, j: i), (lambda i, j: j)
     o, lse = pl.pallas_call(
         kernel,
         grid=(n, heads // g, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec],
+        in_specs=_qkv_specs(bq, bk, w, q_blk, k_blk, at),
         out_specs=[
-            q_spec,
+            _rows_spec(bq, w, q_blk),
             pl.BlockSpec((1, g, bq, 128),
                          lambda b_, p_, i, j: (b_, p_, i, 0)),
         ],
@@ -361,15 +399,18 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
-              delta=None, out_dtype=None, vma=None):
+              delta=None, out_dtype=None, vma=None, at=(0, 0, 0),
+              width=None):
     """``res`` = (q, k, v, o, lse) as :func:`_fwd_rows` takes and gives
-    them, ``g`` = dO like ``o``. ``delta`` (``[N, heads, Tq]``) and
-    ``out_dtype`` are for block-composed callers (parallel/ring_flash.py):
-    a ring backward precomputes the global rowsum(dO*O) once and needs f32
-    gradient outputs so per-hop accumulation does not round at the input
-    dtype."""
+    them (``at`` and ``width`` too), ``g`` = dO like ``o``; returns dq, dk
+    and dv as three arrays of ``heads*D`` lanes. ``delta`` (``[N, heads,
+    Tq]``) and ``out_dtype`` are for block-composed callers
+    (parallel/ring_flash.py): a ring backward precomputes the global
+    rowsum(dO*O) once and needs f32 gradient outputs so per-hop
+    accumulation does not round at the input dtype."""
     q, k, v, o, lse = res
-    n, t_q, c = q.shape
+    n, t_q = q.shape[:2]
+    c = width or q.shape[2]
     d = c // heads
     hb = _block_heads(heads, d)
     w = hb * d
@@ -387,7 +428,7 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
         delta = jnp.sum(prod.reshape(n, t_q, heads, d),
                         axis=-1).transpose(0, 2, 1)
 
-    qp, kp, vp = _pad_t(q, tq_pad), _pad_t(k, tkv_pad), _pad_t(v, tkv_pad)
+    qp, kp, vp = _pad_each((q, k, v), (tq_pad, tkv_pad, tkv_pad))
     dop = _pad_t(g, tq_pad)
     # lse/delta padded along T and broadcast into 128 lanes so each (bq, 128)
     # tile is layout-friendly
@@ -397,14 +438,17 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
 
     statics = dict(d=d, g=hb, scale=scale, block_q=bq, block_k=bk,
                    causal=causal, kv_len=t_kv)
-    q_spec = pl.BlockSpec((1, bq, w), lambda b_, p_, x, y: (b_, y, p_))
-    k_spec = pl.BlockSpec((1, bk, w), lambda b_, p_, x, y: (b_, x, p_))
+    # grid (b, p, key block x, query block y)
+    q_blk, k_blk = (lambda x, y: y), (lambda x, y: x)
+    q_spec = _rows_spec(bq, w, q_blk)
+    k_spec = _rows_spec(bk, w, k_blk)
     r_spec = pl.BlockSpec((1, hb, bq, 128),
                           lambda b_, p_, x, y: (b_, p_, y, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, nq=nq, **statics),
         grid=(n, heads // hb, nk, nq),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
+        in_specs=_qkv_specs(bq, bk, w, q_blk, k_blk, at) + [
+            q_spec, r_spec, r_spec],
         out_specs=[k_spec, k_spec],
         out_shape=[_sds((n, tkv_pad, c), out_dtype or k.dtype, vma),
                    _sds((n, tkv_pad, c), out_dtype or v.dtype, vma)],
@@ -414,14 +458,16 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
         name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
 
-    q_spec2 = pl.BlockSpec((1, bq, w), lambda b_, p_, x, y: (b_, x, p_))
-    k_spec2 = pl.BlockSpec((1, bk, w), lambda b_, p_, x, y: (b_, y, p_))
+    # grid (b, p, query block x, key block y)
+    q_blk, k_blk = (lambda x, y: x), (lambda x, y: y)
+    q_spec2 = _rows_spec(bq, w, q_blk)
     r_spec2 = pl.BlockSpec((1, hb, bq, 128),
                            lambda b_, p_, x, y: (b_, p_, x, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_q_kernel, nk=nk, **statics),
         grid=(n, heads // hb, nq, nk),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, r_spec2, r_spec2],
+        in_specs=_qkv_specs(bq, bk, w, q_blk, k_blk, at) + [
+            q_spec2, r_spec2, r_spec2],
         out_specs=q_spec2,
         out_shape=_sds((n, tq_pad, c), out_dtype or q.dtype, vma),
         scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
@@ -480,22 +526,28 @@ def _flash_vjp_fwd(q, k, v, heads, causal, scale, block_q, block_k,
                    interpret):
     o, lse = _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k,
                        interpret)
+    o, lse = _kept(o, lse)
+    return o, (q, k, v, o, lse)
+
+
+def _kept(o, lse):
+    """The two residuals the forward kernel itself computed, as a backward
+    pass keeps them."""
     # `lse` is lane 0 of the kernel's 128-lane output. Tied to `o`, the
     # slice runs before anything reads `o`; left free, XLA may put it off
     # until the backward pass, and what is held meanwhile (by a remat
     # policy that saves `lse`, or as a plain residual) is the 128-lane
     # array. The barrier moves no data.
     o, lse = jax.lax.optimization_barrier((o, lse))
-    # The two residuals the kernel itself computed are named for remat
-    # policies (q, k, v are not: a checkpointed caller recomputes them from
-    # its own input). `o` is named as the kernel wrote it: `[B, T, H*D]`,
+    # The two are named for remat policies (q, k, v and the fused
+    # projection are not: a checkpointed caller recomputes them from its
+    # own input). `o` is named as the kernel wrote it: `[B, T, H*D]`,
     # dense in HBM, is what the caller's output projection reads and the
     # backward kernels take. (Through the `[B, H, T, D]` entry it is
     # `[B*H, T, D]`, padded to 128 lanes for as long as it is kept where
     # D < 128.)
-    o = checkpoint_name(o, FLASH_OUT_NAME)
-    lse = checkpoint_name(lse, FLASH_LSE_NAME)
-    return o, (q, k, v, o, lse)
+    return (checkpoint_name(o, FLASH_OUT_NAME),
+            checkpoint_name(lse, FLASH_LSE_NAME))
 
 
 def _flash_vjp_bwd(heads, causal, scale, block_q, block_k, interpret, res,
@@ -505,6 +557,45 @@ def _flash_vjp_bwd(heads, causal, scale, block_q, block_k, interpret, res,
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _qkv_views(qkv, heads):
+    """q, k and v as the kernels find them in the fused projection ``[N,
+    T, 3 * heads * D]``: the one array three times, each ``heads * D``
+    lanes wide, starting at lane blocks 0, P and 2P (P blocks a view)."""
+    c = qkv.shape[2] // 3
+    p = heads // _block_heads(heads, c // heads)
+    return (qkv, qkv, qkv), dict(at=(0, p, 2 * p), width=c)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash_qkv(qkv, heads, causal, scale, block_q, block_k, interpret):
+    views, where = _qkv_views(qkv, heads)
+    o, _ = _fwd_rows(*views, heads, causal, scale, block_q, block_k,
+                     interpret, **where)
+    return o
+
+
+def _flash_qkv_vjp_fwd(qkv, heads, causal, scale, block_q, block_k,
+                       interpret):
+    views, where = _qkv_views(qkv, heads)
+    o, lse = _kept(*_fwd_rows(*views, heads, causal, scale, block_q,
+                              block_k, interpret, **where))
+    return o, (qkv, o, lse)
+
+
+def _flash_qkv_vjp_bwd(heads, causal, scale, block_q, block_k, interpret,
+                       res, g):
+    qkv, o, lse = res
+    views, where = _qkv_views(qkv, heads)
+    # the gradient of the fused projection, as the slices' transposes
+    # would assemble it
+    return (jnp.concatenate(
+        _bwd_rows(heads, causal, scale, block_q, block_k, interpret,
+                  (*views, o, lse), g, **where), axis=-1),)
+
+
+_flash_qkv.defvjp(_flash_qkv_vjp_fwd, _flash_qkv_vjp_bwd)
 
 
 def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False,
@@ -523,6 +614,23 @@ def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False,
         scale = 1.0 / math.sqrt(d)
     return _flash(q, k, v, int(num_heads), bool(causal), float(scale),
                   int(block_q), int(block_k), bool(interpret))
+
+
+def flash_attention_qkv(qkv, num_heads: int, causal: bool = False,
+                        scale: float | None = None,
+                        block_q: int = 512, block_k: int = 512,
+                        interpret: bool = False):
+    """:func:`flash_attention_rows` of self-attention whose q, k and v are
+    ONE matmul's output, ``qkv`` = ``[B, T, 3 * num_heads * D]`` (q's
+    lanes, then k's, then v's): the kernels index the three inside it, so
+    no q, k or v array is made; returns ``[B, T, num_heads * D]``. The
+    gradient is one array like ``qkv``. Needs :func:`heads_per_block`
+    ``(num_heads, D)`` to be a number."""
+    d = qkv.shape[-1] // (3 * num_heads)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    return _flash_qkv(qkv, int(num_heads), bool(causal), float(scale),
+                      int(block_q), int(block_k), bool(interpret))
 
 
 def flash_attention_fused(q, k, v, causal: bool = False,

@@ -12,22 +12,11 @@ sequence parallelism lives in ``bigdl_tpu.parallel.ring_attention``.
 from __future__ import annotations
 
 import math
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-def _fused_qkv_enabled():
-    """A/B toggle for the fused-QKV single-matmul path, read at trace time
-    (like the other BIGDL_TPU_* knobs). The concat of wq/wk/wv happens
-    inside the jitted step (weights are runtime inputs, XLA cannot
-    constant-fold it): one extra write+read of 3H^2 elements per layer per
-    step vs saving 2*B*T*H activation reads from the three-dot form — a net
-    win whenever B*T >> 3H (all bench shapes), and <1% of step time either
-    way at H<=1024. Set BIGDL_TPU_FUSED_QKV=0 to measure the three-dot arm."""
-    return os.environ.get("BIGDL_TPU_FUSED_QKV", "1") != "0"
 
 from .module import Module
 from .norm import LayerNormalization
@@ -175,23 +164,32 @@ class Attention(Module):
         return x.reshape(b, t, heads or self.num_heads,
                          -1).transpose(0, 2, 1, 3)
 
-    def _project(self, params, qx, kx=None):
-        """The q, k, v projections as the matmuls write them: query
-        ``[B, T, nH * D]``, key and value ``[B, T, kvH * D]``.
-
-        Self-attention projects through ONE (H, H+2*kvD) matmul — one
-        read of the activations and a single well-packed MXU contraction
-        instead of three dots. Params stay separate wq/wk/wv (checkpoint
-        layout unchanged); the concat is a trace-time weight reshuffle."""
+    def _project_fused(self, params, qx, kx=None):
+        """Self-attention's q, k and v as ONE matmul writes them, ``[B, T,
+        (nH + 2 * kvH) * D]``: one read of the activations and one MXU
+        contraction with the concatenated ``[H, H + 2 * kvD]`` weight
+        (params stay separate wq/wk/wv, the checkpoint layout; the
+        concatenation runs in the step and costs ``3 H^2`` elements
+        written and read a layer). None where there is no such matmul:
+        cross-attention, and int8 ``QuantizedWeight`` wrappers
+        (quantization/lm.py), which dequantize per matmul and cannot be
+        concatenated."""
         ws = (params["wq"], params["wk"], params["wv"])
-        if (kx is None or kx is qx) and _fused_qkv_enabled() and all(
+        if (kx is None or kx is qx) and all(
                 isinstance(w, jnp.ndarray) for w in ws):
-            # int8 QuantizedWeight wrappers (quantization/lm.py) keep the
-            # three-dot path: they dequantize per-matmul and can't concat
-            w3 = jnp.concatenate(ws, axis=1)
+            return qx @ jnp.concatenate(ws, axis=1)
+        return None
+
+    def _project(self, params, qx, kx=None):
+        """The q, k, v projections as arrays of their own: query ``[B, T,
+        nH * D]``, key and value ``[B, T, kvH * D]``: the slices of
+        :meth:`_project_fused` (each a copy on the device: the flash
+        branch of :meth:`_apply` hands the product over unsliced), or
+        three matmuls where there is no fused product."""
+        flat = self._project_fused(params, qx, kx)
+        if flat is not None:
             H = self.hidden_size
-            kvd = ws[1].shape[1]
-            flat = qx @ w3
+            kvd = params["wk"].shape[1]
             return flat[..., :H], flat[..., H:H + kvd], flat[..., H + kvd:]
         kx = qx if kx is None else kx
         return qx @ params["wq"], kx @ params["wk"], kx @ params["wv"]
@@ -391,14 +389,22 @@ class Attention(Module):
                  and not (training and self.attention_dropout > 0.0
                           and rng is not None))
         kvh = self._kvh()
-        q, k, v = self._project(params, qx, kx)
         if flash and not self.rope and kvh == self.num_heads:
             # the fused O(T)-memory path on the projections' own layout:
             # Pallas kernel on TPU backends, einsum+mask elsewhere
-            # (parallel/flash dispatcher); no head is split or merged
-            from ..parallel.flash import flash_attention_rows
-            o = flash_attention_rows(q, k, v, self.num_heads, causal=True)
+            # (parallel/flash dispatcher); no head is split or merged, and
+            # self-attention's one projection is not sliced: the kernels
+            # read q, k and v out of it
+            from ..parallel.flash import (flash_attention_qkv,
+                                          flash_attention_rows)
+            qkv = self._project_fused(params, qx, kx)
+            if qkv is not None:
+                o = flash_attention_qkv(qkv, self.num_heads, causal=True)
+            else:
+                o = flash_attention_rows(*self._project(params, qx, kx),
+                                         self.num_heads, causal=True)
             return o @ params["wo"]
+        q, k, v = self._project(params, qx, kx)
         q, k, v = self._split(q), self._split(k, kvh), self._split(v, kvh)
         if self.rope:
             if kx is not qx:

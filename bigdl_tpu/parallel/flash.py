@@ -3,11 +3,13 @@
 The kernels themselves live in ``bigdl_tpu.kernels`` (hand-written
 Pallas; ``flash_attention`` for training/prefill, ``paged_attention``
 for the serving tier's paged decode). This module is only the
-dispatcher. Flash attention has two entries by the layout the caller
-holds: :func:`flash_attention_rows` for ``[B, T, H*D]`` (the training
-step: nothing is copied around the kernels) and :func:`flash_attention`
-for split heads ``[B, H, T, D]`` (KV caches, sequence-parallel blocks).
-For every entry:
+dispatcher. Flash attention has its entries by the layout the caller
+holds: :func:`flash_attention_qkv` for the fused projection ``[B, T,
+3*H*D]`` of self-attention (the training step: no q, k or v array is
+made), :func:`flash_attention_rows` for separate ``[B, T, H*D]`` operands
+(nothing is copied around the kernels) and :func:`flash_attention` for
+split heads ``[B, H, T, D]`` (KV caches, sequence-parallel blocks). For
+every entry:
 
 * on the ``tpu`` platform the compiled kernels run, and a kernel that
   fails to trace, lower or compile RAISES — there is no path from a
@@ -143,15 +145,16 @@ def _kernel_obs(counter: str):
         obs.counter(f"kernels/{counter}").inc()
 
 
-def _over_batch(fused):
+def _over_batch(fused, operands=3):
     """``fused`` under the :func:`data_parallel_context`'s ``shard_map``,
-    if one is set; the batch is the leading dimension in both layouts."""
+    if one is set; the batch is the leading dimension of each of its
+    ``operands`` in every layout."""
     mesh, axis = _DATA_CTX.get()
     if mesh is None:
         return fused
     from jax.sharding import PartitionSpec as P
     from ..utils.compat import shard_map
-    return shard_map(fused, mesh=mesh, in_specs=(P(axis),) * 3,
+    return shard_map(fused, mesh=mesh, in_specs=(P(axis),) * operands,
                      out_specs=P(axis), check_vma=False)
 
 
@@ -197,6 +200,32 @@ def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False):
 
     return _dispatch("flash attention", kernel,
                      lambda: _einsum_attention_rows(q, k, v, num_heads,
+                                                    causal))
+
+
+def flash_attention_qkv(qkv, num_heads: int, causal: bool = False):
+    """:func:`flash_attention_rows` of self-attention whose q, k and v are
+    one matmul's output: qkv (B, T, 3*H*D), q's lanes, then k's, then
+    v's; returns (B, T, H*D). Where the kernels can index the layout they
+    read the three out of ``qkv`` themselves and no q, k or v array is
+    made; the einsum path and head sizes that fill no 128-lane block
+    slice it and go the way of separate operands."""
+    c = qkv.shape[-1] // 3
+    sliced = lambda: tuple(qkv[..., i * c:(i + 1) * c]  # noqa: E731
+                           for i in range(3))
+    from ..kernels.flash_attention import heads_per_block
+    if heads_per_block(num_heads, c // num_heads) is None:
+        return flash_attention_rows(*sliced(), num_heads, causal=causal)
+
+    def kernel(interpret):
+        from ..kernels.flash_attention import flash_attention_qkv as fused
+        _kernel_obs("flash_qkv")
+        return _over_batch(lambda qkv: fused(
+            qkv, num_heads, causal=causal, interpret=interpret,
+            **_flash_blocks()), operands=1)(qkv)
+
+    return _dispatch("flash attention", kernel,
+                     lambda: _einsum_attention_rows(*sliced(), num_heads,
                                                     causal))
 
 

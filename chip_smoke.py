@@ -149,6 +149,7 @@ def phase_kernels(cfg, env):
     import jax.numpy as jnp
     import numpy as np
     from bigdl_tpu.kernels.flash_attention import (flash_attention_fused,
+                                                   flash_attention_qkv,
                                                    flash_attention_rows,
                                                    heads_per_block)
     from bigdl_tpu.kernels.paged_attention import paged_decode_attention
@@ -204,6 +205,19 @@ def phase_kernels(cfg, env):
             argnums=(0, 1, 2)))(qr, kr, vr)
         for name, a, b in zip("qkv", g_rows, g_dense):
             within(f"flash_rows_bwd d{name}", a, rows(b))
+        # self-attention's entry: the same kernels read q, k and v out of
+        # ONE array [B, T, 3*H*D], so nothing differs, to the last bit
+        fused = lambda x: flash_attention_qkv(  # noqa: E731
+            x, H, causal=True, interpret=env.interpret)
+        qkv = jnp.concatenate([qr, kr, vr], axis=-1)
+        g_qkv = jax.jit(jax.grad(lambda x: (fused(x) * wr).sum()))(qkv)
+        for name, a, b in (
+                ("flash_qkv_fwd", jax.jit(fused)(qkv),
+                 jax.jit(flash_r)(qr, kr, vr)),
+                ("flash_qkv_bwd", g_qkv, jnp.concatenate(g_rows, axis=-1))):
+            out[f"{name} == flash_rows"] = bool((a == b).all())
+            check(out[f"{name} == flash_rows"],
+                  f"{name}: differs from the rows entry on the slices")
 
     # paged decode attention at the server's geometry: S=1 over a full
     # slot bucket, and one prefill chunk; tables are a random permutation

@@ -126,11 +126,50 @@ def test_flash_rows_match_einsum(monkeypatch, heads, d, t, rows):
     assert ("transpose[" not in forward) == rows, forward
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t", [256, 200])
+@pytest.mark.parametrize("heads,d", [(16, 64), (4, 64), (2, 128)])
+def test_flash_qkv_is_rows_on_the_slices(monkeypatch, heads, d, t, causal):
+    """The fused-projection entry (``parallel.flash.flash_attention_qkv``
+    on ``[B, T, 3*H*D]``) against ``flash_attention_rows`` on the three
+    slices of the same array: the same kernels on the same blocks, so the
+    output and the gradient w.r.t. the fused array agree to the last bit;
+    and the entry slices nothing."""
+    from bigdl_tpu.parallel import flash
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    monkeypatch.setenv("BIGDL_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("BIGDL_TPU_FLASH_BLOCK_K", "128")
+    c = heads * d
+    qkv = jnp.asarray(np.random.RandomState(7).randn(1, t, 3 * c)
+                      .astype(np.float32))
+
+    def fused(x):
+        o = flash.flash_attention_qkv(x, heads, causal=causal)
+        return jnp.sum(jnp.sin(o)), o
+
+    def sliced(x):
+        o = flash.flash_attention_rows(x[..., :c], x[..., c:2 * c],
+                                       x[..., 2 * c:], heads, causal=causal)
+        return jnp.sum(jnp.sin(o)), o
+
+    (_, o), g = jax.value_and_grad(fused, has_aux=True)(qkv)
+    (_, o_ref), g_ref = jax.value_and_grad(sliced, has_aux=True)(qkv)
+    assert o.shape == (1, t, c) and g.shape == qkv.shape
+    assert np.array_equal(np.asarray(o), np.asarray(o_ref))
+    assert np.array_equal(np.asarray(g), np.asarray(g_ref))
+    assert float(jnp.abs(g[..., 2 * c:]).max()) > 0       # dv is there
+    forward = str(jax.make_jaxpr(lambda x: fused(x)[1])(qkv))
+    assert "name=flash_fwd" in forward
+    assert " slice[" not in forward.split("pallas_call")[0], forward
+
+
 def test_flash_entry_counters(monkeypatch):
-    """``kernels/flash_rows`` and ``kernels/flash_heads`` count the flash
-    calls BUILT on each entry: one bump a traced call, none for a cached
-    program's next run, none on the einsum path, none while the
-    observability is off."""
+    """``kernels/flash_qkv``, ``kernels/flash_rows`` and
+    ``kernels/flash_heads`` count the flash calls BUILT on each entry: one
+    bump a traced call, none for a cached program's next run, none on the
+    einsum path, none while the observability is off. A self-attention
+    ``Attention`` builds on the fused-projection entry alone; cross-
+    attention, RoPE and grouped K/V modules never do."""
     from bigdl_tpu import observability as obs
     from bigdl_tpu.parallel import flash
     monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
@@ -155,10 +194,34 @@ def test_flash_entry_counters(monkeypatch):
         flash.flash_attention_rows(odd, odd, odd, 3, causal=True)
         assert (count("flash_rows"), count("flash_heads")) == (r0 + 1,
                                                                h0 + 2)
+        # the modules: which entry an ``Attention`` builds its call on
+        from bigdl_tpu import nn
+        from bigdl_tpu.utils.table import Table
+        x = jnp.ones((1, 128, 128), jnp.float32)
+        built = lambda: tuple(count(n) for n in (  # noqa: E731
+            "flash_qkv", "flash_rows", "flash_heads"))
+
+        def run(attn, inp):
+            params, _ = attn.init(jax.random.PRNGKey(0))
+            attn.apply(params, {}, inp, training=False)
+
+        z0, r1, h1 = built()
+        run(nn.Attention(128, 2, causal=True), x)
+        assert built() == (z0 + 1, r1, h1)
+        run(nn.Attention(128, 2, causal=True), Table(x, x + 1.0))  # cross
+        assert built() == (z0 + 1, r1 + 1, h1)
+        run(nn.Attention(128, 2, causal=True, rope=True), x)
+        assert built() == (z0 + 1, r1 + 1, h1 + 1)
+        run(nn.Attention(128, 2, causal=True, num_kv_heads=1), x)
+        assert built() == (z0 + 1, r1 + 1, h1 + 2)
+        # the fused entry where no block of whole heads fills 128 lanes
+        # (3 heads of 64): sliced, and through the (B, H, T, D) entry
+        run(nn.Attention(192, 3, causal=True), odd)
+        assert built() == (z0 + 1, r1 + 1, h1 + 3)
         monkeypatch.setenv("BIGDL_TPU_FLASH", "off")
         flash.flash_attention_rows(rows, rows, rows, 2, causal=True)
-        assert (count("flash_rows"), count("flash_heads")) == (r0 + 1,
-                                                               h0 + 2)
+        flash.flash_attention_qkv(jnp.ones((1, 128, 384)), 2, causal=True)
+        assert built() == (z0 + 1, r1 + 1, h1 + 3)
     finally:
         obs.disable()
 

@@ -150,6 +150,51 @@ def test_remat_keeps_what_the_flash_kernel_made(monkeypatch, family,
     assert len(re.findall(r"= remat\w*\[", texts[1])) == layers
 
 
+@pytest.mark.parametrize("heads,hidden", [(2, 128), (4, 256), (1, 128)])
+def test_attention_fused_projection_matches_einsum(monkeypatch, heads,
+                                                   hidden):
+    """A flash self-attention layer hands its ONE q/k/v matmul to the
+    kernels unsliced (``parallel.flash.flash_attention_qkv``). Under the
+    Pallas interpreter it gives the einsum path's output and the same
+    gradients for wq, wk, wv, wo and the input; and under ``jax.checkpoint``
+    with ``remat_block``'s policy the forward kernel is still built once a
+    layer: ``o`` and ``lse`` are kept, the fused projection is recomputed."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.nn.attention import remat_block
+    monkeypatch.setenv("BIGDL_TPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("BIGDL_TPU_FLASH_BLOCK_K", "128")
+    attn = nn.Attention(hidden, heads, causal=True)
+    params, _ = attn.init(jax.random.PRNGKey(0))
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 200, hidden)
+                    .astype(np.float32))
+
+    def loss(run):
+        def f(params, x):
+            o = run(params, x)
+            return jnp.sum(jnp.sin(o)), o
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+
+    layer = lambda p, x: attn.apply(p, {}, x, training=True)[0]  # noqa: E731
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "off")
+    (_, o_ref), (gp_ref, gx_ref) = loss(layer)(params, x)
+    monkeypatch.setenv("BIGDL_TPU_FLASH", "interpret")
+    for run in (layer, remat_block(layer)):
+        (_, o), (gp, gx) = loss(run)(params, x)
+        assert np.allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5), \
+            np.abs(np.asarray(o) - np.asarray(o_ref)).max()
+        for name, a, b in [("x", gx, gx_ref)] + [
+                (k, gp[k], gp_ref[k]) for k in ("wq", "wk", "wv", "wo")]:
+            err = np.abs(np.asarray(a) - np.asarray(b)).max()
+            assert err < 5e-4, f"d{name} err {err}"
+        text = str(jax.make_jaxpr(loss(run))(params, x))
+        assert _kernel_calls(text) == {"flash_fwd": 1, "flash_bwd_dkv": 1,
+                                       "flash_bwd_dq": 1}
+        # q, k and v are read where the one matmul wrote them
+        assert f"f32[2,200,{3 * hidden}]" in text
+        assert f"f32[2,256,{3 * hidden}]" in text       # padded once
+    assert len(re.findall(r"= remat\w*\[", text)) == 1
+
+
 def test_lm_loss_chunked_matches_full_logits():
     """lm_loss_chunked == full-logits softmax-CE with RAW (0-based) token
     ids, values AND gradients (through a scan-of-checkpoint body). The
