@@ -35,7 +35,7 @@ from .pool import (SpatialMaxPooling, SpatialAveragePooling,
                    TemporalMaxPooling, VolumetricMaxPooling,
                    VolumetricAveragePooling, RoiPooling)
 from .norm import (BatchNormalization, SpatialBatchNormalization,
-                   VolumetricBatchNormalization, LayerNormalization,
+                   VolumetricBatchNormalization, LayerNormalization, RMSNorm,
                    SpatialCrossMapLRN, SpatialWithinChannelLRN, Normalize,
                    NormalizeScale, SpatialSubtractiveNormalization,
                    SpatialDivisiveNormalization,
@@ -50,7 +50,7 @@ from .shape_ops import (Reshape, View, InferReshape, Squeeze, Unsqueeze,
                         ResizeBilinear)
 from .sparse import (SparseTensor, SparseLinear, LookupTableSparse,
                      SparseJoinTable, DenseToSparse, sparse_dense_matmul)
-from .moe import MixtureOfExperts
+from .moe import MixtureOfExperts, RoutedExperts
 from .table_ops import (CAddTable, CSubTable, CMulTable, CDivTable, CMaxTable,
                         CMinTable, CAveTable, JoinTable, SplitTable,
                         BifurcateSplitTable, SelectTable, NarrowTable,
@@ -66,8 +66,8 @@ from .detection import (Anchor, Nms, PriorBox, Proposal, DetectionOutputSSD,
                         DetectionOutputFrcnn, RoiAlign, bbox_transform_inv,
                         bbox_iou_matrix, bbox_areas, clip_boxes, decode_boxes,
                         nms_mask, generate_basic_anchors, bbox_vote)
-from .attention import (Attention, FeedForwardNetwork, Transformer,
-                        TransformerBlock, dot_product_attention,
+from .attention import (Attention, FeedForwardNetwork, LatentAttention,
+                        Transformer, TransformerBlock, dot_product_attention,
                         flash_attention, position_encoding, causal_mask,
                         padding_mask, rotary_embedding)
 from .speculative import speculative_generate, SpecStats

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .module import Module
-from .norm import LayerNormalization
+from .norm import LayerNormalization, RMSNorm
 from ..kernels.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
 from ..utils.table import Table
 
@@ -28,6 +28,35 @@ def _glorot(rng, shape):
     fan_in, fan_out = shape[0], shape[-1]
     s = math.sqrt(6.0 / (fan_in + fan_out))
     return jax.random.uniform(rng, shape, minval=-s, maxval=s)
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """The ``dim // 2`` inverse frequencies (float64) of YaRN-scaled RoPE
+    (Peng et al. 2023, as ``deepseek_v3`` configs use it): a linear
+    ramp blends the interpolated frequency ``1 / (factor * base^(2i/dim))``
+    into the extrapolated ``1 / base^(2i/dim)`` between the dimensions that
+    turn ``beta_fast`` and ``beta_slow`` times over the original length.
+    The attention scale's ``mscale`` is the caller's."""
+    half = dim // 2
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / ((high - low) or 0.001), 0.0, 1.0)
+    return (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def rotary_embedding(x, positions, base: float = 10000.0):
@@ -444,6 +473,133 @@ class Attention(Module):
         return self._merge(o, params)
 
 
+class LatentAttention(Module):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA) without a query
+    latent, causal self-attention only:
+
+        q_h           = x Wq                      (nope + rope dims a head)
+        [c ; k_pe]    = x Wkva                    (kv_lora_rank + rope)
+        [k_nope_h ; v_h] = RMSNorm(c) Wkvb        (nope + v dims a head)
+        k_h           = [k_nope_h ; RoPE(k_pe)]   (one k_pe for all heads)
+        o_h           = softmax(scale * RoPE(q_h) k_h^T + causal) v_h
+        y             = (o * sigmoid(x Wg)) Wo    (``gated``; else o Wo)
+
+    RoPE turns the last ``qk_rope_head_dim`` of a head only, with
+    :func:`rotary_embedding`'s pairing; ``rope_scaling`` (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``) gives YaRN frequencies and multiplies
+    the softmax scale by ``mscale(all_dim)^2``. After the up-projection q, k
+    and v are ``[B, T, heads * d]`` arrays, so where q/k and v heads are
+    equally wide and fill 128-lane blocks the flash kernels of the dense
+    decoder run them as they are, with the scale passed in. What decode
+    would cache is ``c`` and ``k_pe``; no cached path exists yet."""
+
+    def __init__(self, hidden_size: int, num_heads: int, kv_lora_rank: int,
+                 qk_nope_head_dim: int, qk_rope_head_dim: int,
+                 v_head_dim: int, rope_theta: float = 10000.0,
+                 rope_scaling: Optional[dict] = None, norm_eps: float = 1e-6,
+                 gated: bool = False, use_flash: bool = True, name=None):
+        super().__init__(name=name)
+        if qk_rope_head_dim % 2:
+            raise ValueError("RoPE needs an even qk_rope_head_dim")
+        self.hidden_size, self.num_heads = hidden_size, num_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rope_theta, self.rope_scaling = rope_theta, rope_scaling
+        self.gated, self.use_flash = gated, use_flash
+        self.kv_norm = RMSNorm(kv_lora_rank, norm_eps)
+        qk = qk_nope_head_dim + qk_rope_head_dim
+        self.scale = qk ** -0.5
+        self.inv_freq = None
+        if rope_scaling:
+            rs = rope_scaling
+            self.inv_freq = yarn_inv_freq(
+                qk_rope_head_dim, rope_theta, rs["factor"],
+                rs["original_max_position_embeddings"],
+                rs.get("beta_fast", 32.0), rs.get("beta_slow", 1.0))
+            m = yarn_mscale(rs["factor"], rs.get("mscale_all_dim", 0.0))
+            self.scale *= m * m
+            # cos and sin carry mscale / mscale_all_dim; only 1 is built
+            if rs.get("mscale", 1.0) != rs.get("mscale_all_dim", 0.0):
+                raise NotImplementedError(
+                    "YaRN with mscale != mscale_all_dim scales cos and sin")
+
+    def _init_params(self, rng):
+        k = jax.random.split(rng, 6)
+        H, nh, r = self.hidden_size, self.num_heads, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        p = {"wq": _glorot(k[0], (H, nh * (dn + dr))),
+             "wkva": _glorot(k[1], (H, r + dr)),
+             "kv_norm": self.kv_norm._init_params(k[2]),
+             "wkvb": _glorot(k[3], (r, nh * (dn + dv))),
+             "wo": _glorot(k[4], (nh * dv, H))}
+        if self.gated:
+            p["wg"] = _glorot(k[5], (H, nh * dv))
+        return p
+
+    def _rope(self, x):
+        """RoPE over ``[B, T, heads, rope]`` at positions 0..T-1, with
+        :func:`rotary_embedding`'s pairing (dim i with dim i + rope / 2).
+        The positions are static, so cos and sin are tables made in
+        float64 at trace time: at position 4095 a float32 angle is already
+        2e-4 rad off."""
+        T, half = x.shape[1], x.shape[-1] // 2
+        inv = (self.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+               if self.inv_freq is None else self.inv_freq)
+        ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
+        cos = jnp.asarray(np.cos(ang), x.dtype)[None, :, None, :]
+        sin = jnp.asarray(np.sin(ang), x.dtype)[None, :, None, :]
+        x1, x2 = x[..., :half], x[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+    def qkv(self, params, x):
+        """q, k ``[B, T, heads * (nope + rope)]`` (rotated) and v ``[B, T,
+        heads * v]``: what attention reads, nothing transposed. The
+        up-projection's weight is cut by use, not its output: k_nope and v
+        are two products over the one normed latent."""
+        B, T, _ = x.shape
+        nh, r = self.num_heads, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        q = (x @ params["wq"]).reshape(B, T, nh, dn + dr)
+        kva = x @ params["wkva"]
+        c, _ = self.kv_norm.apply(params["kv_norm"], {}, kva[..., :r])
+        wkvb = params["wkvb"].reshape(r, nh, dn + dv)
+        k_nope = (c @ wkvb[..., :dn].reshape(r, nh * dn)).reshape(B, T, nh, dn)
+        v = c @ wkvb[..., dn:].reshape(r, nh * dv)
+        k_pe = self._rope(kva[..., r:][:, :, None, :])
+        q = jnp.concatenate([q[..., :dn], self._rope(q[..., dn:])], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (B, T, nh, dr))], -1)
+        return (q.reshape(B, T, nh * (dn + dr)),
+                k.reshape(B, T, nh * (dn + dr)), v)
+
+    def _apply(self, params, state, x, training, rng):
+        if isinstance(x, Table):
+            if len(x) >= 3 and x[3] is not None:
+                raise ValueError("latent attention is causal self-attention: "
+                                 "it takes no mask")
+            x = x[1]
+        q, k, v = self.qkv(params, x)
+        nh = self.num_heads
+        if self.use_flash and q.shape[-1] == v.shape[-1]:
+            from ..parallel.flash import flash_attention_rows
+            o = flash_attention_rows(q, k, v, nh, causal=True,
+                                     scale=self.scale)
+        else:
+            B, T, _ = v.shape
+            q, k, v = (a.reshape(B, T, nh, -1) for a in (q, k, v))
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * self.scale
+            w = jax.nn.softmax(logits + causal_mask(T, logits.dtype), axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(B, T, -1)
+        if self.gated:
+            o = o * jax.nn.sigmoid(x @ params["wg"])
+        return o @ params["wo"]
+
+
 class FeedForwardNetwork(Module):
     """Position-wise FFN (nn/FeedForwardNetwork.scala).
 
@@ -453,10 +609,13 @@ class FeedForwardNetwork(Module):
     usually shrink filter_size by 2/3)."""
 
     activation = "relu"   # class default: pre-r4 pickles lack the attr
+    bias = True           # likewise
 
     def __init__(self, hidden_size: int, filter_size: int,
                  relu_dropout: float = 0.0, activation: str = "relu",
-                 name=None):
+                 bias: bool = True, name=None):
+        """``bias=False``: ``w1``, ``w2`` (and ``w3``) alone, as the gated
+        FFNs of today's decoders are published."""
         super().__init__(name=name)
         self.hidden_size, self.filter_size = hidden_size, filter_size
         self.relu_dropout = relu_dropout
@@ -464,30 +623,35 @@ class FeedForwardNetwork(Module):
             raise ValueError(f"activation must be relu/gelu/swiglu, "
                              f"got {activation!r}")
         self.activation = activation
+        self.bias = bias
 
     def _init_params(self, rng):
         k1, k2, k3 = jax.random.split(rng, 3)
         p = {"w1": _glorot(k1, (self.hidden_size, self.filter_size)),
-             "b1": jnp.zeros((self.filter_size,)),
-             "w2": _glorot(k2, (self.filter_size, self.hidden_size)),
-             "b2": jnp.zeros((self.hidden_size,))}
+             "w2": _glorot(k2, (self.filter_size, self.hidden_size))}
+        if self.bias:
+            p["b1"] = jnp.zeros((self.filter_size,))
+            p["b2"] = jnp.zeros((self.hidden_size,))
         if self.activation == "swiglu":
             p["w3"] = _glorot(k3, (self.hidden_size, self.filter_size))
         return p
 
     def _apply(self, params, state, x, training, rng):
         act = self.activation
+        pre = x @ params["w1"]
+        if self.bias:
+            pre = pre + params["b1"]
         if act == "swiglu":
-            h = jax.nn.silu(x @ params["w1"] + params["b1"]) \
-                * (x @ params["w3"])
+            h = jax.nn.silu(pre) * (x @ params["w3"])
         elif act == "gelu":
-            h = jax.nn.gelu(x @ params["w1"] + params["b1"])
+            h = jax.nn.gelu(pre)
         else:
-            h = jax.nn.relu(x @ params["w1"] + params["b1"])
+            h = jax.nn.relu(pre)
         if training and self.relu_dropout > 0 and rng is not None:
             keep = jax.random.bernoulli(rng, 1 - self.relu_dropout, h.shape)
             h = jnp.where(keep, h / (1 - self.relu_dropout), 0.0)
-        return h @ params["w2"] + params["b2"]
+        out = h @ params["w2"]
+        return out + params["b2"] if self.bias else out
 
 
 def position_encoding(length, hidden_size, dtype=jnp.float32):
@@ -514,27 +678,43 @@ def embed_ids(embed, ids, hidden_size, with_pe: bool = True):
     return h + position_encoding(ids.shape[1], hidden_size, h.dtype)
 
 
+def _make_norm(kind: str, hidden_size: int, eps: float):
+    """The block norm of this name: ``layer`` (LayerNorm) or ``rms``."""
+    if kind == "rms":
+        return RMSNorm(hidden_size, eps)
+    if kind != "layer":
+        raise ValueError(f"norm must be 'layer' or 'rms', got {kind!r}")
+    return LayerNormalization(hidden_size, eps)
+
+
 class TransformerBlock(Module):
-    """Pre-LN transformer block: self-attn (+ optional cross-attn) + FFN."""
+    """Pre-norm transformer block: self-attn (+ optional cross-attn) + FFN.
+    ``norm`` picks LayerNorm or RMSNorm; ``attn`` and ``ffn`` take the
+    place of the attention and FFN built from the sizes (latent attention,
+    a routed-expert layer: any module over ``[B, T, H]``). An FFN that
+    returns state (an expert layer's counters) makes the block return
+    ``(h, state)``."""
 
     def __init__(self, hidden_size: int, num_heads: int, filter_size: int,
                  attn_dropout: float = 0.0, ffn_dropout: float = 0.0,
                  with_cross: bool = False, causal: bool = False,
                  use_flash: bool = True, num_kv_heads=None,
                  rope: bool = False, ffn_activation: str = "relu",
-                 name=None):
+                 norm: str = "layer", norm_eps: float = 1e-6,
+                 attn: Optional[Module] = None,
+                 ffn: Optional[Module] = None, name=None):
         super().__init__(name=name)
-        self.attn = Attention(hidden_size, num_heads, attn_dropout,
-                              use_flash=use_flash, causal=causal,
-                              num_kv_heads=num_kv_heads, rope=rope)
-        self.ffn = FeedForwardNetwork(hidden_size, filter_size, ffn_dropout,
-                                      activation=ffn_activation)
-        self.ln1 = LayerNormalization(hidden_size)
-        self.ln2 = LayerNormalization(hidden_size)
+        self.attn = attn or Attention(
+            hidden_size, num_heads, attn_dropout, use_flash=use_flash,
+            causal=causal, num_kv_heads=num_kv_heads, rope=rope)
+        self.ffn = ffn or FeedForwardNetwork(
+            hidden_size, filter_size, ffn_dropout, activation=ffn_activation)
+        self.ln1 = _make_norm(norm, hidden_size, norm_eps)
+        self.ln2 = _make_norm(norm, hidden_size, norm_eps)
         self.with_cross = with_cross
         if with_cross:
             self.cross = Attention(hidden_size, num_heads, attn_dropout)
-            self.ln3 = LayerNormalization(hidden_size)
+            self.ln3 = _make_norm(norm, hidden_size, norm_eps)
 
     def _init_params(self, rng):
         k = jax.random.split(rng, 6)
@@ -575,9 +755,9 @@ class TransformerBlock(Module):
             h = h + c
         n, _ = self.ln2.apply(params["ln2"], {}, h, training, None,
                               scope="ln2")
-        f, _ = self.ffn.apply(params["ffn"], {}, n, training, r2,
-                              scope="ffn")
-        return h + f
+        f, ffn_state = self.ffn.apply(params["ffn"], {}, n, training, r2,
+                                      scope="ffn")
+        return (h + f, ffn_state) if ffn_state else h + f
 
     def _ffn_sublayer(self, params, h):
         n, _ = self.ln2.apply(params["ln2"], {}, h, False, None)
@@ -669,6 +849,11 @@ class Transformer(Module):
     LM over token ids) or ``mode='translation'`` (encoder-decoder; input
     Table(src_ids, tgt_ids)). Returns logits over vocab."""
 
+    # class defaults: pickles from before these options lack the attrs
+    embed_scale = True
+    tied_head = True
+    mtp = None
+
     def __init__(self, vocab_size: int, hidden_size: int = 256,
                  num_heads: int = 4, filter_size: int = 1024,
                  num_hidden_layers: int = 2, postprocess_dropout: float = 0.0,
@@ -676,8 +861,26 @@ class Transformer(Module):
                  mode: str = "lm", max_len: int = 2048,
                  use_flash: bool = True, remat: bool = False,
                  num_kv_heads=None, pos_encoding: str = "sinusoidal",
-                 ffn_activation: str = "relu", name=None):
-        """``use_flash``: LM-mode self-attention goes through the fused
+                 ffn_activation: str = "relu", norm: str = "layer",
+                 norm_eps: float = 1e-6, embed_scale: bool = True,
+                 tied_head: bool = True, make_attention=None, make_ffn=None,
+                 mtp: bool = False, name=None):
+        """``norm``/``norm_eps``: the blocks' and the final norm
+        (``layer`` or ``rms``). ``pos_encoding="none"`` adds no positions
+        (an attention that rotates its own q/k, as ``make_attention``'s may)
+        and ``embed_scale=False`` leaves the embeddings unscaled.
+        ``tied_head=False`` gives the output projection its own ``head``
+        ``[H, vocab]``. ``make_attention()`` / ``make_ffn(i)`` return the
+        attention and the FFN module of LM block ``i`` in place of the
+        dense ones (``make_ffn`` may return None for a dense layer: the FFN
+        kind by layer). ``mtp``: one multi-token-prediction module
+        (DeepSeek-V3): ``W_eh [norm(Emb(t_{i+1})) ; norm(h_i)]`` through one
+        more block (built as the LAST layer's kind) and a final norm of its
+        own, embedding and head shared; in training the output is then
+        ``Table(logits, mtp_logits)`` with ``mtp_logits[:, i]`` predicting
+        the SAME target as ``logits[:, i]`` from one position further back
+        (``mtp_logits[:, 0]`` has no prediction: mask its target).
+        ``use_flash``: LM-mode self-attention goes through the fused
         O(T)-memory flash path (Pallas on TPU) instead of materialising the
         (B,H,T,T) score matrix. ``remat``: each block runs under
         :func:`remat_block`, so the backward pass recomputes block internals
@@ -694,28 +897,43 @@ class Transformer(Module):
         # LM mode: causal masking is a block property (flash-friendly);
         # translation mode keeps additive masks (padding masks cannot be
         # expressed as the flash kernel's static causal pattern)
-        if pos_encoding not in ("sinusoidal", "rope"):
-            raise ValueError(f"pos_encoding must be 'sinusoidal' or "
-                             f"'rope', got {pos_encoding!r}")
+        if pos_encoding not in ("sinusoidal", "rope", "none"):
+            raise ValueError(f"pos_encoding must be 'sinusoidal', 'rope' or "
+                             f"'none', got {pos_encoding!r}")
         if pos_encoding == "rope" and mode != "lm":
             raise ValueError("RoPE is LM-mode only (cross-attention has "
                              "no rotary form here)")
+        if mode != "lm" and (mtp or make_attention or make_ffn
+                             or not tied_head):
+            raise ValueError("an untied head, block modules of the caller's "
+                             "and the MTP module are LM-mode options")
         self.pos_encoding = pos_encoding
-        self.blocks = [TransformerBlock(hidden_size, num_heads, filter_size,
-                                        attention_dropout, relu_dropout,
-                                        with_cross=(mode == "translation"),
-                                        causal=(mode == "lm"),
-                                        use_flash=use_flash,
-                                        num_kv_heads=num_kv_heads,
-                                        rope=(pos_encoding == "rope"),
-                                        ffn_activation=ffn_activation)
-                       for _ in range(num_hidden_layers)]
+        self.embed_scale, self.tied_head = embed_scale, tied_head
+
+        def block(i):
+            return TransformerBlock(
+                hidden_size, num_heads, filter_size, attention_dropout,
+                relu_dropout, with_cross=(mode == "translation"),
+                causal=(mode == "lm"), use_flash=use_flash,
+                num_kv_heads=num_kv_heads, rope=(pos_encoding == "rope"),
+                ffn_activation=ffn_activation, norm=norm, norm_eps=norm_eps,
+                attn=make_attention() if make_attention else None,
+                ffn=make_ffn(i) if make_ffn else None)
+
+        self.blocks = [block(i) for i in range(num_hidden_layers)]
         if mode == "translation":
             self.enc_blocks = [TransformerBlock(hidden_size, num_heads,
                                                 filter_size, attention_dropout,
-                                                relu_dropout)
+                                                relu_dropout, norm=norm,
+                                                norm_eps=norm_eps)
                                for _ in range(num_hidden_layers)]
-        self.ln_f = LayerNormalization(hidden_size)
+        self.ln_f = _make_norm(norm, hidden_size, norm_eps)
+        self.mtp = None
+        if mtp:
+            self.mtp = {"enorm": _make_norm(norm, hidden_size, norm_eps),
+                        "hnorm": _make_norm(norm, hidden_size, norm_eps),
+                        "block": block(num_hidden_layers - 1),
+                        "ln_f": _make_norm(norm, hidden_size, norm_eps)}
 
     def _init_params(self, rng):
         k = jax.random.split(rng, 4 + len(self.blocks) * 2)
@@ -728,16 +946,39 @@ class Transformer(Module):
             for i, blk in enumerate(self.enc_blocks):
                 p[f"enc_block{i}"] = blk._init_params(
                     k[2 + len(self.blocks) + i])
+        if not self.tied_head:
+            p["head"] = _glorot(k[-2], (self.hidden_size, self.vocab_size))
+        if self.mtp:
+            km = jax.random.split(k[-1], 5)
+            p["mtp"] = {n: m._init_params(km[j])
+                        for j, (n, m) in enumerate(self.mtp.items())}
+            p["mtp"]["eh_proj"] = _glorot(
+                km[4], (2 * self.hidden_size, self.hidden_size))
         return p
+
+    def _init_state(self):
+        """The expert layers' counters, where a block has any (zeros until
+        the first forward), so that the state a step returns has the
+        structure of the state it was given."""
+        from .moe import merge_counters
+        blocks = self.blocks + ([self.mtp["block"]] if self.mtp else [])
+        return merge_counters([b.ffn._init_state() for b in blocks])
 
     @jax.named_scope("embed")
     def _embed(self, params, ids):
+        if not self.embed_scale:
+            if self.pos_encoding != "none":
+                raise ValueError("unscaled embeddings take no positions")
+            return jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
         return embed_ids(params["embed"], ids, self.hidden_size,
                          with_pe=getattr(self, "pos_encoding",
-                                         "sinusoidal") != "rope")
+                                         "sinusoidal") == "sinusoidal")
 
     def _stack(self, blocks, prefix, params, h, mask, training, rng,
-               enc=None, enc_mask=None):
+               enc=None, enc_mask=None, states=None):
+        """``h`` through the blocks; a block that returns ``(h, state)``
+        (its FFN counts its routing) has the state appended to
+        ``states``."""
         for i, blk in enumerate(blocks):
             r = jax.random.fold_in(rng, i) if rng is not None else None
             # the block goes through `_apply`, so its scope (the parameter
@@ -751,19 +992,47 @@ class Transformer(Module):
             if self.remat:
                 run = remat_block(run)
             h = run(params[f"{prefix}{i}"], h)
+            if isinstance(h, tuple):
+                h, state = h
+                states.append(state)
         return h
 
-    def hidden_states(self, params, x, training=False, rng=None):
+    def hidden_states(self, params, x, training=False, rng=None,
+                      states=None, trunk=None):
         """Final-LayerNorm hidden states (B, T, H) — the LM trunk without
         the vocab projection, so callers can fuse projection+loss in
         chunks (see models.transformer_lm.lm_loss_chunked) instead of
-        materialising (B, T, vocab) logits."""
+        materialising (B, T, vocab) logits. ``states`` collects the
+        blocks' states, ``trunk`` the last block's output before the final
+        norm (what the MTP module reads)."""
         assert self.mode == "lm", "hidden_states is the LM-mode trunk"
         h = self._embed(params, x)
-        h = self._stack(self.blocks, "block", params, h, None, training, rng)
+        h = self._stack(self.blocks, "block", params, h, None, training, rng,
+                        states=[] if states is None else states)
+        if trunk is not None:
+            trunk.append(h)
         h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None,
                                scope="ln_f")
         return h
+
+    @jax.named_scope("mtp")
+    def _mtp_hidden(self, params, ids, h, training, rng, states):
+        """The MTP module's normed hidden states, laid one position to the
+        right: row ``i`` is made from ``h[i-1]`` and ``Emb(ids[i])`` and
+        predicts what the main row ``i`` predicts, ``ids[i+1]``. Row 0
+        (rolled round from the last position, whose next token is not in
+        ``ids``) predicts nothing. Causal attention and per-token experts
+        keep that last position from reaching any other."""
+        p, m = params["mtp"], self.mtp
+        nxt = jnp.roll(ids.astype(jnp.int32), -1, axis=1)
+        e, _ = m["enorm"].apply(p["enorm"], {}, self._embed(params, nxt),
+                                scope="enorm")
+        n, _ = m["hnorm"].apply(p["hnorm"], {}, h, scope="hnorm")
+        x = jnp.concatenate([e, n], axis=-1) @ p["eh_proj"]
+        x = self._stack([m["block"]], "block", {"block0": p["block"]}, x,
+                        None, training, rng, states=states)
+        x, _ = m["ln_f"].apply(p["ln_f"], {}, x, scope="ln_f")
+        return jnp.roll(x, 1, axis=1)
 
     def _apply(self, params, state, x, training, rng):
         if self.mode == "translation":
@@ -780,11 +1049,23 @@ class Transformer(Module):
                                    scope="ln_f")
             return self._head(params, h)
         # LM mode: causal masking lives inside the blocks (flash path)
-        return self._head(params, self.hidden_states(params, x, training,
-                                                     rng))
+        states, trunk = [], []
+        out = self._head(params, self.hidden_states(
+            params, x, training, rng, states, trunk))
+        if self.mtp and training:
+            r = jax.random.fold_in(rng, len(self.blocks)) \
+                if rng is not None else None
+            out = Table(out, self._head(params, self._mtp_hidden(
+                params, x, trunk[0], training, r, states)))
+        if states:
+            from .moe import merge_counters
+            return out, merge_counters(states)
+        return out
 
     @jax.named_scope("head")
     def _head(self, params, h):
+        if not self.tied_head:
+            return h @ params["head"]
         return h @ params["embed"].T  # tied output projection
 
     # ---- autoregressive inference (KV cache; TPU-first, the reference's

@@ -1,12 +1,18 @@
-"""Mixture-of-Experts layer (single-program form).
+"""Mixture-of-Experts layers (single-program form).
 
 TPU-first addition beyond the reference (BigDL 0.x has no MoE; its
-closest relative is the gating ``nn/MixtureTable.scala``, which this
-generalizes with learned top-1 routing and capacity).
+closest relative is the gating ``nn/MixtureTable.scala``, which these
+generalize with learned routing).
 
-The SPMD expert-parallel counterpart is :func:`bigdl_tpu.parallel.moe.moe_ffn`
-(same dispatch/combine math over a device mesh). This module form drops into
-any Sequential/Graph like an ordinary FFN.
+:class:`MixtureOfExperts` is the Switch-style layer: top-1 routing with a
+capacity that DROPS, through one-hot dispatch/combine tensors; its SPMD
+expert-parallel counterpart is :func:`bigdl_tpu.parallel.moe.moe_ffn` (same
+dispatch/combine math over a device mesh). :class:`RoutedExperts` is the
+layer of today's expert decoders: top-k of sigmoid scores with a
+selection-only balancing bias, shared experts, a sorted dispatch that drops
+nothing, and ``held``: the experts one chip of an expert-parallel
+deployment holds, routed over all of them (on one chip without the
+exchange). Both drop into a block like an ordinary FFN.
 """
 from __future__ import annotations
 
@@ -18,6 +24,11 @@ import numpy as np
 
 from .module import Module
 from ..parallel.moe import expert_capacity, top1_routing
+
+ROWS_LOCAL = "moe/rows_local"
+LOAD_MAX_OVER_MEAN = "moe/load_max_over_mean"
+
+
 class MixtureOfExperts(Module):
     """Switch-style MoE FFN as an ordinary layer (single-program form).
 
@@ -65,3 +76,161 @@ class MixtureOfExperts(Module):
         new_state = dict(state)
         new_state["aux_loss"] = aux
         return y.reshape(shape), new_state
+
+
+def merge_counters(states):
+    """One model state from the expert layers' states: ``{"counters":
+    {moe/rows_local: mean over the layers, moe/load_max_over_mean: the
+    worst layer's}}``; ``{}`` where no layer counts anything."""
+    counted = [s["counters"] for s in states if s and "counters" in s]
+    if not counted:
+        return {}
+    return {"counters": {
+        ROWS_LOCAL: sum(c[ROWS_LOCAL] for c in counted) / len(counted),
+        LOAD_MAX_OVER_MEAN: jnp.max(jnp.stack(
+            [c[LOAD_MAX_OVER_MEAN] for c in counted]))}}
+
+
+class RoutedExperts(Module):
+    """Top-k routed experts with shared experts, as DeepSeek-V3's
+    ``deepseek_v3`` configs describe the layer, told which experts it
+    holds:
+
+        s   = sigmoid(x Wr)                           over ALL n_experts
+        sel = top_k of s + bias        (``bias``: selection only, no gradient;
+                                        the ``noaux_tc`` balancing bias)
+        w   = s[sel] / (sum s[sel] + 1e-20) * routed_scale
+        y   = sum_{i in sel, i held} w_i E_i(x) + Shared(x)
+        E(x) = W2 (silu(W1 x) * W3 x)                 (SwiGLU, no biases)
+
+    ``held = (first, count)``: the layer routes over all ``n_experts`` and
+    computes experts ``first .. first + count - 1``, the share of one chip
+    of an expert-parallel deployment; what the absent experts would add is
+    left out (``None``: all are held). The router runs in float32 at the
+    highest matmul precision whatever the rest does, as the published
+    implementations compute the gate.
+
+    Dispatch is by sorting, without dropping: the (token, choice) pairs
+    are sorted by expert, each held expert's rows gathered into
+    ``capacity`` slots of ONE ``[count, capacity, H]`` buffer, the experts
+    run as a batched product over it, and the weighted rows are added back
+    into their tokens (a gather and a scatter-add of ``count * capacity``
+    rows, and the same two transposed in the backward pass: on a v5e a
+    scatter-add of 16,384 rows of 2,048 costs 2.0 ms, the six gathers a
+    token that avoid it 5.3); no ``(tokens, experts, capacity)`` tensor is
+    built. ``capacity_factor``
+    sets the slots an expert has as a multiple of the even share ``tokens *
+    top_k / n_experts`` (None: ``tokens``, which no routing can exceed).
+    An expert sent more rows than its slots is an ERROR, not a drop: the
+    layer's output is NaN, which the trainer's guard counts as a failed
+    step.
+
+    State: ``{"counters": {"moe/rows_local": rows routed to the held
+    experts, "moe/load_max_over_mean": the fullest of ALL experts' load
+    over the mean load}}``."""
+
+    def __init__(self, hidden_size: int, n_experts: int, top_k: int,
+                 expert_hidden: int, held=None, n_shared: int = 0,
+                 routed_scale: float = 1.0, capacity_factor=None, name=None):
+        super().__init__(name=name)
+        from .attention import FeedForwardNetwork
+        first, count = held or (0, n_experts)
+        if not (0 <= first and first + count <= n_experts and count > 0):
+            raise ValueError(f"held {held} is not a range of the "
+                             f"{n_experts} experts")
+        self.hidden_size, self.n_experts, self.top_k = (
+            hidden_size, n_experts, top_k)
+        self.expert_hidden, self.held = expert_hidden, (first, count)
+        self.routed_scale = routed_scale
+        self.capacity_factor = capacity_factor
+        self.shared = FeedForwardNetwork(
+            hidden_size, n_shared * expert_hidden, activation="swiglu",
+            bias=False) if n_shared else None
+
+    def _init_params(self, rng):
+        k = jax.random.split(rng, 5)
+        d, f, E = self.hidden_size, self.expert_hidden, self.n_experts
+        n = self.held[1]
+        s1, s2 = 1.0 / np.sqrt(d), 1.0 / np.sqrt(f)
+        p = {"router": jax.random.normal(k[0], (d, E)) * s1,
+             "bias": jnp.zeros((E,)),
+             "experts": {"w1": jax.random.normal(k[1], (n, d, f)) * s1,
+                         "w3": jax.random.normal(k[2], (n, d, f)) * s1,
+                         "w2": jax.random.normal(k[3], (n, f, d)) * s2}}
+        if self.shared:
+            p["shared"] = self.shared._init_params(k[4])
+        return p
+
+    def _init_state(self):
+        return {"counters": {ROWS_LOCAL: jnp.zeros(()),
+                             LOAD_MAX_OVER_MEAN: jnp.zeros(())}}
+
+    def capacity(self, tokens: int) -> int:
+        if self.capacity_factor is None:
+            return tokens
+        return min(tokens, expert_capacity(
+            tokens * self.top_k, self.n_experts, self.capacity_factor))
+
+    def route(self, params, x):
+        """``(sel [T, K] int32, w [T, K])`` for rows ``x [T, H]``."""
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), params["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, sel = jax.lax.top_k(
+            s + jax.lax.stop_gradient(params["bias"].astype(jnp.float32)),
+            self.top_k)
+        w = jnp.take_along_axis(s, sel, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return sel.astype(jnp.int32), (w * self.routed_scale).astype(x.dtype)
+
+    def _plan(self, sel, capacity):
+        """Which (token, choice) pair fills each slot. Pairs are sorted by
+        held expert (the others last); slot ``(e, c)`` is the c-th pair of
+        held expert ``e``. Returns the slots' pairs and validity, the load
+        of every expert and whether a held expert overflowed."""
+        first, count = self.held
+        local = sel.reshape(-1) - first
+        key = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        load = jnp.sum(sel.reshape(-1, 1) == jnp.arange(self.n_experts),
+                       axis=0, dtype=jnp.int32)
+        size = jax.lax.dynamic_slice_in_dim(load, first, count)
+        start = jnp.cumsum(size) - size
+        c = jnp.arange(capacity, dtype=jnp.int32)
+        slot_valid = (c[None, :] < size[:, None]).reshape(-1)
+        slot_pair = order[jnp.minimum(start[:, None] + c[None, :],
+                                      order.shape[0] - 1)].reshape(-1)
+        return slot_pair, slot_valid, load, jnp.any(size > capacity)
+
+    def _apply(self, params, state, x, training, rng):
+        shape = x.shape
+        h = x.reshape(-1, shape[-1])
+        T = h.shape[0]
+        count = self.held[1]
+        capacity = self.capacity(T)
+        with jax.named_scope("route"):
+            sel, w = self.route(params, h)
+            slot_pair, slot_valid, load, overflow = self._plan(sel, capacity)
+            slot_token = slot_pair // self.top_k
+            xs = jnp.where(slot_valid[:, None], h[slot_token], 0)
+        with jax.named_scope("experts"):
+            e = params["experts"]
+            xs = xs.reshape(count, capacity, -1)
+            mid = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xs, e["w1"])) \
+                * jnp.einsum("ecd,edf->ecf", xs, e["w3"])
+            ys = jnp.einsum("ecf,efd->ecd", mid, e["w2"])
+        with jax.named_scope("route"):
+            w_slot = jnp.where(slot_valid, w.reshape(-1)[slot_pair], 0)
+            y = jnp.zeros_like(h).at[slot_token].add(
+                w_slot[:, None] * ys.reshape(count * capacity, -1))
+            y = jnp.where(overflow, jnp.nan, y)
+            loadf = load.astype(jnp.float32)
+            counters = {
+                ROWS_LOCAL: jnp.sum(jax.lax.dynamic_slice_in_dim(
+                    loadf, self.held[0], count)),
+                LOAD_MAX_OVER_MEAN: jnp.max(loadf) / jnp.mean(loadf)}
+        if self.shared:
+            s, _ = self.shared.apply(params["shared"], {}, h, training, None,
+                                     scope="shared")
+            y = y + s
+        return y.reshape(shape), {"counters": counters}

@@ -129,6 +129,25 @@ class LayerNormalization(Module):
         return y * params["weight"] + params["bias"]
 
 
+class RMSNorm(Module):
+    """Root-mean-square norm over the last dim, a weight and no offset
+    (Zhang & Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * weight``. The
+    statistics are taken in float32 whatever the input's dtype."""
+
+    def __init__(self, hidden_size: int, eps: float = 1e-6, name=None):
+        super().__init__(name=name)
+        self.hidden_size, self.eps = hidden_size, eps
+
+    def _init_params(self, rng):
+        return {"weight": jnp.ones((self.hidden_size,))}
+
+    def _apply(self, params, state, x, training, rng):
+        xf = x.astype(jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + self.eps)
+        return (y * params["weight"]).astype(x.dtype)
+
+
 class SpatialCrossMapLRN(Module):
     """AlexNet-style LRN across channels (nn/SpatialCrossMapLRN.scala):
     y = x / (k + alpha/n * sum_{nearby c} x^2)^beta."""

@@ -991,6 +991,20 @@ class BaseOptimizer:
         # sync-ok: sync policy blocks on every step by definition
         return float(loss)
 
+    def _publish_counters(self, mstate, fused, step_span):
+        """What the model counted in the step whose loss was just read
+        (``mstate["counters"]``: scalars by name, an expert layer's routed
+        rows): into ``metrics`` and onto the ``step`` span, one small
+        readback where the loop has waited for the device already. Under a
+        lagged loss policy the newest state is still in flight and nothing
+        is read."""
+        counters = mstate.get("counters") if isinstance(mstate, dict) else None
+        if not counters or not (fused or self.sync_policy == "sync"):
+            return
+        for name, v in jax.device_get(counters).items():
+            self.metrics.add(name, float(v))
+            step_span.annotate(**{name: float(v)})
+
     def _drain_pending_losses(self, state):
         """Resolve losses still in flight when the loop ends (async's one
         pending read, window:K's up-to-K-1 tail) — a NaN pending on the
@@ -1816,6 +1830,7 @@ class BaseOptimizer:
                             # _resolved_step names it
                             losses = (self._observe_loss(loss, first),)
                             steps = (self._resolved_step,)
+                        self._publish_counters(mstate, fused, stp)
                     t2 = time.perf_counter()
                     # what follows the resolved losses: their host replay
                     # step by step, then bookkeeping, remediation and the

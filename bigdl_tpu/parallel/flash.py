@@ -65,12 +65,14 @@ def _einsum_attention(q, k, v, causal):
     return dot_product_attention(q, k, v, mask)
 
 
-def _einsum_attention_rows(q, k, v, heads, causal):
+def _einsum_attention_rows(q, k, v, heads, causal, scale=None):
     """:func:`_einsum_attention` read from the ``[B, T, H, D]`` view of
     ``[B, T, H * D]`` operands: no head is moved."""
     b, t, c = q.shape
     q, k, v = (x.reshape(b, x.shape[1], heads, -1) for x in (q, k, v))
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(q.shape[-1])
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    logits = (logits / math.sqrt(q.shape[-1]) if scale is None
+              else logits * scale)
     if causal:
         logits = logits + _causal_bias(t)
     w = jax.nn.softmax(logits, axis=-1)
@@ -175,17 +177,23 @@ def flash_attention(q, k, v, causal: bool = False):
                      lambda: _einsum_attention(q, k, v, causal))
 
 
-def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False):
+def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False,
+                         scale: float = None):
     """q, k, v: (B, T, H*D), the q/k/v projections as they are written;
     returns (B, T, H*D), what the output projection reads. Where the
     kernels can index that layout (whole heads fill 128-lane blocks:
     ``kernels.flash_attention.heads_per_block``) no head is split or
     merged; other head sizes are split here, go through
-    :func:`flash_attention` and are merged again."""
+    :func:`flash_attention` and are merged again. ``scale``: the softmax
+    scale where it is not ``D ** -0.5`` (rows layout only)."""
     b, t, c = q.shape
     if flash_mode() != "einsum":
         from ..kernels.flash_attention import heads_per_block
         if heads_per_block(num_heads, c // num_heads) is None:
+            if scale is not None:
+                raise NotImplementedError(
+                    "a softmax scale of its own needs heads that fill "
+                    "128-lane blocks")
             split = lambda x: x.reshape(b, x.shape[1], num_heads,  # noqa: E731
                                         -1).swapaxes(1, 2)
             o = flash_attention(split(q), split(k), split(v), causal=causal)
@@ -195,12 +203,12 @@ def flash_attention_rows(q, k, v, num_heads: int, causal: bool = False):
         from ..kernels.flash_attention import flash_attention_rows as rows
         _kernel_obs("flash_rows")
         return _over_batch(lambda q, k, v: rows(
-            q, k, v, num_heads, causal=causal, interpret=interpret,
-            **_flash_blocks()))(q, k, v)
+            q, k, v, num_heads, causal=causal, scale=scale,
+            interpret=interpret, **_flash_blocks()))(q, k, v)
 
     return _dispatch("flash attention", kernel,
                      lambda: _einsum_attention_rows(q, k, v, num_heads,
-                                                    causal))
+                                                    causal, scale))
 
 
 def flash_attention_qkv(qkv, num_heads: int, causal: bool = False):

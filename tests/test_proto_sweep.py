@@ -65,6 +65,8 @@ SPECS = {
     "L1Penalty": lambda: N.L1Penalty(0.01),
     "LSTM": lambda: N.LSTM(6, 5),
     "LSTMPeephole": lambda: N.LSTMPeephole(6, 5),
+    "LatentAttention": lambda: N.LatentAttention(16, 2, 8, 6, 2, 8,
+                                                 gated=True),
     "LayerNormalization": lambda: N.LayerNormalization(8),
     "Linear": lambda: N.Linear(6, 4),
     "LocallyConnected1D": lambda: N.LocallyConnected1D(8, 4, 3, 2),
@@ -77,6 +79,7 @@ SPECS = {
     "Model": lambda: _graph(N.Model),
     "MulConstant": lambda: N.MulConstant(2.0),
     "NormalizeScale": lambda: N.NormalizeScale(size=(1, 6, 1, 1)),
+    "RMSNorm": lambda: N.RMSNorm(8),
     "Recurrent": lambda: N.Recurrent(N.LSTM(6, 5)),
     "BiRecurrent": lambda: N.BiRecurrent().add(N.RnnCell(6, 5)),
     "MultiRNNCell": lambda: N.MultiRNNCell([N.RnnCell(6, 6),
@@ -117,6 +120,8 @@ SPECS = {
     "SpatialShareConvolution": lambda: N.SpatialShareConvolution(3, 4, 3, 3),
     "SpatialZeroPadding": lambda: N.SpatialZeroPadding(1, 1, 1, 1),
     "SplitTable": lambda: N.SplitTable(1),
+    "RoutedExperts": lambda: N.RoutedExperts(8, 4, 2, 6, held=(0, 2),
+                                             n_shared=1),
     "StaticGraph": lambda: _graph(N.StaticGraph),
     "TemporalConvolution": lambda: N.TemporalConvolution(4, 6, 3),
     "TemporalMaxPooling": lambda: N.TemporalMaxPooling(2),
