@@ -119,13 +119,11 @@ def _qkv_specs(bq, bk, w, q_blk, k_blk, at):
             _rows_spec(bk, w, k_blk, at[2])]
 
 
-def _mm(a, b, ta=False, tb=False):
-    """f32-accumulating matmul on the MXU; optionally transpose operands."""
-    ca = 0 if ta else 1
-    cb = 1 if tb else 0
-    out = jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    return out
+def _mm(a, b, tb=False):
+    """f32-accumulating matmul on the MXU: ``a @ b``, or ``a @ b.T`` (both
+    contracted over their lanes) with ``tb``."""
+    return jax.lax.dot_general(a, b, (((1,), (1 if tb else 0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _head_lanes(shape, h, d, g):
@@ -152,15 +150,35 @@ def _each_head(g, head):
         jax.lax.fori_loop(0, g, lambda h, carry: (head(h), carry)[1], 0)
 
 
-def _score_mask(q_off, k_off, block_q, block_k, kv_len, causal):
-    """Which (query row, key column) pairs of one tile take part."""
-    col = k_off + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+def _score_mask(q_off, k_off, block_q, block_k, kv_len, causal,
+                key_major=False):
+    """Which (query row, key column) pairs of one tile take part; with
+    ``key_major`` the tile is ``(block_k, block_q)``, keys down the rows."""
+    shape = (block_k, block_q) if key_major else (block_q, block_k)
+    col = k_off + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                           0 if key_major else 1)
     mask = col < kv_len
     if causal:
-        row = q_off + jax.lax.broadcasted_iota(jnp.int32,
-                                               (block_q, block_k), 0)
+        row = q_off + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                               1 if key_major else 0)
         mask = jnp.logical_and(mask, col <= row)
     return mask
+
+
+def _stat_spec(g, block_q, q_blk):
+    """BlockSpec of the ``(1, 1, g, block_q)`` tile of a row statistic
+    ``[N, heads // g, g, T]`` (:func:`_stat_view`) under the kernels' grid
+    ``(b, p, x, y)``: the ``g`` heads of lane block ``p``, one f32 a row,
+    the rows of query block ``q_blk(x, y)`` along the lanes."""
+    return pl.BlockSpec((1, 1, g, block_q),
+                        lambda b_, p_, x, y: (b_, p_, 0, q_blk(x, y)))
+
+
+def _stat_view(x, g, t_pad):
+    """A row statistic ``[N, heads, T]`` as the kernels index it: ``[N,
+    heads // g, g, t_pad]``, the heads of one lane block together."""
+    n, heads, _ = x.shape
+    return _pad_t(x, t_pad, axis=2).reshape(n, heads // g, g, t_pad)
 
 
 # ---------------------------------------------------------------------------
@@ -226,11 +244,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             lanes = _head_lanes(o_h.shape, h, d, g)
             acc_ref[:] = o_h if lanes is None else jnp.where(lanes, o_h,
                                                              acc_ref[:])
-            lse = m_ref[h, :, :1] + jnp.log(jnp.maximum(l, 1e-30))
-            lse_ref[0, h] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
         _each_head(g, head)
         o_ref[0] = acc_ref[:].astype(o_ref.dtype)
+        # the statistic leaves as ONE f32 a row, the rows along the lanes:
+        # m and l hold it on every lane of a (bq, 128) column, and row 0 of
+        # the transpose is the row to write, once a query block
+        for h in range(g):
+            lse = m_ref[h] + jnp.log(jnp.maximum(l_ref[h], 1e-30))
+            lse_ref[0, 0, h:h + 1, :] = lse.T[:1]
 
 
 def _sds(shape, dtype, vma):
@@ -287,14 +309,10 @@ def _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k, interpret,
         kernel,
         grid=(n, heads // g, nq, nk),
         in_specs=_qkv_specs(bq, bk, w, q_blk, k_blk, at),
-        out_specs=[
-            _rows_spec(bq, w, q_blk),
-            pl.BlockSpec((1, g, bq, 128),
-                         lambda b_, p_, i, j: (b_, p_, i, 0)),
-        ],
+        out_specs=[_rows_spec(bq, w, q_blk), _stat_spec(g, bq, q_blk)],
         out_shape=[
             _sds((n, tq_pad, c), q.dtype, vma),
-            _sds((n, heads, tq_pad, 128), jnp.float32, vma),
+            _sds((n, heads // g, g, tq_pad), jnp.float32, vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, w), jnp.float32),
@@ -304,7 +322,7 @@ def _fwd_rows(q, k, v, heads, causal, scale, block_q, block_k, interpret,
         interpret=interpret,
         name="flash_fwd",
     )(qp, kp, vp)
-    return o[:, :t_q], lse[:, :, :t_q, 0]
+    return o[:, :t_q], lse.reshape(n, heads, tq_pad)[:, :, :t_q]
 
 
 # ---------------------------------------------------------------------------
@@ -330,26 +348,31 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _compute():
         # bf16-operand MXU contractions with f32 accumulation (see the
         # forward kernel's dtype note); p/ds are computed in f32 and cast
-        # back to the wire dtype only as matmul operands
+        # back to the wire dtype only as matmul operands.
+        # KEY-MAJOR: the tiles are (bk, bq), keys down the rows, so a row
+        # statistic is used as it arrives, a (1, bq) row along the lanes (a
+        # new one every grid step: the query block is the inner axis), and
+        # dV, dK are plain products with no transposed operand
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         dt = q.dtype
-        mask = _score_mask(q_off, k_off, block_q, block_k, kv_len, causal)
+        mask = _score_mask(q_off, k_off, block_q, block_k, kv_len, causal,
+                           key_major=True)
 
         def head(h):
             # q and dO on the head's lanes alone: the scores contract
             # over them, and dV, dK land on them
             lanes = _head_lanes(q.shape, h, d, g)
             q_h, do_h = _own(q, lanes), _own(do, lanes)
-            lse = lse_ref[0, h, :, :1]                 # (bq, 1)
-            delta = delta_ref[0, h, :, :1]             # (bq, 1)
+            lse = lse_ref[0, 0, pl.ds(h, 1), :]        # (1, bq)
+            delta = delta_ref[0, 0, pl.ds(h, 1), :]    # (1, bq)
 
-            s = _mm(q_h, k, tb=True) * scale           # (bq, bk)
-            p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (bq, bk) f32
+            s = _mm(k, q_h, tb=True) * scale           # (bk, bq)
+            p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (bk, bq) f32
 
-            dv_acc[:] += _mm(p.astype(dt), do_h, ta=True)   # (bk, g*d)
-            dp = _mm(do_h, v, tb=True)                      # (bq, bk)
+            dv_acc[:] += _mm(p.astype(dt), do_h)       # (bk, g*d)
+            dp = _mm(v, do_h, tb=True)                 # (bk, bq)
             ds = p * (dp - delta) * scale
-            dk_acc[:] += _mm(ds.astype(dt), q_h, ta=True)   # (bk, g*d)
+            dk_acc[:] += _mm(ds.astype(dt), q_h)       # (bk, g*d)
 
         _each_head(g, head)
 
@@ -382,8 +405,10 @@ def _bwd_q_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             # here k and v carry the head: dQ lands on its lanes
             lanes = _head_lanes(k.shape, h, d, g)
             k_h, v_h = _own(k, lanes), _own(v, lanes)
-            lse = lse_ref[0, h, :, :1]
-            delta = delta_ref[0, h, :, :1]
+            # query-major tiles want the statistics down the rows: the
+            # head's (bq,) row turned into a (bq, 1) column, in VMEM
+            lse = jnp.expand_dims(lse_ref[0, 0, h], -1)
+            delta = jnp.expand_dims(delta_ref[0, 0, h], -1)
 
             s = _mm(q, k_h, tb=True) * scale
             p = jnp.where(mask, jnp.exp(s - lse), 0.0)
@@ -430,11 +455,7 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
 
     qp, kp, vp = _pad_each((q, k, v), (tq_pad, tkv_pad, tkv_pad))
     dop = _pad_t(g, tq_pad)
-    # lse/delta padded along T and broadcast into 128 lanes so each (bq, 128)
-    # tile is layout-friendly
-    ones = jnp.ones((1, 1, 1, 128), jnp.float32)
-    lsep = _pad_t(lse, tq_pad, axis=2)[..., None] * ones
-    deltap = _pad_t(delta, tq_pad, axis=2)[..., None] * ones
+    stats = (_stat_view(lse, hb, tq_pad), _stat_view(delta, hb, tq_pad))
 
     statics = dict(d=d, g=hb, scale=scale, block_q=bq, block_k=bk,
                    causal=causal, kv_len=t_kv)
@@ -442,8 +463,7 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
     q_blk, k_blk = (lambda x, y: y), (lambda x, y: x)
     q_spec = _rows_spec(bq, w, q_blk)
     k_spec = _rows_spec(bk, w, k_blk)
-    r_spec = pl.BlockSpec((1, hb, bq, 128),
-                          lambda b_, p_, x, y: (b_, p_, y, 0))
+    r_spec = _stat_spec(hb, bq, q_blk)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, nq=nq, **statics),
         grid=(n, heads // hb, nk, nq),
@@ -456,13 +476,12 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
                         pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(qp, kp, vp, dop, lsep, deltap)
+    )(qp, kp, vp, dop, *stats)
 
     # grid (b, p, query block x, key block y)
     q_blk, k_blk = (lambda x, y: x), (lambda x, y: y)
     q_spec2 = _rows_spec(bq, w, q_blk)
-    r_spec2 = pl.BlockSpec((1, hb, bq, 128),
-                           lambda b_, p_, x, y: (b_, p_, x, 0))
+    r_spec2 = _stat_spec(hb, bq, q_blk)
     dq = pl.pallas_call(
         functools.partial(_bwd_q_kernel, nk=nk, **statics),
         grid=(n, heads // hb, nq, nk),
@@ -473,7 +492,7 @@ def _bwd_rows(heads, causal, scale, block_q, block_k, interpret, res, g,
         scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qp, kp, vp, dop, lsep, deltap)
+    )(qp, kp, vp, dop, *stats)
 
     return dq[:, :t_q], dk[:, :t_kv], dv[:, :t_kv]
 
@@ -531,21 +550,16 @@ def _flash_vjp_fwd(q, k, v, heads, causal, scale, block_q, block_k,
 
 
 def _kept(o, lse):
-    """The two residuals the forward kernel itself computed, as a backward
-    pass keeps them."""
-    # `lse` is lane 0 of the kernel's 128-lane output. Tied to `o`, the
-    # slice runs before anything reads `o`; left free, XLA may put it off
-    # until the backward pass, and what is held meanwhile (by a remat
-    # policy that saves `lse`, or as a plain residual) is the 128-lane
-    # array. The barrier moves no data.
-    o, lse = jax.lax.optimization_barrier((o, lse))
-    # The two are named for remat policies (q, k, v and the fused
-    # projection are not: a checkpointed caller recomputes them from its
-    # own input). `o` is named as the kernel wrote it: `[B, T, H*D]`,
-    # dense in HBM, is what the caller's output projection reads and the
-    # backward kernels take. (Through the `[B, H, T, D]` entry it is
-    # `[B*H, T, D]`, padded to 128 lanes for as long as it is kept where
-    # D < 128.)
+    """The two residuals the forward kernel itself computed, named for
+    remat policies (q, k, v and the fused projection are not: a
+    checkpointed caller recomputes them from its own input)."""
+    # Both are kept as the kernel wrote them. `lse` is one f32 a row, so
+    # nothing has to order it before `o`'s readers: what a policy holds
+    # until the backward pass is those `[N, heads, T]` numbers whenever
+    # XLA chooses to lay them out. `o` is `[B, T, H*D]`, dense in HBM, what
+    # the caller's output projection reads and the backward kernels take.
+    # (Through the `[B, H, T, D]` entry it is `[B*H, T, D]`, padded to 128
+    # lanes for as long as it is kept where D < 128.)
     return (checkpoint_name(o, FLASH_OUT_NAME),
             checkpoint_name(lse, FLASH_LSE_NAME))
 

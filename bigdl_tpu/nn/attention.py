@@ -836,9 +836,10 @@ def remat_block(run):
     ``o`` and its logsumexp are kept, so the forward kernel runs once a
     layer and not twice. Per layer that keeps the block input and ``o``
     (two ``[B, T, H]`` tensors, was one; ``o`` in the merged form the
-    output projection reads) plus ``[B, heads, T]`` of f32. On the einsum
-    path no residual carries these names, nothing is kept, and the whole
-    block is recomputed."""
+    output projection reads) plus the logsumexp as the kernel wrote it
+    and the backward kernels read it: ``[B, heads, T]`` f32, one number a
+    row. On the einsum path no residual carries these names, nothing is
+    kept, and the whole block is recomputed."""
     return jax.checkpoint(
         run, policy=jax.checkpoint_policies.save_only_these_names(
             FLASH_OUT_NAME, FLASH_LSE_NAME))

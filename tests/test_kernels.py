@@ -9,15 +9,18 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bigdl_tpu.kernels import flash_attention_fused
+from bigdl_tpu.kernels import (flash_attention_fused, flash_attention_qkv,
+                               flash_attention_rows)
+from bigdl_tpu.kernels.flash_attention import flash_chunk_attention
 from bigdl_tpu.nn.attention import dot_product_attention
+from utils import jaxpr_equations
 
 
 def _ref(q, k, v, causal):
     mask = None
     if causal:
-        t = q.shape[-2]
-        mask = jnp.where(np.tril(np.ones((t, t), np.bool_))[None, None],
+        t_q, t_kv = q.shape[-2], k.shape[-2]
+        mask = jnp.where(np.tril(np.ones((t_q, t_kv), np.bool_))[None, None],
                          0.0, -1e30)
     return dot_product_attention(q, k, v, mask)
 
@@ -121,9 +124,89 @@ def test_flash_rows_match_einsum(monkeypatch, heads, d, t, rows):
         err = np.abs(np.asarray(a) - np.asarray(b)).max()
         assert err < 5e-4, f"d{name} err {err}"
     assert (heads_per_block(heads, d) is not None) == rows
-    forward = str(jax.make_jaxpr(lambda q, k, v: loss(q, k, v)[1])(q, k, v))
-    assert "name=flash_fwd" in forward
-    assert ("transpose[" not in forward) == rows, forward
+    forward = jax.make_jaxpr(lambda q, k, v: loss(q, k, v)[1])(q, k, v)
+    assert "name=flash_fwd" in str(forward)
+    around = {e.primitive.name for e in jaxpr_equations(forward.jaxpr, closed=("pallas_call",))}
+    assert ("transpose" not in around) == rows, forward
+
+
+_THREE = ["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"]
+
+
+@pytest.mark.parametrize("attend,shape,stat,kernels", [
+    # the fused projection, two 64-wide heads a lane block (g = 2)
+    (lambda x: flash_attention_qkv(x, 4, causal=True, interpret=True),
+     (2, 256, 3 * 4 * 64), (2, 2, 2, 256), _THREE),
+    # rows of 128-wide heads, one a lane block (g = 1)
+    (lambda x: flash_attention_rows(x, x, x, 2, causal=True, interpret=True),
+     (2, 256, 2 * 128), (2, 2, 1, 256), _THREE),
+    # split heads [B, H, T, D]: one head a row of [B * H, T, D]
+    (lambda x: flash_attention_fused(x, x, x, causal=True, interpret=True),
+     (2, 3, 256, 64), (6, 1, 1, 256), _THREE),
+    # the serving prefill's chunk, forward only
+    (lambda x: flash_chunk_attention(x[:, :, -128:], x, x, 128,
+                                     interpret=True),
+     (2, 3, 256, 64), (6, 1, 1, 128), ["flash_fwd"]),
+], ids=["qkv", "rows", "heads", "chunk"])
+def test_flash_statistics_cross_hbm_one_f32_a_row(attend, shape, stat,
+                                                  kernels):
+    """``lse`` and ``delta`` enter and leave every kernel of every entry
+    as ``[N, heads // g, g, T]``, T on the lanes, and nothing around the
+    kernels holds a row statistic repeated over a trailing 128."""
+    x = jnp.ones(shape, jnp.float32)
+    fn = lambda x: jnp.sum(attend(x))  # noqa: E731
+    if len(kernels) > 1:
+        fn = jax.value_and_grad(fn)
+    eqns = list(jaxpr_equations(jax.make_jaxpr(fn)(x).jaxpr,
+                                closed=("pallas_call",)))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == kernels
+    for call in calls:
+        io = call.outvars[1:] if call.params["name"] == "flash_fwd" \
+            else call.invars[4:]
+        assert [v.aval.shape for v in io] == [stat] * len(io), call
+        assert all(v.aval.dtype == jnp.float32 for v in io)
+    t = stat[-1]
+    for eqn in eqns:
+        for v in eqn.outvars:
+            sh = v.aval.shape
+            assert not (len(sh) == 4 and sh[-1] == 128 and sh[-2] >= t), eqn
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t_q,t_kv,block", [
+    (200, 200, 128),     # T no multiple of the tile, two tiles a side
+    (640, 640, 512),     # the step's own tile, T = 1.25 tiles
+    (200, 640, 128),     # Tq != Tkv: more key blocks than query blocks
+    (640, 200, 512),     # and fewer: one key tile, two query tiles
+])
+@pytest.mark.parametrize("heads,d", [(2, 64), (1, 128)], ids=["g2", "g1"])
+def test_flash_rows_grads_ragged(heads, d, t_q, t_kv, block, causal):
+    """Gradients of the rows entry against the einsum path where the
+    statistics' tiles are padded (T no multiple of the tile) and where the
+    two backward kernels walk different numbers of blocks (Tq != Tkv),
+    causal and not, two heads a lane block and one."""
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(1, t_q, heads * d).astype(np.float32))
+    k, v = [jnp.asarray(rng.randn(1, t_kv, heads * d).astype(np.float32))
+            for _ in range(2)]
+    split = lambda x: x.reshape(1, -1, heads, d).transpose(0, 2, 1, 3)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(jnp.sin(flash_attention_rows(
+            q, k, v, heads, causal=causal, block_q=block, block_k=block,
+            interpret=True)))
+
+    def loss_ref(q, k, v):
+        o = _ref(split(q), split(k), split(v), causal)
+        return jnp.sum(jnp.sin(o))
+
+    val, g = jax.value_and_grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    val_ref, g_ref = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    assert abs(float(val) - float(val_ref)) < 2e-3 * max(1, abs(float(val_ref)))
+    for a, b, name in zip(g, g_ref, "qkv"):
+        err = np.abs(np.asarray(a) - np.asarray(b)).max()
+        assert err < 5e-4, f"d{name} err {err}"
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -152,6 +152,34 @@ def test_ring_flash_interpret_kernel_path(causal, monkeypatch):
             (name, np.abs(np.asarray(a) - np.asarray(b)).max())
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t_q,t_kv", [(128, 128), (72, 200)])
+def test_flash_bwd_takes_the_ring_delta(t_q, t_kv, causal):
+    """The ring's contract with the block kernels: ``_flash_bwd`` handed
+    ``delta=`` (the rowsum of dO * O as ``[B, H, Tq]``, which the ring
+    computes once for all hops) and ``out_dtype=f32`` gives the gradients
+    of the path that computes ``delta`` itself, and gives them in f32
+    whatever the operands' dtype."""
+    from bigdl_tpu.kernels.flash_attention import _flash_bwd, _flash_fwd
+    rng = np.random.RandomState(12)
+    q, do = [jnp.asarray(rng.randn(2, 3, t_q, 64), jnp.bfloat16)
+             for _ in range(2)]
+    k, v = [jnp.asarray(rng.randn(2, 3, t_kv, 64), jnp.bfloat16)
+            for _ in range(2)]
+    o, lse = _flash_fwd(q, k, v, causal, 0.125, 128, 128, True)
+    assert lse.shape == (2, 3, t_q) and lse.dtype == jnp.float32
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    own = _flash_bwd(causal, 0.125, 128, 128, True, (q, k, v, o, lse), do)
+    ring = _flash_bwd(causal, 0.125, 128, 128, True, (q, k, v, o, lse), do,
+                      delta=delta, out_dtype=jnp.float32)
+    for a, b, x in zip(ring, own, (q, k, v)):
+        assert a.dtype == jnp.float32 and b.dtype == jnp.bfloat16
+        assert a.shape == b.shape == x.shape
+        # the same f32 accumulators, rounded to bfloat16 on one side only
+        assert np.array_equal(np.asarray(a.astype(jnp.bfloat16), np.float32),
+                              np.asarray(b, np.float32))
+
+
 def test_attention_module_seq_parallel_matches_dense():
     """nn.Attention(seq_axis='seq', causal=True) inside shard_map equals
     the same module's dense path — long-context through the MODEL API."""

@@ -17,6 +17,7 @@ from bigdl_tpu.models import TransformerLM
 from bigdl_tpu.optim import Adam, DistriOptimizer, LocalOptimizer
 from bigdl_tpu.optim.trigger import max_iteration
 from bigdl_tpu.parallel.mesh import data_parallel_mesh
+from utils import jaxpr_equations
 
 
 def lm_samples(n=8, t=16, vocab=64):
@@ -174,18 +175,6 @@ def test_the_flash_kernels_carry_their_names():
         assert name in bwd, name
 
 
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations hold
-    (``remat``, ``custom_vjp_call``, ``pjit``, ``shard_map``)."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _equations(sub)
-
-
 def test_flash_attention_moves_no_head(monkeypatch):
     """Two 64-wide heads fill a 128-lane block, so the training step's
     attention runs on the projections' own ``[B, T, H*D]``: under the scope
@@ -204,7 +193,7 @@ def test_flash_attention_moves_no_head(monkeypatch):
         return jnp.sum(jnp.tanh(model.apply(p, {}, ids,
                                             training=False)[0] * 0.01))
 
-    eqns = list(_equations(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
+    eqns = list(jaxpr_equations(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
     moved = [(e.invars[0].aval.shape, str(e.source_info.name_stack))
              for e in eqns if e.primitive.name == "transpose"
              and e.invars[0].aval.ndim == 4

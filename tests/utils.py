@@ -62,3 +62,19 @@ def check_gradient(module, x, eps=1e-3, tol=2e-2, seed=0):
 
 def allclose(a, b, tol=1e-5):
     return np.allclose(np.asarray(a), np.asarray(b), atol=tol, rtol=tol)
+
+
+def jaxpr_equations(jaxpr, closed=()):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (``remat``, ``custom_vjp_call``, ``pjit``, ``shard_map``); the bodies of
+    the primitives named in ``closed`` (a ``pallas_call``'s kernel) are
+    not entered."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name in closed:
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from jaxpr_equations(sub, closed)
