@@ -27,9 +27,13 @@ and each query attends to the ``min(t + 1, topk)`` keys of largest score
                    scales what it kept.
 
 Every kernel that uses the selection reads the one bitmask, so the forward
-and both backward kernels see the identical set, and every one skips a
-(query block, key block) tile with no selected pair. Nothing of size
-``[T, T]`` or ``[T, topk]`` but the bitmask (``T * T`` bits) reaches HBM.
+and both backward kernels see the identical set. The four kernels that tile
+the (query block, key block) square run its whole grid and skip in two
+ways: a step whose tile is not causal neither computes nor fetches (its
+index maps return the blocks of the nearest causal step of its grid row,
+so the pipeline sees no new block), and a causal tile with no selected
+pair skips its compute but still fetches. Nothing of size ``[T, T]`` or
+``[T, topk]`` but the bitmask (``T * T`` bits) reaches HBM.
 
 The bitmask is ``[N, (T // block_q) * R, T]`` int32 with ``R = block_q //
 32``: bit ``r`` of word ``(i * R + w, s)`` is the pair (query ``i *
@@ -266,6 +270,18 @@ def _visit(i, j, bq, bk, words):
     return jnp.logical_and(j * bk <= i * bq + bq - 1, jnp.any(words != 0))
 
 
+def _last_k(i, bq, bk, nk):
+    """The last key block query block ``i`` sees: tile ``(i, j)`` is causal
+    for ``j <= _last_k(i)``."""
+    return jnp.minimum((i * bq + bq - 1) // bk, nk - 1)
+
+
+def _first_q(j, bq, bk):
+    """The first query block that sees key block ``j``: tile ``(i, j)`` is
+    causal for ``i >= _first_q(j)``."""
+    return j * bk // bq
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, g, d, scale, bq, bk, nk):
     i, j = pl.program_id(2), pl.program_id(3)
@@ -306,7 +322,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, acc_ref,
 def _specs(g, d, bq, bk, q_blk, k_blk):
     """q-like ``(1, bq, g*d)``, k-like ``(1, bk, d)``, a row statistic
     ``(1, g, bq)`` and the bitmask's ``(1, R, bk)`` under the grid ``(b,
-    kv head, x, y)``."""
+    kv head, x, y)``, with ``q_blk(x, y)`` and ``k_blk(x, y)`` the query
+    and key block of a step."""
     nw = _words(bq)
     return dict(
         q=pl.BlockSpec((1, bq, g * d), lambda b, p, x, y: (b, q_blk(x, y), p)),
@@ -316,11 +333,19 @@ def _specs(g, d, bq, bk, q_blk, k_blk):
                           lambda b, p, x, y: (b, q_blk(x, y), k_blk(x, y))))
 
 
+def _query_major(g, d, bq, bk, nk):
+    """The specs under the grid ``(b, kv head, i, j)``, key block ``j``
+    innermost: a step past row ``i``'s last causal key block keeps that
+    block, so it fetches nothing."""
+    return _specs(g, d, bq, bk, lambda i, j: i,
+                  lambda i, j: jnp.minimum(j, _last_k(i, bq, bk, nk)))
+
+
 def _attn_fwd(q, k, v, bits, heads, kv_heads, scale, bq, bk, interpret):
     n, t, c = q.shape
     d, g = c // heads, heads // kv_heads
     nq, nk = t // bq, t // bk
-    sp = _specs(g, d, bq, bk, lambda x, y: x, lambda x, y: y)
+    sp = _query_major(g, d, bq, bk, nk)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, g=g, d=d, scale=scale, bq=bq, bk=bk,
                           nk=nk),
@@ -415,7 +440,11 @@ def _attn_bwd(q, k, v, bits, o, lse, do, heads, kv_heads, scale, bq, bk,
     delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
                      ).reshape(n, t, heads, d), axis=-1).transpose(0, 2, 1)
     statics = dict(g=g, d=d, scale=scale, bq=bq, bk=bk)
-    sp = _specs(g, d, bq, bk, lambda x, y: y, lambda x, y: x)
+    # grid (b, kv head, j, i), query block i innermost: the steps before
+    # key block j's first causal query block take that block, which the
+    # row's first step fetches once
+    sp = _specs(g, d, bq, bk, lambda j, i: jnp.maximum(i, _first_q(j, bq, bk)),
+                lambda j, i: j)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, nq=nq, **statics),
         grid=(n, kv_heads, nk, nq),
@@ -431,7 +460,7 @@ def _attn_bwd(q, k, v, bits, o, lse, do, heads, kv_heads, scale, bq, bk,
         interpret=interpret,
         name="dsa_bwd_dkv",
     )(q, k, v, do, lse, delta, bits)
-    sp = _specs(g, d, bq, bk, lambda x, y: x, lambda x, y: y)
+    sp = _query_major(g, d, bq, bk, nk)
     dq = pl.pallas_call(
         functools.partial(_bwd_q_kernel, nk=nk, **statics),
         grid=(n, kv_heads, nq, nk),
@@ -553,22 +582,35 @@ def _index_call(qi, kit, w, ilse, bits, q, k, lse, heads, kv_heads, scale,
     kernel = functools.partial(
         _index_kernel, n_index=n_index, g=g, d=d, heads=heads, scale=scale,
         bq=bq, bk=bk, kv_heads=kv_heads)
+    nk = t // bk
     row = lambda b, i, j, p: (b, 0, i)
     qi_spec = pl.BlockSpec((1, n_index, bq, d_index),
                            lambda b, i, j, p: (b, 0, i, 0))
     kit_all = pl.BlockSpec((1, d_index, t), lambda b, i, j, p: (b, 0, 0))
+
+    def causal(shape, index):
+        """``index(b, i, j, p)``'s block, and on a step past row ``i``'s
+        last causal key block that of the row's last causal step (KV head
+        ``p`` innermost), so such a step fetches nothing."""
+        def clamped(b, i, j, p):
+            last = _last_k(i, bq, bk, nk)
+            past = j > last
+            return index(b, i, jnp.where(past, last, j),
+                         jnp.where(past, kv_heads - 1, p))
+        return pl.BlockSpec(shape, clamped)
+
     return pl.pallas_call(
         kernel,
-        grid=(n, t // bq, t // bk, kv_heads),
+        grid=(n, t // bq, nk, kv_heads),
         in_specs=[
             qi_spec,
-            pl.BlockSpec((1, d_index, bk), lambda b, i, j, p: (b, 0, j)),
+            causal((1, d_index, bk), lambda b, i, j, p: (b, 0, j)),
             pl.BlockSpec((1, n_index, bq), row),
             pl.BlockSpec((1, 1, bq), row),
-            pl.BlockSpec((1, nw, bk), lambda b, i, j, p: (b, i, j)),
-            pl.BlockSpec((1, bq, g * d), lambda b, i, j, p: (b, i, p)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, p: (b, j, p)),
-            pl.BlockSpec((1, g, bq), lambda b, i, j, p: (b, p, i))],
+            causal((1, nw, bk), lambda b, i, j, p: (b, i, j)),
+            causal((1, bq, g * d), lambda b, i, j, p: (b, i, p)),
+            causal((1, bk, d), lambda b, i, j, p: (b, j, p)),
+            causal((1, g, bq), lambda b, i, j, p: (b, p, i))],
         out_specs=[pl.BlockSpec((1, 1, bq), row), qi_spec, kit_all,
                    pl.BlockSpec((1, n_index, bq), row)],
         out_shape=[jax.ShapeDtypeStruct((n, 1, t), jnp.float32),

@@ -1,7 +1,8 @@
 """The learned-sparse-attention kernels (``kernels/sparse_attention.py``) in
 the Pallas interpreter against plain ``jax.numpy``: the exact top-k
 selection and its tie rule, the sparse grouped-query attention forward and
-backward, the indexer's KL and its gradient, the tiles skipped."""
+backward, the indexer's KL and its gradient, the tiles skipped, and the
+grid steps that fetch nothing (their index maps walked in pure Python)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +88,20 @@ def selected(x):
     return _select(x)
 
 
+@pytest.fixture(scope="module", params=[(T, BQ, BK), (1024, 256, 256)],
+                ids=["skips_in_one_row", "skips_span_rows"])
+def case(request):
+    """Inputs, their selection and the tiling: at ``T`` = 512 with 256 x
+    128 tiles only the first query row has non-causal steps; at 1,024 with
+    256 x 256 tiles three rows have them, each kernel's skipped steps run
+    over several rows and the clamped index maps reach back across them."""
+    t, bq, bk = request.param
+    x = _inputs(t=t)
+    bits, ilse = sa.dsa_select(x["qi"], x["kit"], x["w"], K, bq, bk,
+                               interpret=True)
+    return dict(x=x, bits=bits, ilse=ilse, t=t, bq=bq, bk=bk)
+
+
 def test_the_selection_is_the_exact_top_k_of_every_row(x, selected):
     bits, ilse = selected
     index = _scores(x["qi"], x["kit"], x["w"])
@@ -123,18 +138,17 @@ def test_ties_go_to_the_earlier_key(x, kind):
             want[0], (cols[None] <= cols[:, None]) & (cols[None] < K))
 
 
-def test_the_sparse_attention_and_its_gradients_are_the_references(
-        x, selected):
-    bits, _ = selected
-    chosen = jnp.asarray(_unpack(bits, T))
+def test_the_sparse_attention_and_its_gradients_are_the_references(case):
+    x, bits, bq, bk = case["x"], case["bits"], case["bq"], case["bk"]
+    chosen = jnp.asarray(_unpack(bits, case["t"], bq))
     o, lse = sa.dsa_attention(x["q"], x["k"], x["v"], bits, H, KV, SCALE,
-                              BQ, BK, interpret=True)
+                              bq, bk, interpret=True)
     ro, rlse, _ = _attention(x["q"], x["k"], x["v"], chosen)
     np.testing.assert_allclose(o, ro, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(lse, rlse, rtol=2e-5, atol=2e-5)
     g = jax.random.normal(jax.random.PRNGKey(9), o.shape)
     got = jax.grad(lambda q, k, v: jnp.sum(sa.dsa_attention(
-        q, k, v, bits, H, KV, SCALE, BQ, BK, interpret=True)[0] * g),
+        q, k, v, bits, H, KV, SCALE, bq, bk, interpret=True)[0] * g),
         (0, 1, 2))(x["q"], x["k"], x["v"])
     want = jax.grad(lambda q, k, v: jnp.sum(
         _attention(q, k, v, chosen)[0] * g), (0, 1, 2))(
@@ -144,11 +158,12 @@ def test_the_sparse_attention_and_its_gradients_are_the_references(
                                    atol=2e-5 * float(jnp.max(jnp.abs(b))))
 
 
-def test_the_indexers_kl_and_its_gradients_are_the_references(x, selected):
-    bits, ilse = selected
-    chosen = jnp.asarray(_unpack(bits, T))
+def test_the_indexers_kl_and_its_gradients_are_the_references(case):
+    x, bits, ilse = case["x"], case["bits"], case["ilse"]
+    bq, bk = case["bq"], case["bk"]
+    chosen = jnp.asarray(_unpack(bits, case["t"], bq))
     _, lse = sa.dsa_attention(x["q"], x["k"], x["v"], bits, H, KV, SCALE,
-                              BQ, BK, interpret=True)
+                              bq, bk, interpret=True)
     _, _, p = _attention(x["q"], x["k"], x["v"], chosen)
     mean = jnp.mean(p, axis=(1, 2))
 
@@ -161,7 +176,7 @@ def test_the_indexers_kl_and_its_gradients_are_the_references(x, selected):
 
     def got(qi, kit, w):
         return sa.dsa_index_loss(qi, kit, w, ilse, bits, x["q"], x["k"], lse,
-                                 H, KV, SCALE, BQ, BK, interpret=True)
+                                 H, KV, SCALE, bq, bk, interpret=True)
 
     args = (x["qi"], x["kit"], x["w"])
     kl, grads = jax.value_and_grad(got, (0, 1, 2))(*args)
@@ -212,3 +227,97 @@ def test_block_and_length_rules():
         sa.dsa_select(x["qi"], x["kit"], x["w"], K, BQ, BK, interpret=True)
     with pytest.raises(ValueError, match="multiple of 256"):
         sa.dsa_select(x["qi"], x["kit"], x["w"], K, 128, BK, interpret=True)
+
+
+# the cell keye_train_16k's shapes: one row of 16,384 positions, 32 query
+# and 4 KV heads of 128, an indexer of 16 heads of 64, 512 x 512 tiles
+SHAPES = {"test": dict(t=T, heads=H, kv=KV, hi=HI, bq=BQ, bk=BK),
+          "keye_16k": dict(t=16384, heads=32, kv=4, hi=16, bq=512, bk=512)}
+# the grid axes of each tiled kernel that hold the query block i and the
+# key block j
+GRID_IJ = {"dsa_fwd": (2, 3), "dsa_bwd_dq": (2, 3), "dsa_bwd_dkv": (3, 2),
+           "dsa_index_bwd": (1, 2)}
+
+
+def _calls(monkeypatch, t, heads, kv, hi, bq, bk):
+    """Each tiled kernel's grid, input BlockSpecs and input dtypes, by
+    ``name=``, as the attention's value and gradient and the indexer's loss
+    build them at these shapes: ``pallas_call`` is replaced by a recorder
+    under ``jax.eval_shape``, so nothing is allocated and no kernel runs."""
+    calls = {}
+
+    def record(kernel, *, grid, in_specs, out_shape, name, **_):
+        def call(*args):
+            calls[name] = (grid, in_specs, [a.dtype for a in args])
+            return jax.tree.map(lambda o: jnp.zeros(o.shape, o.dtype),
+                                out_shape)
+        return call
+
+    monkeypatch.setattr(sa.pl, "pallas_call", record)
+    sds = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    shapes = (sds((N, t, heads * D), f32), sds((N, t, kv * D), f32),
+              sds((N, t, kv * D), f32), sds((N, t // 32, t), jnp.int32),
+              sds((N, hi, t, DI), f32), sds((N, DI, t), f32),
+              sds((N, hi, t), f32), sds((N, 1, t), f32))
+
+    def build(q, k, v, bits, qi, kit, w, ilse):
+        grads = jax.grad(lambda q, k, v: jnp.sum(sa.dsa_attention(
+            q, k, v, bits, heads, kv, SCALE, bq, bk)[0]), (0, 1, 2))(q, k, v)
+        _, lse = sa.dsa_attention(q, k, v, bits, heads, kv, SCALE, bq, bk)
+        return grads, sa.dsa_index_loss(qi, kit, w, ilse, bits, q, k, lse,
+                                        heads, kv, SCALE, bq, bk)
+
+    jax.eval_shape(build, *shapes)
+    return calls
+
+
+def _blocks(grid, spec):
+    """The block index of ``spec`` at every grid step, in the pipeline's
+    order (the last axis fastest): ``(steps, rank)``."""
+    steps = np.indices(grid).reshape(len(grid), -1)
+    index = spec.index_map(*steps)
+    return np.stack([np.broadcast_to(np.asarray(c), steps.shape[1:])
+                     for c in index], axis=-1)
+
+
+def _fetched(blocks, specs, dtypes, steps):
+    """Bytes the pipeline copies in over ``steps`` (a mask of the grid's
+    steps): an input's block on the first step and wherever its index
+    differs from the previous step's."""
+    total = 0
+    for b, spec, dtype in zip(blocks, specs, dtypes):
+        b = b[steps]
+        copies = 1 + int(np.sum(np.any(b[1:] != b[:-1], axis=-1)))
+        total += copies * int(np.prod(spec.block_shape)) * dtype.itemsize
+    return total
+
+
+@pytest.mark.parametrize("shapes", sorted(SHAPES))
+@pytest.mark.parametrize("kernel", sorted(GRID_IJ))
+def test_non_causal_grid_steps_fetch_nothing(monkeypatch, kernel, shapes):
+    """A step whose tile is not causal takes no block of its own: an
+    input's block index stays the previous step's, or (on the first steps
+    of a ``dsa_bwd_dkv`` row) moves to the block of the next causal step,
+    which then fetches nothing. What a call fetches is what its causal
+    steps alone would fetch."""
+    s = SHAPES[shapes]
+    grid, specs, dtypes = _calls(monkeypatch, **s)[kernel]
+    steps = np.indices(grid).reshape(len(grid), -1)
+    i, j = (steps[a] for a in GRID_IJ[kernel])
+    causal = j * s["bk"] <= i * s["bq"] + s["bq"] - 1
+    at = np.arange(causal.size)
+    # the next causal step, in grid order, of every step
+    ahead = np.minimum.accumulate(np.where(causal, at, at[-1])[::-1])[::-1]
+    blocks = [_blocks(grid, spec) for spec in specs]
+    for n, b in enumerate(blocks):
+        moved = np.concatenate([[True], np.any(b[1:] != b[:-1], axis=-1)])
+        early = moved & ~causal
+        np.testing.assert_array_equal(b[early], b[ahead[early]],
+                                      err_msg=f"{kernel} input {n}")
+        assert not np.any(moved[ahead[early]]), (kernel, n)
+    assert (_fetched(blocks, specs, dtypes, causal)
+            == _fetched(blocks, specs, dtypes, np.ones_like(causal)))
+    if shapes == "keye_16k":
+        # 496 of the 1,024 tiles a KV head lie past the diagonal
+        assert (int(np.sum(~causal)), causal.size) == (1984, 4096)
