@@ -39,7 +39,8 @@ per phase. Enable with ``observability.enable()`` or
 ``BIGDL_TPU_TRACE=1`` in the environment.
 
 Span naming convention: ``<subsystem>/<phase>`` with the subsystem as a
-stable prefix (``step/``, ``eval/``, ``predict/``, ``bench/``); nested
+stable prefix (``step/``, ``eval/``, ``predict/``, ``bench/``,
+``host/``, ``epoch/``); nested
 phases extend the parent's name (``step`` > ``step/data_fetch``).
 """
 from __future__ import annotations
@@ -47,7 +48,8 @@ from __future__ import annotations
 import os as _os
 
 from .trace import (Tracer, enable, disable, enabled, span, instant,
-                    complete, get_tracer, reset)
+                    complete, get_tracer, reset, GC_SPAN, GcPauses,
+                    gc_hook_install, gc_hook_remove)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       registry, counter, gauge, histogram)
 from .exporters import (chrome_trace, write_chrome_trace, prometheus_text,

@@ -92,7 +92,9 @@ class MetricSnapshotWriter:
         self._dir = directory or _flight.bundle_dir()
         self._idx = _process_index() if process_index is None \
             else int(process_index)
-        self._last = 0.0
+        # no write yet: the first maybe_write writes whatever the
+        # monotonic clock reads (its origin is arbitrary, e.g. boot)
+        self._last: Optional[float] = None
         self.writes = 0
         self._sections: Dict[str, object] = {}
 
@@ -120,7 +122,7 @@ class MetricSnapshotWriter:
             if self.every_s <= 0:
                 return None
             now = time.monotonic()
-            if now - self._last < self.every_s:
+            if self._last is not None and now - self._last < self.every_s:
                 return None
             self._last = now
         return self.write(step=step)

@@ -237,6 +237,21 @@ def dump_crash_bundle(error: Optional[BaseException] = None,
 AGGREGATE_SCHEMA = "bigdl_tpu.flight_aggregate.v1"
 
 
+def _aggregate_watermark(directory: str, name: str) -> float:
+    """The newest ``written_at`` an aggregate folded: its own record of
+    it, else its write time, else its file name's millisecond."""
+    try:
+        with open(os.path.join(directory, name)) as f:
+            agg = json.load(f)
+        return float(agg.get("folded_through", agg["written_at"]))
+    except Exception:
+        pass
+    try:
+        return float(name.rsplit("_", 1)[1].split(".")[0]) / 1000.0
+    except (IndexError, ValueError):
+        return 0.0
+
+
 def aggregate_bundles(directory: Optional[str] = None,
                       out: Optional[str] = None) -> Optional[str]:
     """Merge every per-process crash bundle under ``directory`` (default
@@ -255,15 +270,15 @@ def aggregate_bundles(directory: Optional[str] = None,
         d = directory or bundle_dir()
         if not os.path.isdir(d):
             return None
-        last_agg = 0.0  # watermark: newest existing aggregate
+        # watermark: the newest bundle an existing aggregate folded, on
+        # the bundles' own clock (the file name keeps whole milliseconds
+        # only, and a bundle written in the aggregate's millisecond would
+        # be folded again)
+        last_agg = 0.0
         for name in os.listdir(d):
             if name.startswith("flight_aggregate") and \
                     name.endswith(".json"):
-                try:
-                    last_agg = max(last_agg, float(
-                        name.rsplit("_", 1)[1].split(".")[0]) / 1000.0)
-                except (IndexError, ValueError):
-                    pass
+                last_agg = max(last_agg, _aggregate_watermark(d, name))
         bundles = []
         for name in sorted(os.listdir(d)):
             if not (name.startswith("flight_") and name.endswith(".json")) \
@@ -299,6 +314,8 @@ def aggregate_bundles(directory: Optional[str] = None,
                "written_at_iso": datetime.datetime.fromtimestamp(
                    now, datetime.timezone.utc).isoformat(),
                "n_bundles": len(bundles), "summary": summary,
+               "folded_through": max(b.get("written_at", 0)
+                                     for b in bundles),
                "bundles": bundles}
         if out is None:
             out = os.path.join(d, f"flight_aggregate_{int(now * 1000)}.json")

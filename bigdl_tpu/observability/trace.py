@@ -27,6 +27,7 @@ Design constraints, in order:
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from typing import Dict, List, Optional
@@ -241,6 +242,81 @@ def span(name: str, **args):
     if not _enabled:
         return _annotation(name, args)
     return _tracer.span(name, **args)
+
+
+#: the span of one collection of Python's cyclic garbage collector
+GC_SPAN = "host/gc"
+
+# -- the collector's pauses (``gc.callbacks``) ------------------------------
+# A collection holds the GIL, so one on any thread stops the loop too.
+# CPython runs one collection at a time process-wide, so one slot holds
+# the collection in progress. The callback takes no lock (a collection can
+# start inside any allocation, a lock's holder included) and allocates a
+# fixed handful of objects a collection; the in-memory tracer does not
+# see the span, only a profiler session does.
+_gc_users = 0
+_gc_users_lock = threading.Lock()
+_gc_open = None               # (annotation, generation, start ns)
+_gc_totals = (0, 0, 0)        # ns, collections, generation-2 collections
+
+
+def _on_gc(phase, info):
+    global _gc_open, _gc_totals
+    if phase == "start":
+        ann = _ProfilerSpan(GC_SPAN, generation=info["generation"])
+        ann.__enter__()
+        _gc_open = (ann, info["generation"], time.perf_counter_ns())
+    elif _gc_open is not None:
+        (ann, gen, t0), _gc_open = _gc_open, None
+        ns, n, full = _gc_totals
+        _gc_totals = (ns + time.perf_counter_ns() - t0, n + 1,
+                      full + (gen == 2))
+        ann.set_metadata(collected=info["collected"],
+                         uncollectable=info["uncollectable"])
+        ann.__exit__(None, None, None)
+
+
+def gc_hook_install():
+    """Open a ``host/gc`` span (``generation=``; ``collected=`` and
+    ``uncollectable=`` at its end) around every collection on whatever
+    thread runs it, and sum the pauses for :class:`GcPauses`. Reference
+    counted: the hook is in ``gc.callbacks`` once, however many callers
+    hold it; each :func:`gc_hook_remove` undoes one install."""
+    global _gc_users
+    with _gc_users_lock:
+        _gc_users += 1
+        if _gc_users == 1:
+            gc.callbacks.append(_on_gc)
+
+
+def gc_hook_remove():
+    global _gc_users, _gc_open
+    with _gc_users_lock:
+        if _gc_users <= 0:
+            return
+        _gc_users -= 1
+        if _gc_users == 0:
+            gc.callbacks.remove(_on_gc)
+            _gc_open = None
+
+
+class GcPauses:
+    """The collector's pauses since the previous :meth:`take` (or since
+    this reader was made), summed over the process while the hook was
+    installed: each reader keeps its own mark on the process-wide totals,
+    so two loops never take each other's."""
+
+    __slots__ = ("_mark",)
+
+    def __init__(self):
+        self._mark = _gc_totals
+
+    def take(self):
+        """(ms, collections, generation-2 collections) since the last
+        call."""
+        now, (ns, n, full) = _gc_totals, self._mark
+        self._mark = now
+        return (now[0] - ns) / 1e6, now[1] - n, now[2] - full
 
 
 def instant(name: str, **args):

@@ -1189,6 +1189,10 @@ class BaseOptimizer:
                            batch_size=self.batch_size,
                            superstep=self.superstep,
                            sync_policy=self.sync_policy)
+        # the collector's pauses as ``host/gc`` spans, and per step as
+        # the ``step`` span's ``host/gc_*`` counters, for this run only
+        obs.gc_hook_install()
+        self._gc_pauses = obs.GcPauses()
         try:
             return self._optimize_impl()
         except TrainingHalted:
@@ -1206,6 +1210,7 @@ class BaseOptimizer:
                     "nan_policy": self.nan_policy})
             raise
         finally:
+            obs.gc_hook_remove()
             if self._snap_writer.enabled and obs.enabled():
                 # terminal snapshot: the cluster merge must see this
                 # process's END state, not its last cadence tick —
@@ -1258,42 +1263,41 @@ class BaseOptimizer:
         fused = self.superstep > 1
         done = False
         nan_streak = 0
+        batches, epoch_start = self._open_epoch(batched, fused)
         while not done:
-            batched.shuffle()
-            epoch_start = time.time()
-            # the stager owns produce + device placement; with
-            # prefetch_depth >= 2 both run on a lookahead thread while
-            # the device computes, otherwise inline (the serial loop).
-            # With superstep K > 1 it also owns the stacking stage:
-            # groups of K microbatches assemble into [K, batch, ...]
-            # device stacks and the hot loop dequeues one per dispatch.
-            batches = staged(batched.data(train=True),
-                             _host_xy if fused else self._stage_minibatch,
-                             depth=self.prefetch_depth, name="stager",
-                             group=self.superstep,
-                             group_fn=self._stage_group if fused else None,
-                             group_key=self._stage_group_key,
-                             stall_deadline_s=self.stall_deadline_s)
             box = {"params": params, "opt_state": opt_state,
                    "mstate": mstate, "nan_streak": nan_streak, "done": done}
             try:
                 self._run_epoch(batches, state, box, fused)
-            finally:
+            except BaseException:
                 batches.close()  # join the stager thread — no leaks, ever
+                raise
             params, opt_state, mstate = \
                 box["params"], box["opt_state"], box["mstate"]
             nan_streak, done = box["nan_streak"], box["done"]
-            if not done:
+            if done:
+                batches.close()
+                break
+            # the epoch boundary, from the exhausted stager's join to the
+            # next one's start: one span, so that the loop's host time
+            # lies under ``step`` or here
+            with obs.span("epoch/turnover", epoch=state["epoch"]):
+                t_turn = time.perf_counter()
+                batches.close()
                 state["epoch"] += 1
                 state["epoch_finished"] = True
-                self.metrics.add("epoch_time", time.time() - epoch_start)
-                if obs.enabled():
-                    _flight.record("epoch", epoch=state["epoch"] - 1,
-                                   neval=state["neval"],
-                                   epoch_time_s=time.time() - epoch_start)
+                epoch_s = time.time() - epoch_start
+                self.metrics.add("epoch_time", epoch_s)
                 self._fire_epoch(state, params, opt_state, mstate)
-                if self.end_trigger(state):
-                    done = True
+                done = bool(self.end_trigger(state))
+                if not done:
+                    batches, epoch_start = self._open_epoch(batched, fused)
+                turnover_s = time.perf_counter() - t_turn
+            self.metrics.add("epoch_turnover_time", turnover_s)
+            if obs.enabled():
+                _flight.record("epoch", epoch=state["epoch"] - 1,
+                               neval=state["neval"], epoch_time_s=epoch_s,
+                               turnover_s=turnover_s)
 
         # drain the async/window in-flight losses (a NaN pending on the
         # final steps must not be swallowed)
@@ -1303,6 +1307,24 @@ class BaseOptimizer:
         self.model.grad_params = _tmap(jnp.zeros_like, self.model.params)
         self._close_checkpoints()  # land async writes, stop the writer
         return self.model
+
+    def _open_epoch(self, batched, fused):
+        """Reshuffle and start the epoch's stager: (batches, wall start).
+        The stager owns produce + device placement; with prefetch_depth
+        >= 2 both run on a lookahead thread while the device computes,
+        otherwise inline (the serial loop). With superstep K > 1 it also
+        owns the stacking stage: groups of K microbatches assemble into
+        [K, batch, ...] device stacks and the hot loop dequeues one per
+        dispatch."""
+        batched.shuffle()
+        epoch_start = time.time()
+        return staged(batched.data(train=True),
+                      _host_xy if fused else self._stage_minibatch,
+                      depth=self.prefetch_depth, name="stager",
+                      group=self.superstep,
+                      group_fn=self._stage_group if fused else None,
+                      group_key=self._stage_group_key,
+                      stall_deadline_s=self.stall_deadline_s), epoch_start
 
     # -- self-healing tiers ---------------------------------------------
     def _dispatch_guarded(self, params, opt_state, mstate, *args):
@@ -1838,6 +1860,12 @@ class BaseOptimizer:
                             steps = (self._resolved_step,)
                         self._publish_counters(mstate, fused, stp)
                     t2 = time.perf_counter()
+                    # the collector's pauses since the previous step's
+                    # read, on any thread: host numbers, no readback
+                    gc_ms, gc_n, gc_full = self._gc_pauses.take()
+                    stp.annotate(**{"host/gc_ms": gc_ms,
+                                    "host/gc_collections": gc_n,
+                                    "host/gc_full": gc_full})
                     # what follows the resolved losses: their host replay
                     # step by step, then bookkeeping, remediation and the
                     # triggers (validation, checkpoint, the caller's end
@@ -1872,6 +1900,7 @@ class BaseOptimizer:
                         if self._profiler is not None:
                             self._profiler.maybe_tick(state["neval"])
                         self.metrics.add("data_time", t1 - t0)
+                        self.metrics.add("gc_time", gc_ms / 1e3)
                         self.metrics.add("step_time", t2 - t1)
                         if obs.enabled():
                             obs.counter("optim/steps").inc(k)

@@ -360,10 +360,12 @@ def test_an_iteration_is_one_step_span_with_its_five_children(k, tmp_path,
             assert s.depth >= 1, s
             assert any(p.start_ns <= s.start_ns and s.end_ns <= p.end_ns
                        for p in steps), s
-    assert not [s for s in spans
-                if s.tid in loop_thread and s.depth == 0
-                and s.name != "step"
-                and steps[0].start_ns <= s.start_ns <= steps[-1].end_ns]
+    # ... but the epoch's turnover: four batches an epoch, one boundary
+    assert [s.name for s in spans
+            if s.tid in loop_thread and s.depth == 0
+            and s.name != "step"
+            and steps[0].start_ns <= s.start_ns <= steps[-1].end_ns] == \
+        ["epoch/turnover"]
     assert "step/superstep" not in {s.name for s in spans}
     done = []
     for p in steps:
