@@ -16,7 +16,19 @@ and each query attends to the ``min(t + 1, topk)`` keys of largest score
                    keys (the indexer's softmax).
 ``dsa_fwd``        grouped-query flash attention over the selected pairs:
                    one KV head's ``g`` query heads a grid step, read in the
-                   projections' own layout ``[N, T, heads * D]``.
+                   projections' own layout ``[N, T, heads * D]``. Its online
+                   softmax keeps a row's running max, its rescale factor
+                   and its sum on 128 lanes, the row's value on each, so
+                   the only work across lanes a head and tile is the row
+                   max and the row sum themselves. It masks a tile's
+                   scores ONCE: with the finite ``NEG_INF`` an unselected
+                   key's ``exp(NEG_INF - m)`` is 0 once the row has met a
+                   selected key; what the row summed before (p = 1 while
+                   m is still ``NEG_INF``) its first selected key
+                   multiplies by ``alpha = exp(NEG_INF - m) = 0``; and
+                   ``dsa_select`` gives every row at least one key. A row
+                   given none by hand is written as 0. The scale stays on
+                   the scores, as ``_probs`` applies it.
 ``dsa_bwd_dkv``    dK, dV;  ``dsa_bwd_dq``  dQ.
 ``dsa_index_bwd``  the indexer's objective, KL(p || softmax_S(I)) with p the
                    main attention's probabilities averaged over all heads
@@ -282,6 +294,12 @@ def _first_q(j, bq, bk):
     return j * bk // bq
 
 
+def _lanes(x, n):
+    """A ``(rows, 128)`` block as ``(rows, 128 * n)``: n copies side by
+    side, whole registers reused, no data moved."""
+    return x if n == 1 else pltpu.repeat(x, n, axis=1)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, acc_ref,
                 m_ref, l_ref, *, g, d, scale, bq, bk, nk):
     i, j = pl.program_id(2), pl.program_id(3)
@@ -301,20 +319,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref, acc_ref,
         for h in range(g):
             s = _mm(q_ref[0, :, h * d:(h + 1) * d], k, tb=True) * scale
             s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_ref[h, :, :1]
+            # m, alpha and l stay (bq, 128), a row's value on every lane:
+            # the row max is broadcast once, and nothing else is a
+            # (bq, 1) column to lay out and broadcast again
+            m_prev = m_ref[h]
             m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_cur)
-            p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+            # no second select: exp(NEG_INF - m_cur) is 0 once the row has
+            # met a selected key; before that m_cur is NEG_INF, p is 1 off
+            # the selection, and the row's first selected key wipes those
+            # finite sums with alpha = exp(NEG_INF - m) = 0
+            p = jnp.exp(s - _lanes(m_cur, bk // 128))
             l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
-            m_ref[h] = jnp.broadcast_to(m_cur, m_ref.shape[1:])
-            acc_ref[h] = acc_ref[h] * alpha + _mm(p.astype(v.dtype), v)
+            m_ref[h] = m_cur
+            acc_ref[h] = (acc_ref[h] * _lanes(alpha, d // 128)
+                          + _mm(p.astype(v.dtype), v))
 
     @pl.when(j == nk - 1)
     def _finish():
         for h in range(g):
-            l = l_ref[h, :, :1]
-            o_ref[0, :, h * d:(h + 1) * d] = (
-                acc_ref[h] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            # a row that met no selected key (only a hand-made bitmask
+            # has one) still holds m = NEG_INF and the sums of its
+            # unselected keys: its o is 0, its lse NEG_INF as before
+            seen = m_ref[h, :, :1] > NEG_INF
+            o = acc_ref[h] / jnp.where(seen, l_ref[h, :, :1], 1.0)
+            o_ref[0, :, h * d:(h + 1) * d] = jnp.where(
+                seen, o, 0.0).astype(o_ref.dtype)
             lse = m_ref[h] + jnp.log(jnp.maximum(l_ref[h], 1e-30))
             lse_ref[0, h:h + 1, :] = lse.T[:1]
 
