@@ -51,6 +51,7 @@ from .shape_ops import (Reshape, View, InferReshape, Squeeze, Unsqueeze,
 from .sparse import (SparseTensor, SparseLinear, LookupTableSparse,
                      SparseJoinTable, DenseToSparse, sparse_dense_matmul)
 from .moe import MixtureOfExperts, RoutedExperts
+from .ssm import Mamba2Mixer, ssd_scan
 from .table_ops import (CAddTable, CSubTable, CMulTable, CDivTable, CMaxTable,
                         CMinTable, CAveTable, JoinTable, SplitTable,
                         BifurcateSplitTable, SelectTable, NarrowTable,
@@ -67,7 +68,7 @@ from .detection import (Anchor, Nms, PriorBox, Proposal, DetectionOutputSSD,
                         bbox_iou_matrix, bbox_areas, clip_boxes, decode_boxes,
                         nms_mask, generate_basic_anchors, bbox_vote)
 from .attention import (Attention, FeedForwardNetwork, LatentAttention,
-                        SparseAttention,
+                        SparseAttention, SublayerBlock,
                         Transformer, TransformerBlock, dot_product_attention,
                         flash_attention, position_encoding, causal_mask,
                         padding_mask, rotary_embedding)

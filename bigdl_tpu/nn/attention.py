@@ -131,11 +131,13 @@ class Attention(Module):
     seq_impl = "ring"     # class defaults: pre-r4 pickles lack the attrs
     num_kv_heads = None   # None → MHA (kv heads == query heads)
     rope = False          # rotary position embedding on q/k
+    head_dim = None       # None → hidden_size // num_heads
 
     def __init__(self, hidden_size: int, num_heads: int,
                  attention_dropout: float = 0.0, use_flash: bool = True,
                  seq_axis=None, causal: bool = False, seq_impl: str = "ring",
-                 num_kv_heads=None, rope: bool = False, name=None):
+                 num_kv_heads=None, rope: bool = False, head_dim=None,
+                 name=None):
         """``seq_axis``: name of a mesh axis the sequence dim is sharded
         over — attention then runs sequence-parallel. ``seq_impl``
         picks the scheme: ``"ring"`` (parallel/ring_flash.py: ppermute
@@ -144,9 +146,12 @@ class Attention(Module):
         head-scatter all_to_all, dense flash locally, needs
         num_heads % axis_size == 0). Only valid inside ``shard_map``
         over that axis; self-attention only, masking via ``causal``
-        (additive masks cannot cross devices)."""
+        (additive masks cannot cross devices). ``head_dim``: the width of
+        a head where ``num_heads * head_dim`` is not ``hidden_size`` (a
+        tensor-parallel share of the heads: q and o are ``num_heads *
+        head_dim`` wide)."""
         super().__init__(name=name)
-        assert hidden_size % num_heads == 0
+        assert head_dim or hidden_size % num_heads == 0
         if seq_axis is not None and attention_dropout > 0:
             raise ValueError(
                 "seq-parallel attention does not support attention "
@@ -160,7 +165,8 @@ class Attention(Module):
         self.causal = causal
         self.num_kv_heads = num_kv_heads
         self.rope = rope
-        if rope and (hidden_size // num_heads) % 2:
+        self.head_dim = head_dim
+        if rope and self._head_dim() % 2:
             raise ValueError("RoPE needs an even head dim")
         if num_kv_heads is not None:
             if num_heads % num_kv_heads:
@@ -179,12 +185,15 @@ class Attention(Module):
     def _kvh(self):
         return self.num_kv_heads or self.num_heads
 
+    def _head_dim(self):
+        return self.head_dim or self.hidden_size // self.num_heads
+
     def _init_params(self, rng):
         k = jax.random.split(rng, 4)
-        H = self.hidden_size
-        kvd = self._kvh() * (H // self.num_heads)
-        return {"wq": _glorot(k[0], (H, H)), "wk": _glorot(k[1], (H, kvd)),
-                "wv": _glorot(k[2], (H, kvd)), "wo": _glorot(k[3], (H, H))}
+        H, d = self.hidden_size, self._head_dim()
+        qd, kvd = self.num_heads * d, self._kvh() * d
+        return {"wq": _glorot(k[0], (H, qd)), "wk": _glorot(k[1], (H, kvd)),
+                "wv": _glorot(k[2], (H, kvd)), "wo": _glorot(k[3], (qd, H))}
 
     def _split(self, x, heads=None):
         """``[B, T, heads * D]`` → ``[B, heads, T, D]``: the layout of the
@@ -219,9 +228,10 @@ class Attention(Module):
         three matmuls where there is no fused product."""
         flat = self._project_fused(params, qx, kx)
         if flat is not None:
-            H = self.hidden_size
+            qd = self.num_heads * self._head_dim()
             kvd = params["wk"].shape[1]
-            return flat[..., :H], flat[..., H:H + kvd], flat[..., H + kvd:]
+            return (flat[..., :qd], flat[..., qd:qd + kvd],
+                    flat[..., qd + kvd:])
         kx = qx if kx is None else kx
         return qx @ params["wq"], kx @ params["wk"], kx @ params["wv"]
 
@@ -779,10 +789,11 @@ class SparseAttention(Module):
 class FeedForwardNetwork(Module):
     """Position-wise FFN (nn/FeedForwardNetwork.scala).
 
-    ``activation``: 'relu' (reference default), 'gelu', or 'swiglu'
+    ``activation``: 'relu' (reference default), 'gelu', 'swiglu'
     (gated: ``(silu(x@w1) * (x@w3)) @ w2`` — the modern LLM default; the
     gate keeps param count comparable by construction since callers
-    usually shrink filter_size by 2/3)."""
+    usually shrink filter_size by 2/3) or 'relu2' (squared ReLU, ungated:
+    ``relu(x@w1)^2 @ w2``, as ``nemotron_h`` configs name it)."""
 
     activation = "relu"   # class default: pre-r4 pickles lack the attr
     bias = True           # likewise
@@ -795,8 +806,8 @@ class FeedForwardNetwork(Module):
         super().__init__(name=name)
         self.hidden_size, self.filter_size = hidden_size, filter_size
         self.relu_dropout = relu_dropout
-        if activation not in ("relu", "gelu", "swiglu"):
-            raise ValueError(f"activation must be relu/gelu/swiglu, "
+        if activation not in ("relu", "gelu", "swiglu", "relu2"):
+            raise ValueError(f"activation must be relu/gelu/swiglu/relu2, "
                              f"got {activation!r}")
         self.activation = activation
         self.bias = bias
@@ -821,6 +832,8 @@ class FeedForwardNetwork(Module):
             h = jax.nn.silu(pre) * (x @ params["w3"])
         elif act == "gelu":
             h = jax.nn.gelu(pre)
+        elif act == "relu2":
+            h = jnp.square(jax.nn.relu(pre))
         else:
             h = jax.nn.relu(pre)
         if training and self.relu_dropout > 0 and rng is not None:
@@ -885,6 +898,9 @@ class TransformerBlock(Module):
             causal=causal, num_kv_heads=num_kv_heads, rope=rope)
         self.ffn = ffn or FeedForwardNetwork(
             hidden_size, filter_size, ffn_dropout, activation=ffn_activation)
+        if getattr(self.ffn, "bias_update", 0):
+            raise ValueError("this block carries no state from step to step:"
+                             " a moving bias needs a layer_pattern stack")
         self.ln1 = _make_norm(norm, hidden_size, norm_eps)
         self.ln2 = _make_norm(norm, hidden_size, norm_eps)
         self.with_cross = with_cross
@@ -941,6 +957,10 @@ class TransformerBlock(Module):
         from .moe import union_states
         state = union_states(attn_state, ffn_state)
         return (h + f, state) if state else h + f
+
+    def sublayers(self):
+        """The modules whose state (counters, losses) the block returns."""
+        return (self.attn, self.ffn)
 
     def _ffn_sublayer(self, params, h):
         n, _ = self.ln2.apply(params["ln2"], {}, h, False, None)
@@ -1012,6 +1032,44 @@ class TransformerBlock(Module):
         return self._ffn_sublayer(params, h_t + a), k_pages, v_pages
 
 
+# a layer pattern's characters (``hybrid_override_pattern`` of nemotron_h
+# configs) and the key, and scope, of the sublayer each one builds
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "ffn"}
+
+
+class SublayerBlock(Module):
+    """One layer of a hybrid stack: ONE sublayer behind its own pre-norm
+    and residual, ``h + module(norm(h))``. ``key`` is the sublayer's key in
+    the block's parameters and its scope (``ssm``, ``attn``, ``ffn``); the
+    norm's is ``ln``. The block's state is the module's: a module that
+    returns state (an expert layer's counters and moved bias) makes the
+    block return ``(h, state)``."""
+
+    def __init__(self, module: Module, key: str, hidden_size: int,
+                 norm: str = "rms", norm_eps: float = 1e-6, name=None):
+        super().__init__(name=name)
+        self.module, self.key = module, key
+        self.ln = _make_norm(norm, hidden_size, norm_eps)
+
+    def _init_params(self, rng):
+        k1, k2 = jax.random.split(rng)
+        return {"ln": self.ln._init_params(k1),
+                self.key: self.module._init_params(k2)}
+
+    def _init_state(self):
+        return self.module._init_state()
+
+    def sublayers(self):
+        return (self.module,)
+
+    def _apply(self, params, state, x, training, rng):
+        h = x[1] if isinstance(x, Table) else x
+        n, _ = self.ln.apply(params["ln"], {}, h, training, None, scope="ln")
+        y, st = self.module.apply(params[self.key], state, n, training, rng,
+                                  scope=self.key)
+        return (h + y, st) if st else h + y
+
+
 def remat_block(run):
     """What ``remat=True`` means in this package: ``run`` (one block) under
     ``jax.checkpoint``. The backward pass recomputes the block from its
@@ -1051,7 +1109,8 @@ class Transformer(Module):
                  ffn_activation: str = "relu", norm: str = "layer",
                  norm_eps: float = 1e-6, embed_scale: bool = True,
                  tied_head: bool = True, make_attention=None, make_ffn=None,
-                 mtp: bool = False, name=None):
+                 mtp: bool = False, layer_pattern: Optional[str] = None,
+                 make_layer=None, name=None):
         """``norm``/``norm_eps``: the blocks' and the final norm
         (``layer`` or ``rms``). ``pos_encoding="none"`` adds no positions
         (an attention that rotates its own q/k, as ``make_attention``'s may)
@@ -1067,6 +1126,13 @@ class Transformer(Module):
         ``Table(logits, mtp_logits)`` with ``mtp_logits[:, i]`` predicting
         the SAME target as ``logits[:, i]`` from one position further back
         (``mtp_logits[:, 0]`` has no prediction: mask its target).
+        ``layer_pattern`` (with ``make_layer(kind, i)``): a hybrid stack of
+        single-sublayer layers (:class:`SublayerBlock`), one a character:
+        ``M`` a state-space mixer, ``*`` attention, ``E`` an expert layer
+        (:data:`LAYER_KINDS`); ``make_layer`` returns the
+        module of layer ``i``, of that kind. The stack has
+        ``len(layer_pattern)`` layers; ``num_hidden_layers``, ``make_attention``,
+        ``make_ffn`` and ``mtp`` do not apply.
         ``use_flash``: LM-mode self-attention goes through the fused
         O(T)-memory flash path (Pallas on TPU) instead of materialising the
         (B,H,T,T) score matrix. ``remat``: each block runs under
@@ -1096,6 +1162,15 @@ class Transformer(Module):
                              "and the MTP module are LM-mode options")
         self.pos_encoding = pos_encoding
         self.embed_scale, self.tied_head = embed_scale, tied_head
+        if layer_pattern is not None:
+            if (mode != "lm" or make_layer is None or mtp or make_attention
+                    or make_ffn):
+                raise ValueError("a layer pattern is an LM-mode stack built "
+                                 "by make_layer alone (no MTP module)")
+            unknown = set(layer_pattern) - set(LAYER_KINDS)
+            if unknown:
+                raise ValueError(f"layer pattern characters {sorted(unknown)}"
+                                 f" are none of {sorted(LAYER_KINDS)}")
 
         def block(i):
             return TransformerBlock(
@@ -1107,7 +1182,12 @@ class Transformer(Module):
                 attn=make_attention() if make_attention else None,
                 ffn=make_ffn(i) if make_ffn else None)
 
-        self.blocks = [block(i) for i in range(num_hidden_layers)]
+        if layer_pattern is not None:
+            self.blocks = [SublayerBlock(make_layer(kind, i), LAYER_KINDS[kind],
+                                         hidden_size, norm, norm_eps)
+                           for i, kind in enumerate(layer_pattern)]
+        else:
+            self.blocks = [block(i) for i in range(num_hidden_layers)]
         if mode == "translation":
             self.enc_blocks = [TransformerBlock(hidden_size, num_heads,
                                                 filter_size, attention_dropout,
@@ -1145,13 +1225,18 @@ class Transformer(Module):
 
     def _init_state(self):
         """The layers' counters and losses, where a block has any (zeros
-        until the first forward), so that the state a step returns has the
+        until the first forward), and under a block's key what it carries
+        from one step to the next, so that the state a step returns has the
         structure of the state it was given."""
-        from .moe import merge_counters, union_states
+        from .moe import carried, merge_counters, union_states
         blocks = self.blocks + ([self.mtp["block"]] if self.mtp else [])
-        return merge_counters([union_states(b.attn._init_state(),
-                                            b.ffn._init_state())
-                               for b in blocks])
+        state = merge_counters([union_states(*(m._init_state()
+                                               for m in b.sublayers()))
+                                for b in blocks])
+        for i, b in enumerate(self.blocks):
+            if carried(b._init_state()):
+                state[f"block{i}"] = carried(b._init_state())
+        return state
 
     @jax.named_scope("embed")
     def _embed(self, params, ids):
@@ -1164,40 +1249,48 @@ class Transformer(Module):
                                          "sinusoidal") == "sinusoidal")
 
     def _stack(self, blocks, prefix, params, h, mask, training, rng,
-               enc=None, enc_mask=None, states=None):
+               enc=None, enc_mask=None, states=None, state=None, kept=None):
         """``h`` through the blocks; a block that returns ``(h, state)``
         (its FFN counts its routing) has the state appended to
-        ``states``."""
+        ``states``. A block is given what ``state`` holds under its key,
+        and what it carries to the next step goes under that key of
+        ``kept``."""
+        from .moe import carried
         for i, blk in enumerate(blocks):
+            key = f"{prefix}{i}"
             r = jax.random.fold_in(rng, i) if rng is not None else None
             # the block goes through `_apply`, so its scope (the parameter
             # key, as `Module.apply(scope=)` takes it) is put here: inside
             # the checkpoint, so the recomputed forward carries it too
-            @jax.named_scope(f"{prefix}{i}")
-            def run(p, h, enc=enc, blk=blk, r=r):
+            @jax.named_scope(key)
+            def run(p, s, h, enc=enc, blk=blk, r=r):
                 arg = Table(h, mask) if enc is None else Table(h, mask, enc,
                                                                enc_mask)
-                return blk._apply(p, {}, arg, training, r)
+                return blk._apply(p, s, arg, training, r)
             if self.remat:
                 run = remat_block(run)
-            h = run(params[f"{prefix}{i}"], h)
+            h = run(params[key], (state or {}).get(key, {}), h)
             if isinstance(h, tuple):
-                h, state = h
-                states.append(state)
+                h, st = h
+                states.append(st)
+                if carried(st):
+                    kept[key] = carried(st)
         return h
 
     def hidden_states(self, params, x, training=False, rng=None,
-                      states=None, trunk=None):
+                      states=None, trunk=None, state=None, kept=None):
         """Final-LayerNorm hidden states (B, T, H) — the LM trunk without
         the vocab projection, so callers can fuse projection+loss in
         chunks (see models.transformer_lm.lm_loss_chunked) instead of
         materialising (B, T, vocab) logits. ``states`` collects the
         blocks' states, ``trunk`` the last block's output before the final
-        norm (what the MTP module reads)."""
+        norm (what the MTP module reads); ``state`` is the model's, and
+        ``kept`` takes what its blocks carry to the next step."""
         assert self.mode == "lm", "hidden_states is the LM-mode trunk"
         h = self._embed(params, x)
         h = self._stack(self.blocks, "block", params, h, None, training, rng,
-                        states=[] if states is None else states)
+                        states=[] if states is None else states, state=state,
+                        kept={} if kept is None else kept)
         if trunk is not None:
             trunk.append(h)
         h, _ = self.ln_f.apply(params["ln_f"], {}, h, training, None,
@@ -1238,9 +1331,9 @@ class Transformer(Module):
                                    scope="ln_f")
             return self._head(params, h)
         # LM mode: causal masking lives inside the blocks (flash path)
-        states, trunk = [], []
+        states, trunk, kept = [], [], {}
         out = self._head(params, self.hidden_states(
-            params, x, training, rng, states, trunk))
+            params, x, training, rng, states, trunk, state, kept))
         if self.mtp and training:
             r = jax.random.fold_in(rng, len(self.blocks)) \
                 if rng is not None else None
@@ -1248,7 +1341,7 @@ class Transformer(Module):
                 params, x, trunk[0], training, r, states)))
         if states:
             from .moe import merge_counters
-            return out, merge_counters(states)
+            return out, dict(merge_counters(states), **kept)
         return out
 
     @jax.named_scope("head")
@@ -1266,7 +1359,7 @@ class Transformer(Module):
         nH/kvH smaller. Positions beyond the current one hold garbage —
         decode masks by position."""
         attn = self.blocks[0].attn
-        d = self.hidden_size // attn.num_heads
+        d = attn._head_dim()
         kvh = attn._kvh()
         return [(jnp.zeros((batch, kvh, max_len, d), dtype),) * 2
                 for _ in self.blocks]

@@ -90,6 +90,14 @@ def union_states(*states):
     return out
 
 
+def carried(state):
+    """What a layer's state carries from one step to the next (an expert
+    layer's moved bias): all of it but the ``counters`` and ``losses`` that
+    each forward makes anew."""
+    return {k: v for k, v in (state or {}).items()
+            if k not in ("counters", "losses")}
+
+
 def merge_counters(states):
     """One model state from the layers' states: ``{"counters": {each
     counter: the mean over the layers that count it, the worst layer's for
@@ -157,24 +165,53 @@ class RoutedExperts(Module):
     ``moe/balance``), ``f_e`` the share of the layer's tokens that chose
     expert e among their top k (summing to k), ``P_e`` its mean score.
 
+    ``activation="relu2"``: ungated squared-ReLU experts, ``E(u) = W2
+    relu(W1 u)^2`` (``nemotron_h``). ``latent`` (LatentMoE): the routed
+    experts work in a narrower space, ``y_r = (sum w_i E_i(x W_down))
+    W_up`` with ``W_down [H, latent]`` and ``W_up [latent, H]`` shared by
+    all experts (scope ``experts``; the router still reads ``x``). The
+    shared experts take the layer's activation.
+
+    ``bias_update`` (``u`` of ``noaux_tc``; sigmoid scoring): the bias
+    follows the load as training goes, ``b_i <- b_i + u * sign(mean load -
+    load_i)`` after every training forward, from the load that forward
+    routed (DeepSeek-V3's update between steps). The bias is then
+    ``params["bias"]``, set before training and left alone by the
+    optimizer (it has no gradient), plus ``state["bias"]``, what the
+    updates have added since, which the layer's state carries from one
+    step to the next.
+
     State: ``{"counters": {"moe/rows_local": rows routed to the held
     experts, "moe/load_max_over_mean": the fullest of ALL experts' load
-    over the mean load}}``, and ``"losses"`` where ``balance_coef`` > 0."""
+    over the mean load}}``, ``"losses"`` where ``balance_coef`` > 0 and
+    ``"bias"`` where ``bias_update`` > 0."""
 
     # class defaults: pickles from before these options lack the attrs
     scoring = "sigmoid"
     balance_coef = 0.0
+    activation = "swiglu"
+    latent = None
+    bias_update = 0.0
 
     def __init__(self, hidden_size: int, n_experts: int, top_k: int,
                  expert_hidden: int, held=None, n_shared: int = 0,
                  routed_scale: float = 1.0, capacity_factor=None,
                  scoring: str = "sigmoid", balance_coef: float = 0.0,
-                 name=None):
+                 activation: str = "swiglu", latent: Optional[int] = None,
+                 bias_update: float = 0.0, name=None):
         super().__init__(name=name)
         if scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring must be 'sigmoid' or 'softmax', got "
                              f"{scoring!r}")
+        if activation not in ("swiglu", "relu2"):
+            raise ValueError(f"activation must be 'swiglu' or 'relu2', got "
+                             f"{activation!r}")
+        if bias_update and scoring != "sigmoid":
+            raise ValueError("bias_update moves the sigmoid router's bias; "
+                             "softmax scoring has none")
         self.scoring, self.balance_coef = scoring, balance_coef
+        self.activation, self.latent = activation, latent
+        self.bias_update = bias_update
         from .attention import FeedForwardNetwork
         first, count = held or (0, n_experts)
         if not (0 <= first and first + count <= n_experts and count > 0):
@@ -186,18 +223,24 @@ class RoutedExperts(Module):
         self.routed_scale = routed_scale
         self.capacity_factor = capacity_factor
         self.shared = FeedForwardNetwork(
-            hidden_size, n_shared * expert_hidden, activation="swiglu",
+            hidden_size, n_shared * expert_hidden, activation=activation,
             bias=False) if n_shared else None
 
     def _init_params(self, rng):
         k = jax.random.split(rng, 5)
-        d, f, E = self.hidden_size, self.expert_hidden, self.n_experts
-        n = self.held[1]
+        H, f, E = self.hidden_size, self.expert_hidden, self.n_experts
+        d, n = self.latent or H, self.held[1]
         s1, s2 = 1.0 / np.sqrt(d), 1.0 / np.sqrt(f)
-        p = {"router": jax.random.normal(k[0], (d, E)) * s1,
+        p = {"router": jax.random.normal(k[0], (H, E)) * (1.0 / np.sqrt(H)),
              "experts": {"w1": jax.random.normal(k[1], (n, d, f)) * s1,
-                         "w3": jax.random.normal(k[2], (n, d, f)) * s1,
                          "w2": jax.random.normal(k[3], (n, f, d)) * s2}}
+        if self.activation == "swiglu":
+            p["experts"]["w3"] = jax.random.normal(k[2], (n, d, f)) * s1
+        if self.latent:
+            k5, k6 = jax.random.split(jax.random.fold_in(rng, 5))
+            p["latent"] = {
+                "down": jax.random.normal(k5, (H, d)) * (1.0 / np.sqrt(H)),
+                "up": jax.random.normal(k6, (d, H)) * s1}
         if self.scoring == "sigmoid":
             p["bias"] = jnp.zeros((E,))
         if self.shared:
@@ -209,6 +252,8 @@ class RoutedExperts(Module):
                               LOAD_MAX_OVER_MEAN: jnp.zeros(())}}
         if self.balance_coef:
             state["losses"] = {BALANCE: jnp.zeros(())}
+        if self.bias_update:
+            state["bias"] = jnp.zeros((self.n_experts,))
         return state
 
     def capacity(self, tokens: int) -> int:
@@ -221,8 +266,9 @@ class RoutedExperts(Module):
         """``(sel [T, K] int32, w [T, K])`` for rows ``x [T, H]``."""
         return self._route(params, x)[:2]
 
-    def _route(self, params, x):
-        """:meth:`route` and the scores ``[T, E]`` of every expert."""
+    def _route(self, params, x, moved=None):
+        """:meth:`route` and the scores ``[T, E]`` of every expert;
+        ``moved``: what :attr:`bias_update` has added to the bias."""
         logits = jnp.dot(
             x.astype(jnp.float32), params["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
@@ -231,9 +277,10 @@ class RoutedExperts(Module):
             _, sel = jax.lax.top_k(s, self.top_k)
         else:
             s = jax.nn.sigmoid(logits)
-            _, sel = jax.lax.top_k(
-                s + jax.lax.stop_gradient(params["bias"].astype(jnp.float32)),
-                self.top_k)
+            b = jax.lax.stop_gradient(params["bias"].astype(jnp.float32))
+            if moved is not None:
+                b = b + moved
+            _, sel = jax.lax.top_k(s + b, self.top_k)
         w = jnp.take_along_axis(s, sel, axis=-1)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return (sel.astype(jnp.int32),
@@ -264,20 +311,32 @@ class RoutedExperts(Module):
         T = h.shape[0]
         count = self.held[1]
         capacity = self.capacity(T)
+        moved = None
+        if self.bias_update:
+            moved = state.get("bias", jnp.zeros((self.n_experts,)))
         with jax.named_scope("route"):
-            sel, w, scores = self._route(params, h)
+            sel, w, scores = self._route(params, h, moved)
             slot_pair, slot_valid, load, overflow = self._plan(sel, capacity)
             slot_token = slot_pair // self.top_k
-            xs = jnp.where(slot_valid[:, None], h[slot_token], 0)
+        u = h
+        if self.latent:
+            with jax.named_scope("experts"):
+                u = h @ params["latent"]["down"]
+        with jax.named_scope("route"):
+            xs = jnp.where(slot_valid[:, None], u[slot_token], 0)
         with jax.named_scope("experts"):
             e = params["experts"]
             xs = xs.reshape(count, capacity, -1)
-            mid = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xs, e["w1"])) \
-                * jnp.einsum("ecd,edf->ecf", xs, e["w3"])
+            pre = jnp.einsum("ecd,edf->ecf", xs, e["w1"])
+            if self.activation == "relu2":
+                mid = jnp.square(jax.nn.relu(pre))
+            else:
+                mid = jax.nn.silu(pre) * jnp.einsum("ecd,edf->ecf", xs,
+                                                    e["w3"])
             ys = jnp.einsum("ecf,efd->ecd", mid, e["w2"])
         with jax.named_scope("route"):
             w_slot = jnp.where(slot_valid, w.reshape(-1)[slot_pair], 0)
-            y = jnp.zeros_like(h).at[slot_token].add(
+            y = jnp.zeros_like(u).at[slot_token].add(
                 w_slot[:, None] * ys.reshape(count * capacity, -1))
             y = jnp.where(overflow, jnp.nan, y)
             loadf = load.astype(jnp.float32)
@@ -285,7 +344,14 @@ class RoutedExperts(Module):
                 ROWS_LOCAL: jnp.sum(jax.lax.dynamic_slice_in_dim(
                     loadf, self.held[0], count)),
                 LOAD_MAX_OVER_MEAN: jnp.max(loadf) / jnp.mean(loadf)}
+        if self.latent:
+            with jax.named_scope("experts"):
+                y = y @ params["latent"]["up"]
         state = {"counters": counters}
+        if self.bias_update:
+            with jax.named_scope("route"):
+                state["bias"] = moved + self.bias_update * jnp.sign(
+                    jnp.mean(loadf) - loadf) if training else moved
         if self.balance_coef:
             with jax.named_scope("route"):
                 # E * sum_e f_e P_e: f_e the tokens' choices of e a token

@@ -75,6 +75,7 @@ SPECS = {
     "LookupTableSparse": lambda: N.LookupTableSparse(10, 6),
     "MapTable": lambda: N.MapTable(N.Linear(4, 3)),
     "Maxout": lambda: N.Maxout(6, 4, 2),
+    "Mamba2Mixer": lambda: N.Mamba2Mixer(8, 4, 4, 2, 8, chunk_size=4),
     "MixtureOfExperts": lambda: N.MixtureOfExperts(8, 2),
     "Model": lambda: _graph(N.Model),
     "MulConstant": lambda: N.MulConstant(2.0),
@@ -124,6 +125,10 @@ SPECS = {
     "RoutedExperts": lambda: N.RoutedExperts(8, 4, 2, 6, held=(0, 2),
                                              n_shared=1),
     "StaticGraph": lambda: _graph(N.StaticGraph),
+    "SublayerBlock": lambda: N.SublayerBlock(
+        N.RoutedExperts(8, 4, 2, 6, held=(0, 2), activation="relu2",
+                        latent=4, n_shared=2, bias_update=1e-3), "ffn", 8,
+        norm="rms"),
     "TemporalConvolution": lambda: N.TemporalConvolution(4, 6, 3),
     "TemporalMaxPooling": lambda: N.TemporalMaxPooling(2),
     "TimeDistributed": lambda: N.TimeDistributed(N.Linear(4, 3)),

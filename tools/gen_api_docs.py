@@ -53,6 +53,33 @@ softmax scoring has no selection bias (no `bias` parameter);
 `balance_coef > 0` returns `coef * E * sum_e f_e P_e` as the state's loss
 `moe/balance`. `Optimizer` adds every `losses` entry a model's state
 returns to the criterion's loss (`optim/optimizer.py::_loss_fn`).
+`activation="relu2"`: ungated experts `W2 relu(W1 u)^2` (`experts/{w1,
+w2}`); `latent=L`: the routed experts work on `u = x latent/down [H, L]`
+and their weighted sum goes back through `latent/up [L, H]` (scope
+`experts`; the router reads `x`); the `n_shared` shared experts take
+the layer's activation (`shared/{w1 [H, n_shared*F], w2}`).
+`bias_update=u` (sigmoid scoring): after every training forward the bias
+moves by `u * sign(mean load - load_i)`; the moved part is the state's
+`bias [E]` (in a `Transformer`, the model state's `block{i}/bias`), added
+to `params["bias"]` when selecting.
+`nn.FeedForwardNetwork(..., activation="relu2")`: `relu(x w1)^2 w2`.
+
+`nn.Mamba2Mixer(hidden_size, num_heads, head_dim, n_groups, state_size,
+conv_kernel=4, chunk_size=128, norm_eps=1e-5)`: Mamba-2's mixer; head h
+reads group h // (num_heads / n_groups). Parameters: `in_proj [H, 2*nh*P +
+2*g*N + nh]` (z, x, B, C, dt), `conv_weight [K, nh*P + 2*g*N]`,
+`conv_bias`, `dt_bias`, `A_log`, `D [nh]`, `norm/weight [nh*P]` (RMS a
+group of nh*P/g channels), `out_proj [nh*P, H]`. The scan is
+`nn.ssd_scan(x [b, T, nh, P], dt, A, B [b, T, g, N], C, chunk)` (scope
+`ssd`), a length not a multiple of chunk padded with dt = 0. A share of
+whole groups of heads is the same layer with fewer heads and groups.
+Nemotron-3-Super's widths: 4096, 128 heads of 64 in 8 groups, state 128.
+
+`nn.Transformer(..., layer_pattern="MEMEMEM*EME", make_layer=fn)`: one
+`nn.SublayerBlock` a character, `h + module(RMSNorm(h))` with the module
+`make_layer(kind, i)` returns under the key and scope `ssm` (M), `attn`
+(*) or `ffn` (E). `nn.Attention(..., head_dim=d)`: q and o
+`num_heads * d` wide where that is not hidden_size.
 
 `kernels.dsa_select(qi [N, hI, T, dI], kit [N, dI, T], w [N, hI, T],
 topk)` -> bitmask `[N, T/32, T]` int32, logsumexp `[N, 1, T]`;
